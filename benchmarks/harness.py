@@ -177,6 +177,38 @@ def metric_direction(name: str) -> str:
     )
 
 
+def _child_env() -> dict[str, str]:
+    """Environment of every child the harness starts: the suite is the
+    CPU smoke harness unless the caller says otherwise."""
+    env = dict(os.environ)
+    env.setdefault("JAX_PLATFORMS", "cpu")
+    return env
+
+
+_JAX_FINGERPRINT_CODE = (
+    "import json, jax; d = jax.devices(); print(json.dumps({"
+    "'jax': jax.__version__, 'jax_backend': jax.default_backend(), "
+    "'jax_device_kind': str(d[0].device_kind), 'jax_device_count': len(d)}))"
+)
+
+
+def _jax_fingerprint() -> dict[str, Any]:
+    """The JAX backend the benchmark children reach — asked of a child.
+
+    The harness process itself must never initialise a backend: a chip
+    belongs to one process at a time, so a parent that has called
+    ``jax.devices()`` holds it and every benchmark child then fails or
+    hangs.  The child gets the environment :func:`run_bench` gives them."""
+    proc = subprocess.run(
+        [sys.executable, "-c", _JAX_FINGERPRINT_CODE],
+        capture_output=True, text=True, env=_child_env(), timeout=300,
+    )
+    if proc.returncode != 0:
+        tail = "\n".join(proc.stderr.splitlines()[-15:])
+        raise HarnessError(f"cannot fingerprint the JAX backend:\n{tail}")
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
 def environment_fingerprint() -> dict[str, Any]:
     """Where these numbers came from — compared (informationally) against
     the baseline's fingerprint so cross-rig comparisons are never silent."""
@@ -189,30 +221,9 @@ def environment_fingerprint() -> dict[str, Any]:
                     break
     except OSError:
         pass
-    # the JAX backend actually reached matters as much as the version:
-    # BENCH_r01–r06 were ambiguous about CPU fallback precisely because
-    # the fingerprint never said which backend/device kind ran them
-    jax_version = "unavailable"
-    jax_backend = "unavailable"
-    jax_device_kind = "unavailable"
-    jax_device_count = 0
-    try:
-        import jax
-
-        jax_version = jax.__version__
-        jax_backend = jax.default_backend()
-        devices = jax.devices()
-        jax_device_count = len(devices)
-        if devices:
-            jax_device_kind = str(devices[0].device_kind)
-    except Exception:  # noqa: BLE001 - fingerprinting must never fail
-        pass
     return {
         "python": platform.python_version(),
-        "jax": jax_version,
-        "jax_backend": jax_backend,
-        "jax_device_kind": jax_device_kind,
-        "jax_device_count": jax_device_count,
+        **_jax_fingerprint(),
         "platform": platform.platform(),
         "machine": platform.machine(),
         "cpus": os.cpu_count() or 0,
@@ -246,14 +257,12 @@ def _parse_metric_lines(stdout: str) -> dict[str, float]:
 def run_bench(bench: Bench, mode: str) -> dict[str, float]:
     """One subprocess run of one benchmark; returns its metrics."""
     args = bench.smoke_args if mode == "smoke" else bench.full_args
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     try:
         proc = subprocess.run(
             [sys.executable, os.path.join(BENCH_DIR, bench.script), *args],
             capture_output=True,
             text=True,
-            env=env,
+            env=_child_env(),
             timeout=bench.timeout_s,
             cwd=REPO_ROOT,
         )

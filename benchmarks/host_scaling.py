@@ -140,12 +140,6 @@ def _worker_main(workload, n_rows, wid, n, port, outq):
         os.environ["PATHWAY_PROCESS_ID"] = str(wid)
         os.environ["PATHWAY_FIRST_PORT"] = str(port)
         os.environ["PATHWAY_THREADS"] = "1"
-        import jax
-
-        try:
-            jax.config.update("jax_platforms", "cpu")
-        except RuntimeError:
-            pass
         from pathway_tpu.internals.config import refresh_config
 
         refresh_config()

@@ -176,10 +176,6 @@ def run_eval(
 
 def main() -> None:
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import jax
-
-    if os.environ.get("JAX_PLATFORMS", "").lower() == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     for kind in ("bm25", "dense", "hybrid"):
         metrics = run_eval(make_retriever(kind))
         metrics["metric"] = f"rag_eval_{kind}"

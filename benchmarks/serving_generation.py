@@ -105,11 +105,6 @@ def run_continuous(sched, trace):
 
 def main() -> None:
     mode = sys.argv[1] if len(sys.argv) > 1 else "smoke"
-    import jax
-
-    if os.environ.get("JAX_PLATFORMS", "").lower() == "cpu":
-        jax.config.update("jax_platforms", "cpu")
-
     from pathway_tpu.models.decoder import DecoderLM
     from pathway_tpu.serving.generation import GenerationScheduler
 

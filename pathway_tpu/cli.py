@@ -14,6 +14,7 @@ accepted for parity but the device mesh is what actually scales compute.
 
 from __future__ import annotations
 
+import glob
 import os
 import secrets
 import subprocess
@@ -24,6 +25,47 @@ from typing import Any, NoReturn
 import click
 
 import pathway_tpu as pw
+
+
+# libtpu's per-process environment: which of the host's chips a process
+# opens, and the (trivial) topology it then forms by itself
+_TPU_PROCESS_ENV = (
+    "TPU_VISIBLE_CHIPS",
+    "TPU_VISIBLE_DEVICES",
+    "TPU_CHIPS_PER_PROCESS_BOUNDS",
+    "TPU_PROCESS_BOUNDS",
+)
+
+
+def _local_tpu_chips() -> int:
+    """TPU chips on this host, counted from their device nodes — the
+    launcher must not ask JAX: a parent that has initialised a backend
+    holds the chips its children need."""
+    return len(glob.glob("/dev/accel[0-9]*")) or len(glob.glob("/dev/vfio/[0-9]*"))
+
+
+def _chip_env(env_base: dict[str, str], processes: int, process_id: int) -> dict[str, str]:
+    """One chip per spawned process on a multi-chip host.
+
+    A chip belongs to one process at a time, and by default every process
+    opens every chip of its host: with the parent's environment passed on
+    unchanged, all but one child of ``spawn -n K`` fail or hang at backend
+    start-up.  Child ``i`` is therefore pinned to chip ``i`` — unless the
+    caller already set any of libtpu's per-process variables, runs on CPU,
+    spawns a single process (which may want the whole host), or the host
+    has no chip ``i``."""
+    if (
+        processes < 2
+        or env_base.get("JAX_PLATFORMS", "").lower() == "cpu"
+        or any(name in env_base for name in _TPU_PROCESS_ENV)
+        or process_id >= _local_tpu_chips()
+    ):
+        return {}
+    return {
+        "TPU_VISIBLE_CHIPS": str(process_id),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+    }
 
 
 def _cluster_env(
@@ -43,6 +85,7 @@ def _cluster_env(
         PATHWAY_PROCESS_ID=str(process_id),
         PATHWAY_RUN_ID=run_id,
     )
+    env.update(_chip_env(env_base, processes, process_id))
     return env
 
 

@@ -72,10 +72,12 @@ import time
 from contextvars import ContextVar
 from typing import Any, Callable, Sequence
 
+import jax
 import numpy as np
 
 from pathway_tpu.device import resilience as _res
 from pathway_tpu.device import telemetry as _dtel
+from pathway_tpu.device.compile_cache import ensure_compile_cache
 from pathway_tpu.device.bucketing import (
     BucketPolicy,
     pad_batch_dim,
@@ -89,14 +91,6 @@ __all__ = [
     "default_executor_snapshot",
     "get_default_executor",
 ]
-
-try:
-    import jax
-
-    _HAVE_JAX = True
-except Exception:  # pragma: no cover - jax is a baked-in dependency
-    _HAVE_JAX = False
-
 
 # request traces of the job currently executing on the dispatch thread —
 # set by ``_run_job`` so ``run_batch``/``_run_chunk`` (inline, same
@@ -307,7 +301,7 @@ def _donation_enabled() -> bool:
         return True
     if mode in ("off", "0", "false"):
         return False
-    return _HAVE_JAX and jax.default_backend() not in ("cpu",)
+    return jax.default_backend() not in ("cpu",)
 
 
 class DeviceExecutor:
@@ -323,6 +317,7 @@ class DeviceExecutor:
     ):
         from pathway_tpu.internals.config import env_float, env_int
 
+        ensure_compile_cache()
         if max_inflight_mb is None:
             max_inflight_mb = env_float("PATHWAY_DEVICE_INFLIGHT_MB")
         if max_inflight_requests is None:
@@ -502,8 +497,6 @@ class DeviceExecutor:
         static_argnames: tuple[str, ...],
         donate_argnums: tuple[int, ...],
     ) -> Callable:
-        if not _HAVE_JAX:
-            return fn
         kwargs: dict[str, Any] = {}
         if static_argnames:
             kwargs["static_argnames"] = static_argnames
@@ -543,6 +536,19 @@ class DeviceExecutor:
         with entry.lock:
             return set(entry.seen_keys)
 
+    def executables(self, name: str) -> dict[tuple, Any]:
+        """The AOT-compiled executable behind each cache key of ``name``
+        — for inspecting what was actually compiled (``as_text()``,
+        ``cost_analysis()``), e.g. that a kernel made it into the
+        program."""
+        entry = self._callables[name]
+        with entry.lock:
+            return {
+                key: compiled
+                for key, compiled in entry.compiled.items()
+                if compiled is not _COMPILING
+            }
+
     def stats(self, name: str) -> dict[str, int]:
         entry = self._callables[name]
         with entry.lock:
@@ -561,17 +567,12 @@ class DeviceExecutor:
         + backend.  Mirrors what jit keys on, so ``seen_keys`` tracks
         the real compile cache one-to-one."""
         leaves: list[tuple] = []
-        if _HAVE_JAX:
-            flat = jax.tree_util.tree_leaves((operands, arrays))
-        else:
-            flat = list(operands) + list(arrays)
-        for leaf in flat:
+        for leaf in jax.tree_util.tree_leaves((operands, arrays)):
             leaves.append(
                 (tuple(getattr(leaf, "shape", ())), str(getattr(leaf, "dtype", type(leaf).__name__)))
             )
         static_key = tuple(sorted((static or {}).items()))
-        backend = jax.default_backend() if _HAVE_JAX else "host"
-        return (tuple(leaves), static_key, backend)
+        return (tuple(leaves), static_key, jax.default_backend())
 
     @staticmethod
     def _cost_analysis_enabled() -> bool:
@@ -586,29 +587,34 @@ class DeviceExecutor:
         operands: tuple,
         arrays: tuple,
         static: dict[str, Any] | None,
-    ) -> Any | None:
+    ) -> Any:
         """AOT-compile a fresh cache key and capture its XLA cost.
 
         ``jitted.lower().compile()`` and a plain jit call do NOT share a
         compile cache, so the executable compiled here is kept and
         reused for every later dispatch of the key — paying one backend
         compile AND getting ``cost_analysis()``/``memory_analysis()`` at
-        compile time.  Any failure falls back to the jit call path (that
-        key's dispatches are then counted as *uncosted*, never lost).
-        The caller has already claimed the key with the ``_COMPILING``
-        sentinel inside the freshness critical section."""
+        compile time.  A compile failure propagates to the dispatch that
+        asked for it: it is classified and counted like any other device
+        failure (``device/resilience.py``), never absorbed into a second,
+        unaccounted compile on the jit path.  The caller has already
+        claimed the key with the ``_COMPILING`` sentinel inside the
+        freshness critical section."""
         try:
             lowered = entry.jitted.lower(*operands, *arrays, **(static or {}))
             compiled = lowered.compile()
             cost = _dtel.extract_cost(compiled)
-        except Exception:  # noqa: BLE001 - accounting must never fail dispatch
-            return None  # the finally clears the sentinel and wakes waiters
-        else:
             with entry.cv:
                 entry.compiled[key] = compiled
                 entry.costs[key] = cost
                 entry.cv.notify_all()
             return compiled
+        except BaseException:
+            # un-claim the key: its next dispatch is fresh again and
+            # re-attempts this compile instead of taking the jit path
+            with entry.cv:
+                entry.seen_keys.discard(key)
+            raise
         finally:
             # ANY exit that left the sentinel behind (including a
             # BaseException unwinding through the compile) must clear it,
@@ -641,7 +647,7 @@ class DeviceExecutor:
                     entry.cold += 1
                 # resolved only on fresh keys (an env read per dispatch
                 # would tax the warm path for nothing)
-                aot = _HAVE_JAX and self._cost_analysis_enabled()
+                aot = self._cost_analysis_enabled()
                 if aot:
                     # claim the key IN the same critical section that
                     # decided freshness: a concurrent dispatcher must see
@@ -704,8 +710,7 @@ class DeviceExecutor:
                 out = compiled(*operands, *arrays)
             else:
                 out = entry.jitted(*operands, *arrays, **(static or {}))
-            if _HAVE_JAX:
-                out = jax.tree_util.tree_map(np.asarray, out)
+            out = jax.tree_util.tree_map(np.asarray, out)
         finally:
             if footprint:
                 with self._mem_lock:
@@ -857,9 +862,9 @@ class DeviceExecutor:
                 f"no host fallback registered for {entry.name!r}"
             )
         t0 = time.monotonic()
-        out = fb(*operands, *padded, **(static or {}))
-        if _HAVE_JAX:
-            out = jax.tree_util.tree_map(np.asarray, out)
+        out = jax.tree_util.tree_map(
+            np.asarray, fb(*operands, *padded, **(static or {}))
+        )
         self._m_fb_ms.observe((time.monotonic() - t0) * 1000.0)
         return out
 
@@ -1566,6 +1571,17 @@ class DeviceExecutor:
     def quarantine_records(self) -> list[dict[str, Any]]:
         return self._quarantine.records()
 
+    def _attention_fallback_snapshot(self) -> dict[str, int]:
+        """Shapes ``ops/attention.py`` routed to the XLA path instead of
+        the Pallas kernel, with trace counts — empty on a healthy run."""
+        family = self._reg.family("device.attention.xla_fallback")
+        if family is None:
+            return {}
+        return {
+            dict(key)["shape"]: int(counter.value)
+            for key, counter in family.items()
+        }
+
     def device_snapshot(self) -> dict[str, Any]:
         """The full device story as one JSON-able dict — what rides
         flight-recorder dumps (``set_device_supplier``) and feeds
@@ -1579,6 +1595,7 @@ class DeviceExecutor:
             "callables": {
                 name: self.stats(name) for name in sorted(self._callables)
             },
+            "attention_xla_fallback": self._attention_fallback_snapshot(),
             "resilience": {
                 "enabled": self._resilience,
                 "dispatch_deadline_s": self._dispatch_deadline_s,

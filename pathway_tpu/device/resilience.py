@@ -164,6 +164,10 @@ _OOM_MARKERS = ("resource_exhausted", "out of memory")
 # the letters (zoom, bloom) must not route a transient into the ratchet
 _OOM_WORD = re.compile(r"\boom\b")
 _COMPILE_MARKERS = ("compil", "lowering", "mosaic", "unimplemented")
+# a kernel that overruns its scoped VMEM is refused at compile time with
+# RESOURCE_EXHAUSTED; the overrun is per program, so the OOM ratchet
+# (which shrinks the batch) cannot fix it — it is a compile failure
+_VMEM_MARKERS = ("vmem",)
 
 
 def _looks_device(exc: BaseException) -> bool:
@@ -189,6 +193,8 @@ def classify(exc: BaseException) -> DeviceJobError | None:
         return None
     msg = str(exc)
     low = msg.lower()
+    if any(m in low for m in _VMEM_MARKERS):
+        return DeviceCompileError(msg)
     if any(m in low for m in _OOM_MARKERS) or _OOM_WORD.search(low):
         return DeviceOOMError(msg)
     if any(m in low for m in _COMPILE_MARKERS):

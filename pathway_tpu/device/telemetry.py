@@ -64,6 +64,8 @@ import threading
 import time
 from typing import Any
 
+import jax
+
 from pathway_tpu.engine import metrics as _metrics
 
 __all__ = [
@@ -77,23 +79,14 @@ __all__ = [
     "render_device_snapshot",
 ]
 
-try:
-    import jax
-
-    _HAVE_JAX = True
-except Exception:  # pragma: no cover - jax is a baked-in dependency
-    _HAVE_JAX = False
-
-
 # ---------------------------------------------------------------------------
 # Roofline peak table
 # ---------------------------------------------------------------------------
 
 # Per-device-kind peak FLOP/s (dense, the marketed per-chip peak for the
 # precision the serving path uses).  Matched case-insensitively as a
-# substring of ``jax.devices()[0].device_kind``, most specific first — a
-# new TPU generation missing here falls back to the knob or, absent that,
-# utilization simply reports against the closest match it finds.
+# substring of ``jax.devices()[0].device_kind``, most specific first.  A
+# non-CPU kind missing here is an error (:func:`peak_flops`), not a default.
 PEAK_FLOPS_TABLE: tuple[tuple[str, float], ...] = (
     ("tpu v5p", 459e12),
     ("tpu v5 lite", 197e12),
@@ -114,13 +107,8 @@ CPU_PEAK_FLOPS_PER_CORE = 8e9
 
 
 def device_kind() -> str:
-    """The first local device's kind string (``"cpu"`` without jax)."""
-    if not _HAVE_JAX:
-        return "cpu"
-    try:
-        return str(jax.local_devices()[0].device_kind)
-    except Exception:  # noqa: BLE001 - accounting must never fail a run
-        return "cpu"
+    """The first local device's kind string."""
+    return str(jax.local_devices()[0].device_kind)
 
 
 def peak_flops() -> tuple[float, str]:
@@ -128,7 +116,10 @@ def peak_flops() -> tuple[float, str]:
 
     Priority: the ``PATHWAY_DEVICE_PEAK_FLOPS`` knob (an operator who
     benchmarked their part overrides any table), then the device-kind
-    table, then the CPU measured-peak default scaled by core count."""
+    table; the CPU platform gets the measured-peak default scaled by core
+    count.  An accelerator whose kind is not in the table raises: a
+    utilization against another part's peak is a wrong number, not an
+    estimate."""
     from pathway_tpu.internals.config import env_float
 
     configured = env_float("PATHWAY_DEVICE_PEAK_FLOPS")
@@ -138,6 +129,11 @@ def peak_flops() -> tuple[float, str]:
     for needle, value in PEAK_FLOPS_TABLE:
         if needle in kind:
             return value, kind
+    if jax.default_backend() != "cpu":
+        raise ValueError(
+            f"no peak FLOP/s known for device kind {kind!r}: add it to "
+            "PEAK_FLOPS_TABLE or set PATHWAY_DEVICE_PEAK_FLOPS"
+        )
     cores = os.cpu_count() or 1
     return CPU_PEAK_FLOPS_PER_CORE * cores, f"cpu-default ({cores} cores)"
 
@@ -154,12 +150,10 @@ def extract_cost(compiled: Any) -> dict[str, float]:
     Keys: ``flops``, ``bytes_accessed`` (XLA's HBM traffic estimate),
     ``argument_bytes``, ``output_bytes``, ``temp_bytes`` (peak scratch),
     and ``analyzed`` (1.0 when ``cost_analysis()`` actually produced
-    entries).  ``cost_analysis`` returns a list of per-computation dicts
-    on some jax versions and a single dict on others — both are summed.
-    Never raises; a backend without cost analysis yields zeros with
-    ``analyzed = 0.0``, and the accountant counts that key's dispatches
-    as *uncosted* — a gap in the accounting is visible, never read as a
-    zero-FLOP device."""
+    a cost dict).  Never raises; a backend without cost analysis yields
+    zeros with ``analyzed = 0.0``, and the accountant counts that key's
+    dispatches as *uncosted* — a gap in the accounting is visible, never
+    read as a zero-FLOP device."""
     out = {
         "flops": 0.0,
         "bytes_accessed": 0.0,
@@ -172,18 +166,12 @@ def extract_cost(compiled: Any) -> dict[str, float]:
         analysis = compiled.cost_analysis()
     except Exception:  # noqa: BLE001 - optional per backend
         analysis = None
-    if isinstance(analysis, dict):
-        analysis = [analysis]
-    for entry in analysis or ():
-        if not isinstance(entry, dict):
-            continue
+    if analysis:
         out["analyzed"] = 1.0
-        flops = entry.get("flops")
-        if isinstance(flops, (int, float)) and flops > 0:
-            out["flops"] += float(flops)
-        accessed = entry.get("bytes accessed")
-        if isinstance(accessed, (int, float)) and accessed > 0:
-            out["bytes_accessed"] += float(accessed)
+        out["flops"] = max(0.0, float(analysis.get("flops", 0.0)))
+        out["bytes_accessed"] = max(
+            0.0, float(analysis.get("bytes accessed", 0.0))
+        )
     try:
         mem = compiled.memory_analysis()
         out["argument_bytes"] = float(
@@ -359,8 +347,6 @@ def hbm_stats() -> dict[str, float] | None:
     CPU — callers (the executor's collector) fall back to the tracked
     live-bytes estimate there, so ``device.hbm.*`` is never silently
     absent."""
-    if not _HAVE_JAX:
-        return None
     try:
         stats = jax.local_devices()[0].memory_stats()
     except Exception:  # noqa: BLE001 - optional per backend
@@ -380,8 +366,8 @@ def hbm_stats() -> dict[str, float] | None:
 
 
 class TraceUnavailable(RuntimeError):
-    """Trace capture cannot run here (no trace dir configured, or no
-    ``jax.profiler``) — rendered as a clean 503 / CLI message."""
+    """Trace capture cannot run here (no trace dir configured) —
+    rendered as a clean 503 / CLI message."""
 
 
 class TraceBusy(TraceUnavailable):
@@ -415,8 +401,6 @@ def capture_trace(seconds: float, trace_dir: str | None = None) -> str:
             "no trace directory configured — set PATHWAY_DEVICE_TRACE_DIR "
             "(or pass an explicit directory)"
         )
-    if not _HAVE_JAX or not hasattr(jax, "profiler"):
-        raise TraceUnavailable("jax.profiler is unavailable in this process")
     seconds = max(0.0, min(float(seconds), _MAX_TRACE_SECONDS))
     if not _trace_lock.acquire(blocking=False):
         raise TraceBusy("a trace capture is already running in this process")
@@ -523,6 +507,13 @@ def render_device_snapshot(snapshot: dict[str, Any]) -> str:
             f"  {name}: {st.get('dispatches', 0)} dispatch(es), "
             f"{st.get('keys', 0)} compile key(s) "
             f"(cold {st.get('cold', 0)} / warmed {st.get('warmed', 0)})"
+        )
+    for shape, traces in sorted(
+        (snapshot.get("attention_xla_fallback") or {}).items()
+    ):
+        lines.append(
+            f"  attention {shape}: {traces} trace(s) on the XLA path "
+            "(Pallas kernel does not support the shape)"
         )
     resilience = snapshot.get("resilience") or {}
     for name in sorted(resilience.get("callables") or {}):

@@ -323,6 +323,9 @@ METRICS: dict[str, tuple[str, str]] = {
     "generate.decode.steps": (
         "counter", "continuous decode ticks dispatched (one token per "
         "active slot per tick)"),
+    "generate.tick.failures": (
+        "counter", "generation scheduler ticks that raised; every queued "
+        "and active request of the tick was failed with the error"),
     "generate.churn.synthetic": (
         "counter", "synthetic burst requests injected by the "
         "request_churn chaos fault kind"),
@@ -451,6 +454,10 @@ METRICS: dict[str, tuple[str, str]] = {
     "device.trace.captures": (
         "counter", "on-demand jax.profiler traces captured (GET /trace, "
         "`pathway_tpu trace`)"),
+    "device.attention.xla_fallback": (
+        "counter", "encoder-attention traces served by the XLA path "
+        "because the Pallas kernel does not support the shape (shape= "
+        "label; ops/attention.py)"),
     # device fault tolerance (pathway_tpu/device/resilience.py)
     "device.failures": (
         "counter", "classified device-path failures observed, labeled by "

@@ -368,8 +368,10 @@ ENV_KNOBS: tuple[EnvKnob, ...] = (
        "uncosted accounting", "executor"),
     _k("PATHWAY_DEVICE_PEAK_FLOPS", "float", None,
        "per-device peak FLOP/s for the roofline utilization estimate "
-       "(default: auto-detected from the device kind; the CPU rig gets "
-       "a measured-peak default so the layer is testable without a TPU)",
+       "(default: auto-detected from the device kind — an accelerator "
+       "kind missing from the table is an error until this is set; the "
+       "CPU rig gets a measured-peak default so the layer is testable "
+       "without a TPU)",
        "executor"),
     _k("PATHWAY_DEVICE_TRACE_DIR", "str", None,
        "base directory for on-demand jax.profiler traces (`GET "

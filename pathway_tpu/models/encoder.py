@@ -34,6 +34,7 @@ from flax import linen as nn
 from pathway_tpu.models.tokenizer import (
     bucket_seq_len,
     load_tokenizer,
+    may_have_local_checkpoint,
     pad_batch,
 )
 from pathway_tpu.ops.attention import encoder_attention
@@ -377,6 +378,8 @@ def load_hf_weights(model_name: str, params, config: EncoderConfig):
     """
     import os
 
+    if not may_have_local_checkpoint(model_name):
+        return None
     os.environ.setdefault("HF_HUB_OFFLINE", "1")
     tree_root = params["params"]
     has_head = "Dense_0" in tree_root and "Encoder_0" in tree_root

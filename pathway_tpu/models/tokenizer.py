@@ -56,16 +56,24 @@ class HashTokenizer:
         return " ".join(f"tok{int(i)}" for i in ids if i not in (CLS_ID, SEP_ID, PAD_ID))
 
 
-def load_tokenizer(model_name: str, vocab_size: int, max_length: int) -> Any:
-    """HF tokenizer if ``model_name`` is a local checkpoint directory or is
-    present in the local HF cache; else the hashing stand-in."""
+def may_have_local_checkpoint(model_name: str) -> bool:
+    """False when no offline ``transformers`` load of ``model_name`` can
+    succeed — it is not a directory and there is no local HF cache — so
+    callers skip the (~10 s) transformers import that could only fail."""
     import os
 
     cache = os.path.expanduser(
         os.environ.get("HF_HOME", "~/.cache/huggingface")
     )
-    if not os.path.isdir(cache) and not os.path.isdir(model_name):
-        # no local model cache: skip the (slow) transformers import entirely
+    return os.path.isdir(cache) or os.path.isdir(model_name)
+
+
+def load_tokenizer(model_name: str, vocab_size: int, max_length: int) -> Any:
+    """HF tokenizer if ``model_name`` is a local checkpoint directory or is
+    present in the local HF cache; else the hashing stand-in."""
+    import os
+
+    if not may_have_local_checkpoint(model_name):
         return HashTokenizer(vocab_size=vocab_size, max_length=max_length)
     try:
         os.environ.setdefault("HF_HUB_OFFLINE", "1")

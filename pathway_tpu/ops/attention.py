@@ -39,6 +39,7 @@ attention path.
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
@@ -109,8 +110,29 @@ def _supported(S: int, H: int, heads: int) -> bool:
     return hd in (32, 64, 128) and H % LANE_GROUP == 0 and S >= 16
 
 
+def _note_xla_fallback(S: int, H: int, heads: int) -> None:
+    """A shape the Pallas kernel rejects is served by the XLA path —
+    counted and logged once per trace, so a TPU run that believes it is
+    timing the kernel can see that it is not (``device_snapshot()``'s
+    ``attention_xla_fallback``)."""
+    from pathway_tpu.engine import metrics
+
+    metrics.get_registry().counter(
+        "device.attention.xla_fallback",
+        "encoder-attention traces served by the XLA path because the "
+        "Pallas kernel does not support the shape",
+        shape=f"S{S}_H{H}_heads{heads}",
+    ).inc()
+    logging.getLogger(__name__).warning(
+        "encoder_attention: Pallas kernel does not support S=%d H=%d "
+        "heads=%d; this trace uses the XLA attention path",
+        S, H, heads,
+    )
+
+
 def _xla_attention(q, k, v, mask_bias, heads: int):
-    """Reference/fallback path: plain XLA batched attention."""
+    """Reference path (and the only path off-TPU): plain XLA batched
+    attention."""
     B, S, H = q.shape
     hd = H // heads
     scale = 1.0 / (hd**0.5)
@@ -234,9 +256,10 @@ def encoder_attention(
       ctx ``[B, S, H]`` in the same packed layout and dtype as ``q``.
     """
     B, S, H = q.shape
-    on_tpu = jax.default_backend() == "tpu"
-    use_pallas = (interpret or on_tpu) and not force_xla and _supported(S, H, heads)
-    if not use_pallas:
+    if force_xla or not (interpret or jax.default_backend() == "tpu"):
+        return _xla_attention(q, k, v, mask_bias, heads)
+    if not _supported(S, H, heads):
+        _note_xla_fallback(S, H, heads)
         return _xla_attention(q, k, v, mask_bias, heads)
 
     hd = H // heads

@@ -6,15 +6,16 @@ in HBM; queries are embedded on device; scores are one einsum on the MXU.
 Shapes are bucketed to powers of two so streaming index growth hits a warm
 XLA compile cache; the padded tail is masked to -inf.
 
-Falls back to numpy when jax is unavailable or matrices are tiny (device
-dispatch overhead dominates under ~256 rows).
+Matrices under ``_JAX_MIN_ROWS`` rows are scored in numpy on the host:
+device dispatch overhead dominates there.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Any
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 _JAX_MIN_ROWS = 256  # below this, host numpy beats dispatch overhead
@@ -27,103 +28,99 @@ def _next_pow2(n: int) -> int:
     return p
 
 
-try:
-    import jax
-    import jax.numpy as jnp
+def score_block(matrix, queries, metric: str):
+    """Traceable similarity scores [n_queries, n_rows]; larger = closer.
 
-    _HAVE_JAX = True
-except Exception:  # pragma: no cover
-    _HAVE_JAX = False
+    The ONE device-side definition of each metric — used by both the
+    single-chip jitted path below and the shard_map distributed top-k
+    (``pathway_tpu/parallel/index.py``), so scores agree bit-for-bit
+    between them.  cos/ip run the matmul in bfloat16 (MXU-native);
+    l2sq stays float32 (catastrophic cancellation in bf16).
+    """
+    # bf16 is MXU-native; on CPU it is software-emulated and far slower
+    # than f32, so the fallback path keeps the native dtype
+    mm_dtype = jnp.bfloat16 if jax.default_backend() not in ("cpu",) else jnp.float32
+    m = matrix.astype(mm_dtype)
+    q = queries.astype(mm_dtype)
+    if metric == "cos":
+        mn = m / (jnp.linalg.norm(m, axis=1, keepdims=True).astype(mm_dtype) + 1e-6)
+        qn = q / (jnp.linalg.norm(q, axis=1, keepdims=True).astype(mm_dtype) + 1e-6)
+        return (qn @ mn.T).astype(jnp.float32)
+    if metric == "ip":
+        return (q @ m.T).astype(jnp.float32)
+    # l2sq: return negative squared distance so that larger = closer
+    m32 = matrix.astype(jnp.float32)
+    q32 = queries.astype(jnp.float32)
+    sq_m = jnp.sum(m32 * m32, axis=1)[None, :]
+    sq_q = jnp.sum(q32 * q32, axis=1)[:, None]
+    return -(sq_q + sq_m - 2.0 * (q32 @ m32.T))
 
 
-if _HAVE_JAX:
+_score_jax = functools.partial(jax.jit, static_argnames=("metric",))(score_block)
 
-    def score_block(matrix, queries, metric: str):
-        """Traceable similarity scores [n_queries, n_rows]; larger = closer.
 
-        The ONE device-side definition of each metric — used by both the
-        single-chip jitted path below and the shard_map distributed top-k
-        (``pathway_tpu/parallel/index.py``), so scores agree bit-for-bit
-        between them.  cos/ip run the matmul in bfloat16 (MXU-native);
-        l2sq stays float32 (catastrophic cancellation in bf16).
-        """
-        # bf16 is MXU-native; on CPU it is software-emulated and far slower
-        # than f32, so the fallback path keeps the native dtype
-        mm_dtype = jnp.bfloat16 if jax.default_backend() not in ("cpu",) else jnp.float32
-        m = matrix.astype(mm_dtype)
-        q = queries.astype(mm_dtype)
-        if metric == "cos":
-            mn = m / (jnp.linalg.norm(m, axis=1, keepdims=True).astype(mm_dtype) + 1e-6)
-            qn = q / (jnp.linalg.norm(q, axis=1, keepdims=True).astype(mm_dtype) + 1e-6)
-            return (qn @ mn.T).astype(jnp.float32)
-        if metric == "ip":
-            return (q @ m.T).astype(jnp.float32)
-        # l2sq: return negative squared distance so that larger = closer
-        m32 = matrix.astype(jnp.float32)
-        q32 = queries.astype(jnp.float32)
-        sq_m = jnp.sum(m32 * m32, axis=1)[None, :]
-        sq_q = jnp.sum(q32 * q32, axis=1)[:, None]
-        return -(sq_q + sq_m - 2.0 * (q32 @ m32.T))
+def exact_topk(scores, k: int):
+    """Exact top-k over a large score row, two-stage.
 
-    _score_jax = functools.partial(jax.jit, static_argnames=("metric",))(score_block)
-
-    def exact_topk(scores, k: int):
-        """Exact top-k over a large score row, two-stage.
-
-        ``lax.top_k`` over a megarow is a full sort (~140 ms/query at 1M
-        on v5e — it, not the GEMM, dominated retrieval latency).  Stage 1
-        takes top-k within 1024-wide blocks (vectorized small sorts);
-        stage 2 reduces the ``blocks × k`` candidates.  Exact: every
-        global winner is by definition in its own block's top-k.
-        """
-        Q, N = scores.shape
-        bs = 1024
-        while N % bs:
-            bs >>= 1
-        blocks = N // bs
-        if N <= 65536 or blocks < 2 or k > bs:
-            return jax.lax.top_k(scores, k)
-        vals, idx = jax.lax.top_k(scores.reshape(Q, blocks, bs), k)
-        gidx = idx + (jnp.arange(blocks, dtype=idx.dtype) * bs)[None, :, None]
-        v, pos = jax.lax.top_k(vals.reshape(Q, blocks * k), k)
-        return v, jnp.take_along_axis(gidx.reshape(Q, blocks * k), pos, axis=1)
-
-    def masked_topk_block(matrix, mask, queries, *, metric: str, k: int):
-        """Traceable masked top-k — registered on the DeviceExecutor
-        (the sanctioned jit entry point), which buckets the query batch
-        so churning query counts never recompile."""
-        scores = score_block(matrix, queries, metric)
-        # keep the dot out of the top_k fusion: XLA (notably on CPU) would
-        # otherwise inline the GEMM into the sort fusion and lose the fast
-        # matmul path — measured 18x slower without the barrier
-        scores = jax.lax.optimization_barrier(scores)
-        return exact_topk(scores + mask[None, :], k)
-
-    _TOPK_CALLABLE = "indexing:masked_topk"
-
-    def _topk_executor():
-        """The default executor with the masked top-k registered once."""
-        from pathway_tpu.device import get_default_executor
-
-        ex = get_default_executor()
-        if not ex.registered(_TOPK_CALLABLE):
-            ex.register(
-                _TOPK_CALLABLE,
-                masked_topk_block,
-                static_argnames=("metric", "k"),
-            )
-        return ex
-
-    def masked_topk_jitted():
-        """The compiled masked top-k wrapper for pre-padded fixed shapes
-        — the raw-kernel surface the retrieval benchmarks time.  Call
-        with keyword ``metric=``/``k=``; production code goes through
-        ``topk_search_cached`` (executor-bucketed)."""
-        return _topk_executor().jitted(_TOPK_CALLABLE)
-
-    @functools.partial(jax.jit, static_argnames=("k",))
-    def _topk_jax(scores, k: int):
+    ``lax.top_k`` over a megarow is a full sort (~140 ms/query at 1M
+    on v5e — it, not the GEMM, dominated retrieval latency).  Stage 1
+    takes top-k within 1024-wide blocks (vectorized small sorts);
+    stage 2 reduces the ``blocks × k`` candidates.  Exact: every
+    global winner is by definition in its own block's top-k.
+    """
+    Q, N = scores.shape
+    bs = 1024
+    while N % bs:
+        bs >>= 1
+    blocks = N // bs
+    if N <= 65536 or blocks < 2 or k > bs:
         return jax.lax.top_k(scores, k)
+    vals, idx = jax.lax.top_k(scores.reshape(Q, blocks, bs), k)
+    gidx = idx + (jnp.arange(blocks, dtype=idx.dtype) * bs)[None, :, None]
+    v, pos = jax.lax.top_k(vals.reshape(Q, blocks * k), k)
+    return v, jnp.take_along_axis(gidx.reshape(Q, blocks * k), pos, axis=1)
+
+
+def masked_topk_block(matrix, mask, queries, *, metric: str, k: int):
+    """Traceable masked top-k — registered on the DeviceExecutor
+    (the sanctioned jit entry point), which buckets the query batch
+    so churning query counts never recompile."""
+    scores = score_block(matrix, queries, metric)
+    # keep the dot out of the top_k fusion: XLA (notably on CPU) would
+    # otherwise inline the GEMM into the sort fusion and lose the fast
+    # matmul path — measured 18x slower without the barrier
+    scores = jax.lax.optimization_barrier(scores)
+    return exact_topk(scores + mask[None, :], k)
+
+
+_TOPK_CALLABLE = "indexing:masked_topk"
+
+
+def _topk_executor():
+    """The default executor with the masked top-k registered once."""
+    from pathway_tpu.device import get_default_executor
+
+    ex = get_default_executor()
+    if not ex.registered(_TOPK_CALLABLE):
+        ex.register(
+            _TOPK_CALLABLE,
+            masked_topk_block,
+            static_argnames=("metric", "k"),
+        )
+    return ex
+
+
+def masked_topk_jitted():
+    """The compiled masked top-k wrapper for pre-padded fixed shapes
+    — the raw-kernel surface the retrieval benchmarks time.  Call
+    with keyword ``metric=``/``k=``; production code goes through
+    ``topk_search_cached`` (executor-bucketed)."""
+    return _topk_executor().jitted(_TOPK_CALLABLE)
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def _topk_jax(scores, k: int):
+    return jax.lax.top_k(scores, k)
 
 
 class DeviceIndexCache:
@@ -158,8 +155,6 @@ class DeviceIndexCache:
         return n
 
     def get(self, matrix: np.ndarray, version: int, metric: str = "raw"):
-        if not _HAVE_JAX:
-            return None
         n = matrix.shape[0]
         cap = _next_pow2(max(n, _JAX_MIN_ROWS))
         chips = self._n_chips()
@@ -220,7 +215,7 @@ def topk_search_cached(
     """Top-k against a device-resident padded index (warm across queries)."""
     n = matrix.shape[0]
     k_eff = min(k, n)
-    if not _HAVE_JAX or (n < _JAX_MIN_ROWS and cache.mesh is None):
+    if n < _JAX_MIN_ROWS and cache.mesh is None:
         scores = _score_numpy(
             matrix.astype(np.float32), queries.astype(np.float32), metric
         )
@@ -273,7 +268,7 @@ def score_batch(matrix: np.ndarray, queries: np.ndarray, metric: str = "cos") ->
         matrix = np.atleast_2d(matrix)
     if queries.ndim != 2:
         queries = np.atleast_2d(queries)
-    if not _HAVE_JAX or matrix.shape[0] < _JAX_MIN_ROWS:
+    if matrix.shape[0] < _JAX_MIN_ROWS:
         return _score_numpy(
             matrix.astype(np.float32), queries.astype(np.float32), metric
         )
@@ -287,7 +282,7 @@ def topk_search(
     """(indices, scores) of the k best rows per query."""
     n = matrix.shape[0]
     k_eff = min(k, n)
-    if not _HAVE_JAX or n < _JAX_MIN_ROWS:
+    if n < _JAX_MIN_ROWS:
         scores = _score_numpy(
             matrix.astype(np.float32), queries.astype(np.float32), metric
         )

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from pathway_tpu.parallel._compat import shard_map as _shard_map
-
 
 def _flat_axis_index(axes: tuple[str, ...], mesh: Mesh):
     idx = lax.axis_index(axes[0])
@@ -65,7 +63,7 @@ def _sharded_topk_impl(
         best_vals, pos = lax.top_k(vals_g, k)
         return jnp.take_along_axis(idx_g, pos, axis=1), best_vals
 
-    return _shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axes, None), P(axes), P(None, None)),

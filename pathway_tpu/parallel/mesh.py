@@ -71,17 +71,14 @@ def initialize_distributed(
     # backend").  jaxlib ships gloo TCP collectives; select them before
     # the backend initializes.  Only when CPU is the explicitly requested
     # platform — on TPU the collectives ride ICI/DCN and this flag is
-    # irrelevant (and older jaxlibs may not know it, hence best-effort).
+    # irrelevant.
     platforms = (
         getattr(jax.config, "jax_platforms", None)
         or os.environ.get("JAX_PLATFORMS", "")
         or ""
     )
     if "cpu" in platforms.lower():
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # noqa: BLE001 - unavailable on this jaxlib
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=nproc,

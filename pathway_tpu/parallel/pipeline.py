@@ -45,8 +45,6 @@ import optax
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from pathway_tpu.parallel._compat import shard_map
-
 from pathway_tpu.models.decoder import DecoderConfig, decoder_layer, _rms, _sw_mask
 
 
@@ -176,7 +174,7 @@ def make_pipelined_causal_lm(
         outputs = jnp.where(stage == n_stages - 1, outputs, jnp.zeros_like(outputs))
         return lax.psum(outputs, "stage")
 
-    trunk_sm = shard_map(
+    trunk_sm = jax.shard_map(
         trunk,
         mesh=mesh,
         # P("stage") is a tree prefix: every layer leaf (dense or MoE)

@@ -30,8 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from pathway_tpu.parallel._compat import pcast, shard_map
-
 NEG_INF = -1e30
 
 
@@ -72,7 +70,7 @@ def _ring_attention_local(q, k, v, bias, *, heads: int, axis_name: str):
     # front (they become varying after one ppermute'd step; scan requires
     # carry types to be loop-invariant)
     def varying(x):
-        return pcast(x, (axis_name,), to="varying")
+        return jax.lax.pcast(x, (axis_name,), to="varying")
 
     m0 = varying(jnp.full((B, heads, S_blk), NEG_INF, jnp.float32))
     l0 = varying(jnp.zeros((B, heads, S_blk), jnp.float32))
@@ -102,7 +100,7 @@ def ring_attention_traced(
         raise ValueError(f"sequence length {S} not divisible by mesh axis {n}")
     spec3 = P(None, axis, None)
     spec2 = P(None, axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring_attention_local, heads=heads, axis_name=axis),
         mesh=mesh,
         in_specs=(spec3, spec3, spec3, spec2),

@@ -33,6 +33,7 @@ steady-state, pinned in ``tests/test_jax_accounting.py``).
 from __future__ import annotations
 
 import asyncio
+import logging
 import threading
 import time
 from concurrent.futures import Future
@@ -220,6 +221,8 @@ class GenerationScheduler:
         self._thread: threading.Thread | None = None
         self._churn_ttfts: list[float] = []
         self._tokens_total = 0
+        self._tick_failures = 0
+        self._last_tick_error: str | None = None
         self._tok_window: list[tuple[float, int]] = []  # (t, tokens) per tick
 
         from pathway_tpu.engine import metrics as em
@@ -240,6 +243,11 @@ class GenerationScheduler:
         self._m_ttft = reg.histogram(
             "generate.ttft.ms", "request submit -> first token (ms)",
             buckets=em.MS_BUCKETS,
+        )
+        self._m_tick_failures = reg.counter(
+            "generate.tick.failures",
+            "scheduler ticks that raised (every queued/active request of "
+            "the tick was failed)",
         )
         self._m_churn = reg.counter(
             "generate.churn.synthetic",
@@ -360,6 +368,15 @@ class GenerationScheduler:
             try:
                 self._tick()
             except Exception as exc:  # noqa: BLE001 - fail requests, not the thread
+                # the thread and the server live on, so the failure must
+                # be visible somewhere other than each client's 500
+                self._tick_failures += 1
+                self._last_tick_error = f"{type(exc).__name__}: {exc}"[:300]
+                self._m_tick_failures.inc()
+                logging.getLogger(__name__).exception(
+                    "generation tick failed; failing %d queued/active request(s)",
+                    len(self._queue) + sum(s is not None for s in self._slots),
+                )
                 self._fail_all(exc)
 
     def shutdown(self) -> None:
@@ -735,6 +752,8 @@ class GenerationScheduler:
                 "kv_bytes_peak": self.allocator.peak_bytes,
                 "kv_bytes_dense": self.dense_kv_bytes,
                 "tokens_total": self._tokens_total,
+                "tick_failures": self._tick_failures,
+                "last_tick_error": self._last_tick_error,
             }
 
 

@@ -78,7 +78,10 @@ class BaseRestServer:
 
         ``with_cache`` routes the UDF disk caches through the persistence
         layer, matching the reference's engine-persistence-backed DiskCache
-        (udfs/caches.py:35, PersistenceMode::UdfCaching)."""
+        (udfs/caches.py:35, PersistenceMode::UdfCaching).  Further keyword
+        arguments go to ``pw.run`` — ``with_http_server=True`` serves
+        ``/status`` and ``/metrics`` beside the app, ``monitoring_level``
+        sets the console dashboard."""
         persistence_config = None
         if with_cache:
             from pathway_tpu import persistence as _persistence
@@ -96,6 +99,7 @@ class BaseRestServer:
             return pw.run(
                 terminate_on_error=terminate_on_error,
                 persistence_config=persistence_config,
+                **kwargs,
             )
 
         if threaded:

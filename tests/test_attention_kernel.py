@@ -45,6 +45,11 @@ def _rand_qkv(rng, B, S, H):
         (8, 16, 384, 12),  # tiny bucket
         (1, 256, 1024, 16),  # mxbai-large
         (3, 64, 384, 12),  # batch not divisible by block -> bb falls to 1
+        # the shapes chip_smoke.py compiles for real on the TPU
+        (1, 512, 768, 12),  # longest bucket: bb = 1, rows = 512
+        (1, 512, 1024, 16),
+        (1, 16, 768, 12),  # smallest block: one (16, H) tile
+        (2, 256, 768, 12),
     ],
 )
 def test_kernel_matches_xla(B, S, H, heads):
@@ -88,6 +93,30 @@ def test_kernel_no_cross_sequence_leakage():
         jnp.max(jnp.abs(full[0].astype(jnp.float32) - solo[0].astype(jnp.float32)))
     )
     assert err < 1e-3, err
+
+
+def test_unsupported_shape_is_counted_not_silent():
+    """Where the kernel would run (TPU, or interpret mode here), a shape
+    it rejects still computes through XLA — but the trace is counted, so
+    a chip run cannot mistake the XLA path for the kernel."""
+    from pathway_tpu.device import DeviceExecutor
+
+    rng = np.random.default_rng(4)
+    B, S, H, heads = 2, 16, 128, 8  # head_dim 16: not a supported width
+    assert not _supported(S, H, heads)
+    q, k, v = _rand_qkv(rng, B, S, H)
+    mask = jnp.zeros((B, S), jnp.float32)
+    ex = DeviceExecutor(collector_name=None)
+    label = f"S{S}_H{H}_heads{heads}"
+    before = ex.device_snapshot()["attention_xla_fallback"].get(label, 0)
+    out = encoder_attention(q, k, v, mask, heads, interpret=True)
+    ref = _xla_attention(q, k, v, mask, heads)
+    assert jnp.array_equal(out, ref)
+    after = ex.device_snapshot()["attention_xla_fallback"]
+    assert after[label] == before + 1
+    # off-TPU without interpret the XLA path is the only path: not counted
+    encoder_attention(q, k, v, mask, heads)
+    assert ex.device_snapshot()["attention_xla_fallback"][label] == before + 1
 
 
 def test_supported_predicate():

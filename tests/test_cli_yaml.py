@@ -75,6 +75,32 @@ def test_spawn_sets_cluster_env(tmp_path):
     assert "ports 12345..12346" in res.stderr
 
 
+def test_spawn_pins_each_child_to_its_own_chip(monkeypatch):
+    """On a multi-chip host every child would open every chip and all but
+    one would fail: child i gets chip i through libtpu's per-process
+    environment, unless the caller placed the processes itself."""
+    from pathway_tpu import cli
+
+    def env_of(base, processes, process_id):
+        return cli._cluster_env(
+            base, threads=1, processes=processes, first_port=10000,
+            process_id=process_id, run_id="r",
+        )
+
+    monkeypatch.setattr(cli, "_local_tpu_chips", lambda: 4)
+    for i in range(4):
+        env = env_of({}, 4, i)
+        assert env["TPU_VISIBLE_CHIPS"] == str(i)
+        assert env["TPU_PROCESS_BOUNDS"] == env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+    assert "TPU_VISIBLE_CHIPS" not in env_of({}, 5, 4)  # no fifth chip
+    assert "TPU_VISIBLE_CHIPS" not in env_of({}, 1, 0)  # one process: whole host
+    assert "TPU_VISIBLE_CHIPS" not in env_of({"JAX_PLATFORMS": "cpu"}, 4, 1)
+    placed = env_of({"TPU_VISIBLE_CHIPS": "2,3"}, 4, 1)
+    assert placed["TPU_VISIBLE_CHIPS"] == "2,3" and "TPU_PROCESS_BOUNDS" not in placed
+    monkeypatch.setattr(cli, "_local_tpu_chips", lambda: 0)
+    assert "TPU_VISIBLE_CHIPS" not in env_of({}, 4, 1)  # not a TPU host
+
+
 def test_spawn_propagates_failure_exit_code(tmp_path):
     script = tmp_path / "boom.py"
     script.write_text("raise SystemExit(3)")

@@ -109,6 +109,21 @@ def test_classify_maps_markers_to_typed_kinds():
         res.classify(res.InjectedDeviceError("OOM while allocating 2GiB")),
         DeviceOOMError,
     )
+    # a kernel's scoped-VMEM overrun is per program: a smaller batch
+    # cannot fix it, so it must not enter the OOM ratchet
+    assert isinstance(
+        res.classify(res.InjectedDeviceError(
+            "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem. "
+            "Used 20.5M of 16.0M"
+        )),
+        DeviceCompileError,
+    )
+    assert isinstance(
+        res.classify(res.InjectedDeviceError(
+            "INTERNAL: Mosaic failed to compile TPU kernel: unsupported shape"
+        )),
+        DeviceCompileError,
+    )
 
 
 def test_classify_refuses_host_bugs_and_passes_typed_through():

@@ -88,34 +88,35 @@ def test_golden_utilization_from_faked_cost_analysis(monkeypatch):
     assert acc.snapshot()["uncosted_dispatches"] == 1
 
 
-def test_extract_cost_sums_list_and_dict_forms():
+def test_extract_cost_flattens_cost_and_memory_analysis():
     class FakeMem:
         argument_size_in_bytes = 128
         output_size_in_bytes = 32
         temp_size_in_bytes = 16
 
-    class FakeCompiledList:
+    class FakeCompiled:
         def cost_analysis(self):
-            return [{"flops": 10.0, "bytes accessed": 100.0},
-                    {"flops": 5.0, "bytes accessed": 50.0}]
+            return {"flops": 15.0, "bytes accessed": 150.0}
 
         def memory_analysis(self):
             return FakeMem()
 
-    cost = dtel.extract_cost(FakeCompiledList())
+    cost = dtel.extract_cost(FakeCompiled())
     assert cost["flops"] == 15.0 and cost["bytes_accessed"] == 150.0
     assert cost["argument_bytes"] == 128.0 and cost["temp_bytes"] == 16.0
     assert cost["analyzed"] == 1.0
 
-    class FakeCompiledDict:
+    class FakeCompiledNoMemory:
         def cost_analysis(self):
-            return {"flops": 7.0, "bytes accessed": 70.0}
+            # XLA reports -1 for a cost it cannot model: never a negative total
+            return {"flops": -1.0, "bytes accessed": 70.0}
 
         def memory_analysis(self):
             raise RuntimeError("backend keeps no memory analysis")
 
-    cost = dtel.extract_cost(FakeCompiledDict())
-    assert cost["flops"] == 7.0 and cost["argument_bytes"] == 0.0
+    cost = dtel.extract_cost(FakeCompiledNoMemory())
+    assert cost["flops"] == 0.0 and cost["bytes_accessed"] == 70.0
+    assert cost["argument_bytes"] == 0.0
 
     class FakeCompiledBroken:
         def cost_analysis(self):
@@ -160,6 +161,35 @@ def test_cost_analysis_kill_switch_falls_back_to_uncosted(monkeypatch):
     assert cost["flops_total"] == 0.0
 
 
+def test_aot_compile_failure_is_a_counted_device_failure_not_a_jit_detour():
+    """A failed AOT compile used to be swallowed: the dispatch retraced
+    through the plain jit path and was served *uncosted*, silently.  Now
+    the failure is classified and counted like any other device failure,
+    and the key is un-claimed so its next dispatch compiles AOT again."""
+    from pathway_tpu.device.resilience import InjectedDeviceError
+
+    traces = []
+
+    def flaky(x):
+        traces.append(1)
+        if len(traces) == 1:
+            raise InjectedDeviceError("Mosaic failed to compile TPU kernel")
+        return jnp.sum(x * x, axis=1)
+
+    ex = DeviceExecutor(collector_name=None)
+    ex.register("flaky", flaky, policy=BucketPolicy(max_bucket=8))
+    rows = np.ones((3, 4), np.float32)
+    # the rail serves the batch from the host fallback — visibly
+    assert ex.run_batch("flaky", (rows,)).tolist() == [4.0, 4.0, 4.0]
+    st = ex.resilience_stats("flaky")
+    assert st["failures"] == {"compile": 1} and st["fallback_batches"] == 1
+    assert ex.cache_keys("flaky") == set()
+    assert ex.run_batch("flaky", (rows,)).tolist() == [4.0, 4.0, 4.0]
+    cost = ex.device_snapshot()["cost"]
+    assert cost["costed_dispatches"] == 1 and cost["uncosted_dispatches"] == 0
+    assert ex.resilience_stats("flaky")["fallback_batches"] == 1
+
+
 def test_peak_flops_table_and_cpu_default(monkeypatch):
     monkeypatch.delenv("PATHWAY_DEVICE_PEAK_FLOPS", raising=False)
     monkeypatch.setattr(dtel, "device_kind", lambda: "TPU v4")
@@ -169,6 +199,19 @@ def test_peak_flops_table_and_cpu_default(monkeypatch):
     peak, source = dtel.peak_flops()
     assert peak == dtel.CPU_PEAK_FLOPS_PER_CORE * (os.cpu_count() or 1)
     assert source.startswith("cpu-default")
+
+
+def test_unknown_kind_on_a_non_cpu_platform_raises(monkeypatch):
+    """A TPU the table does not know must not be rated against the CPU
+    default (or any other part's peak): it is an error naming the kind
+    and the knob that settles it."""
+    monkeypatch.delenv("PATHWAY_DEVICE_PEAK_FLOPS", raising=False)
+    monkeypatch.setattr(dtel, "device_kind", lambda: "TPU v9 mega")
+    monkeypatch.setattr(dtel.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="tpu v9 mega.*PATHWAY_DEVICE_PEAK_FLOPS"):
+        dtel.peak_flops()
+    monkeypatch.setenv("PATHWAY_DEVICE_PEAK_FLOPS", "3e14")
+    assert dtel.peak_flops() == (3e14, "PATHWAY_DEVICE_PEAK_FLOPS")
 
 
 def test_accounting_respects_the_metrics_kill_switch():

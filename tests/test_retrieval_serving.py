@@ -2,8 +2,8 @@
 
 Runs the full REST → embed → search → respond stack in a subprocess (the
 engine thread it starts lives until process exit, so it must not share
-this pytest process) at a tiny corpus and pins the artifact contract the
-driver/attest-loop rely on.
+this pytest process) at a tiny corpus and pins the artifact contract
+``bench.py`` relies on.
 """
 
 import json
@@ -56,47 +56,3 @@ def test_serving_harness_contract():
         out["colocated_p50_ms"]
         - (out["host_other_p50_ms"] + out["embed_device_ms"] + out["search_device_ms"])
     ) < 0.01, out
-
-
-def test_bench_aot_roundtrip(tmp_path):
-    """bench.py's AOT serialize/deserialize helpers: a compiled executable
-    round-trips through the cache file and computes identical results
-    (the driver-window fast path of VERDICT r4 next #2).  Runs in a clean
-    single-device subprocess: the deserialized executable binds to the
-    device topology it was compiled for, and this pytest process forces 8
-    virtual devices."""
-    script = f"""
-import importlib.util, sys
-import jax
-jax.config.update("jax_platforms", "cpu")
-import jax.numpy as jnp
-import numpy as np
-spec = importlib.util.spec_from_file_location("bench", {str(REPO / 'bench.py')!r})
-bench = importlib.util.module_from_spec(spec)
-spec.loader.exec_module(bench)
-bench._aot_dir = lambda: {str(tmp_path)!r}
-fn = jax.jit(lambda x: (x * 2 + 1).sum())
-x = jnp.arange(16.0)
-compiled = fn.lower(x).compile()
-bench._save_aot("toy", compiled)
-loaded = bench._try_load_aot("toy")
-assert loaded is not None, "load returned None"
-np.testing.assert_allclose(np.asarray(loaded(x)), np.asarray(fn(x)))
-open({str(tmp_path / 'bad.pkl')!r}, "wb").write(b"not a pickle")
-assert bench._try_load_aot("bad") is None
-print("AOT-ROUNDTRIP-OK")
-"""
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    env["PYTHONPATH"] = str(REPO) + os.pathsep + env.get("PYTHONPATH", "")
-    proc = subprocess.run(
-        [sys.executable, "-c", script],
-        capture_output=True,
-        text=True,
-        timeout=180,
-        env=env,
-    )
-    assert proc.returncode == 0 and "AOT-ROUNDTRIP-OK" in proc.stdout, (
-        proc.stderr[-2000:]
-    )
-    assert (tmp_path / "toy.pkl").exists()
