@@ -84,6 +84,7 @@ from pathway_tpu.device.bucketing import (
 )
 from pathway_tpu.engine import flight_recorder as _blackbox
 from pathway_tpu.engine import metrics as _metrics
+from pathway_tpu.engine import tracing as _tracing
 
 __all__ = [
     "DeviceExecutor",
@@ -106,8 +107,6 @@ def _current_traces() -> tuple:
     traces = _JOB_TRACES.get()
     if traces:
         return traces
-    from pathway_tpu.engine import tracing as _tracing
-
     trace = _tracing.current_trace()
     return (trace,) if trace is not None else ()
 
@@ -916,11 +915,12 @@ class DeviceExecutor:
         span per trace — bucket, rows, cache cold/warm, retries and
         fallback attributes filled by the layers below via ``note``."""
         traces = _current_traces()
-        if not traces:
-            return self._run_chunk_inner(
-                entry, operands, rows, count, bucket, static, None
-            )
-        note: dict[str, Any] = {}
+        # the timeline's interval, from the call to the result on the host
+        call = _tracing.begin(
+            "executor", "device.call",
+            callable=entry.name, bucket=bucket, rows=count,
+        )
+        note: dict[str, Any] | None = {} if traces else None
         started = time.time()
         t0 = time.monotonic()
         try:
@@ -928,6 +928,7 @@ class DeviceExecutor:
                 entry, operands, rows, count, bucket, static, note
             )
         finally:
+            _tracing.end(call)
             duration_s = time.monotonic() - t0
             for trace in traces:
                 trace.add_span(

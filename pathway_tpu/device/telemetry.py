@@ -67,6 +67,7 @@ from typing import Any
 import jax
 
 from pathway_tpu.engine import metrics as _metrics
+from pathway_tpu.engine import tracing as _tracing
 
 __all__ = [
     "CostAccountant",
@@ -413,7 +414,17 @@ def capture_trace(seconds: float, trace_dir: str | None = None) -> str:
             f"-pid{os.getpid()}-{_trace_seq:03d}",
         )
         os.makedirs(path, exist_ok=True)
-        jax.profiler.start_trace(path)
+        # device events and TraceMe annotations (the host timeline's
+        # intervals, engine/tracing.py) only: with the Python tracer on,
+        # a serving process's answers were delayed by seconds while the
+        # capture ran and the hand-over took minutes (PERF.md section 6)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        # the trace's clock starts at the session's start (the trace keeps
+        # its Unix time as ``profile_start_time``); this interval puts the
+        # traced seconds on the timeline's clock too
+        capture = _tracing.begin("profiler", "trace.capture", path=path)
+        jax.profiler.start_trace(path, profiler_options=options)
         try:
             deadline = time.monotonic() + seconds
             # sliced wait: a supervised worker capturing a long trace
@@ -424,6 +435,7 @@ def capture_trace(seconds: float, trace_dir: str | None = None) -> str:
                     break
                 time.sleep(min(0.05, remaining))
         finally:
+            _tracing.end(capture)
             jax.profiler.stop_trace()
         _metrics.get_registry().counter(
             "device.trace.captures", "on-demand jax.profiler traces captured"

@@ -2319,7 +2319,12 @@ class AsyncValuesNode(Node):
                 ]
                 return await asyncio.gather(*coros, return_exceptions=True)
 
-            flat = asyncio.run(run_all())
+            from pathway_tpu.engine import tracing
+
+            # the epoch's barrier: every async UDF of every row is awaited
+            # before the epoch goes on
+            with tracing.interval("engine", "epoch.async_wait", rows=len(to_run)):
+                flat = asyncio.run(run_all())
             n = len(self.coro_fns)
             for i, (k, r) in enumerate(to_run):
                 values = []

@@ -190,15 +190,18 @@ def render_status(
         payload["generation"] = {
             k: v for k, v in scalars.items() if k.startswith("generate.")
         }
-        # the requests panel (`pathway_tpu requests`): trace.* scalars,
-        # the slowest finished traces WITH span trees (waterfall source),
+        # the requests panel (`pathway_tpu requests`): trace.* scalars and
+        # the host timeline's totals (host.phase.*, where the threads' time
+        # went), the slowest finished traces WITH span trees (waterfall source),
         # and the per-bucket histogram exemplars linking a slow bucket to
         # a real trace id
         from pathway_tpu.engine import tracing as _tracing
 
         payload["requests"] = {
             "scalars": {
-                k: v for k, v in scalars.items() if k.startswith("trace.")
+                k: v
+                for k, v in scalars.items()
+                if k.startswith(("trace.", "host.phase."))
             },
             "slowest": _tracing.slowest_requests(10),
             "recent": _tracing.recent_requests(10),

@@ -246,6 +246,10 @@ METRICS: dict[str, tuple[str, str]] = {
     "serve.latency.ms": (
         "histogram", "admitted-request end-to-end latency by route= (ms); "
         "its p50 sizes the Retry-After hint on 429/503 rejects"),
+    "serve.epoch.wait.ms": (
+        "histogram", "REST row committed by its handler to the start of "
+        "the dataflow epoch that holds it, by route= (ms); written for "
+        "every traced request, those that waited nothing included"),
     "serve.shed": (
         "counter", "requests shed before doing pipeline work, by reason= "
         "(queue-full/degraded/queue-deadline/staged-expired/batcher/"
@@ -280,6 +284,9 @@ METRICS: dict[str, tuple[str, str]] = {
         "collector", "serving admission/shedder/drain state gauge "
         "supplier (engine/serving.py controller)"),
     # continuous-batching generation (serving/generation.py)
+    "generate.state": (
+        "collector", "generation panel gauge supplier (slots, queue, "
+        "pages, KV bytes, tokens/s of the newest GenerationScheduler)"),
     "generate.requests": (
         "counter", "generation requests accepted into the continuous-"
         "batching queue"),
@@ -515,6 +522,14 @@ METRICS: dict[str, tuple[str, str]] = {
         "gauge", "duration of the slowest buffered request trace (ms)"),
     "trace.requests.newest.ms": (
         "gauge", "duration of the newest buffered request trace (ms)"),
+    "host.phase.state": (
+        "collector", "host timeline totals supplier (engine/tracing.py)"),
+    "host.phase.seconds": (
+        "gauge", "summed seconds of the host timeline's closed intervals "
+        "since process start, by track= (the thread's lane) and name="),
+    "host.phase.count": (
+        "gauge", "closed intervals of the host timeline since process "
+        "start, by track= and name="),
     # SLO engine (engine/slo.py)
     "slo.state": (
         "collector", "declared-SLO evaluation supplier (engine/slo.py)"),

@@ -54,6 +54,7 @@ from collections import deque
 from typing import Any, Callable
 
 from pathway_tpu.engine import metrics as metrics_mod
+from pathway_tpu.engine import tracing
 from pathway_tpu.internals.config import (
     env_bool,
     env_float,
@@ -319,6 +320,9 @@ class AdmissionController:
         self._lock = threading.Lock()
         self._inflight = 0
         self._inflight_bytes = 0
+        # the timeline's ``serve.idle`` interval: open while no admitted
+        # request is in flight (from the first time the last one leaves)
+        self._idle = None
         self._waiters: deque[_Waiter] = deque()
         self._lat_ms: deque[float] = deque(maxlen=128)
         # shedder hysteresis dwell clocks — explicit None checks (0.0 is a
@@ -385,8 +389,18 @@ class AdmissionController:
             and self._inflight_bytes + nbytes <= self.inflight_bytes_limit
         )
 
+    def _inflight_moved_locked(self, before: int) -> None:
+        """``serve.idle`` ends where in-flight leaves nought and starts
+        where it comes back to it."""
+        if before == 0 and self._inflight > 0:
+            tracing.end(self._idle)
+            self._idle = None
+        elif before > 0 and self._inflight == 0:
+            self._idle = tracing.begin("serve", "serve.idle")
+
     def _grant_locked(self, route: str, nbytes: int, now: float) -> _Ticket:
         self._inflight += 1
+        self._inflight_moved_locked(self._inflight - 1)
         self._inflight_bytes += nbytes
         ticket = _Ticket(route, nbytes, admitted_at=now)
         self._outstanding[id(ticket)] = now
@@ -409,8 +423,6 @@ class AdmissionController:
         ingress ``traceparent`` continues a caller's trace; otherwise one
         is minted): the ticket carries it, and the admission wait —
         fast-path or queued — becomes its first child span."""
-        from pathway_tpu.engine import tracing
-
         trace = tracing.begin_request(route, trace_parent)
         started = time.time()
         try:
@@ -495,7 +507,9 @@ class AdmissionController:
         grants: list[tuple[_Waiter, _Ticket]] = []
         now = self._clock()
         with self._lock:
-            self._inflight = max(0, self._inflight - 1)
+            before = self._inflight
+            self._inflight = max(0, before - 1)
+            self._inflight_moved_locked(before)
             self._inflight_bytes = max(0, self._inflight_bytes - ticket.nbytes)
             self._outstanding.pop(id(ticket), None)
             if latency_ms is not None and code == 200:
@@ -748,6 +762,7 @@ class AdmissionController:
         count = max(1, int(count))
         with self._lock:
             self._inflight += count
+            self._inflight_moved_locked(self._inflight - count)
             self._gauge_locked()
         self._reg.counter(
             "serve.flood.synthetic", "synthetic flood admissions injected"
@@ -757,7 +772,9 @@ class AdmissionController:
             grants: list[tuple[_Waiter, _Ticket]] = []
             now = self._clock()
             with self._lock:
-                self._inflight = max(0, self._inflight - count)
+                before = self._inflight
+                self._inflight = max(0, before - count)
+                self._inflight_moved_locked(before)
                 grants = self._pump_locked(now)
                 self._gauge_locked()
                 self._check_drained_locked(now)

@@ -28,8 +28,17 @@ trace rides the batcher entry / device job / generation request) across
 thread hops — a coalesced batch serving waiters from two event loops
 parents each waiter's spans to its own trace.
 
+Beside the per-request traces the module keeps the process's **host
+timeline** (:func:`begin` / :func:`end` / :func:`interval` /
+:func:`switch`): closed intervals of work that belongs to a thread, not
+to one request — the scheduler's tick phases, the dataflow epochs and
+their async barrier, the executor's device calls, the stretches in which
+the server holds no request.  Both are stamped with ``time.time()``, so
+a request's spans and the threads' phases read as one stream on one
+clock (:func:`timeline`, :func:`recent_requests`).
+
 ``PATHWAY_TRACE_REQUESTS=0`` turns the whole layer off (no trace
-objects, no spans, no ring writes) — the lever
+objects, no spans, no intervals, no ring writes) — the lever
 ``benchmarks/request_trace_overhead.py`` prices (≤ 2 % of request cost).
 """
 
@@ -49,15 +58,21 @@ __all__ = [
     "TRACE_STAMP",
     "RequestTrace",
     "active_trace",
+    "begin",
     "begin_request",
     "current_trace",
     "enabled",
+    "end",
+    "epoch_run",
+    "interval",
     "maybe_trace_storm",
     "recent_requests",
     "reset_for_tests",
     "set_exporter",
     "slowest_requests",
     "snapshot",
+    "switch",
+    "timeline",
     "trace_scope",
 ]
 
@@ -78,17 +93,38 @@ STORM_TREE_DEPTH = 12
 STORM_DEFAULT_TRACES = 64
 
 
+# the host timeline's ring: closed intervals, oldest dropped.  One decode
+# tick writes ≈ 8, so this holds the last ≈ 4,000 ticks (≈ 90 s of
+# uninterrupted decoding on the chip) in ≈ 5 MB
+TIMELINE_MAX = 32768
+
+# how long the timeline trusts its cached reading of the switch: the
+# registry lookup behind ``enabled()`` costs more than a whole interval
+_SWITCH_TTL_S = 0.5
+
+
 def enabled() -> bool:
     """Request tracing on? (``PATHWAY_TRACE_REQUESTS``, default on)."""
     from pathway_tpu.internals.config import env_bool
 
-    return env_bool("PATHWAY_TRACE_REQUESTS")
+    on = env_bool("PATHWAY_TRACE_REQUESTS")
+    _switch[0] = on  # every request's read of the switch also sets the timeline's
+    return on
 
 
 def _buffer_max() -> int:
     from pathway_tpu.internals.config import env_int
 
     return max(1, int(env_int("PATHWAY_TRACE_BUFFER")))
+
+
+# held once: ``add_span`` runs for every span of every request
+_M_SPANS = _metrics.get_registry().counter(
+    "trace.spans", "request-scoped spans recorded"
+)
+_M_SPANS_DROPPED = _metrics.get_registry().counter(
+    "trace.spans.dropped", "request spans dropped by the per-trace span cap"
+)
 
 
 class RequestTrace:
@@ -103,7 +139,7 @@ class RequestTrace:
     __slots__ = (
         "trace_id", "root_span_id", "parent_span_id", "route", "started",
         "spans", "duration_s", "status", "_lock", "_finished", "_dropped",
-        "attributes",
+        "attributes", "committed_at",
     )
 
     def __init__(self, route: str, trace_parent: str | None = None):
@@ -123,6 +159,9 @@ class RequestTrace:
         self.duration_s: float | None = None
         self.status: Any = None
         self.attributes: dict[str, Any] = {}
+        # when the REST handler handed the request's row to its connector
+        # (io/http/_server.py): where ``serve.epoch.wait`` starts
+        self.committed_at: float | None = None
         self._lock = threading.Lock()
         self._finished = False
         self._dropped = 0
@@ -160,15 +199,10 @@ class RequestTrace:
         with self._lock:
             if len(self.spans) >= MAX_SPANS_PER_TRACE:
                 self._dropped += 1
-                _metrics.get_registry().counter(
-                    "trace.spans.dropped",
-                    "request spans dropped by the per-trace span cap",
-                ).inc()
+                _M_SPANS_DROPPED.inc()
                 return span_id
             self.spans.append(record)
-        _metrics.get_registry().counter(
-            "trace.spans", "request-scoped spans recorded"
-        ).inc()
+        _M_SPANS.inc()
         _export(record)
         return span_id
 
@@ -356,6 +390,7 @@ def set_exporter(telemetry: Any) -> None:
     same lifetime contract as the flight-recorder suppliers)."""
     global _exporter
     _exporter = telemetry
+    _staged.clear()  # a run's epochs start over: nothing waits for the last run's
     # the ring size knob is read when a run wires tracing up, not per
     # request — resizing preserves the newest entries
     global _ring
@@ -402,6 +437,179 @@ def requests_state() -> dict[str, float]:
     return out
 
 
+# ---------------------------------------------------------------------------
+# The epoch boundary: what a request waits between its commit and the epoch
+# ---------------------------------------------------------------------------
+
+# requests whose row the connector has staged into an epoch that has not
+# started yet, as (epoch time, trace); filled and emptied on the engine
+# thread alone (io/_utils.py stages, internals/runner.py starts epochs)
+_staged: list[tuple[int, RequestTrace]] = []
+
+
+def note_staged(trace_parent: str | None, epoch_time: int) -> None:
+    """The connector staged a request's row into ``epoch_time``."""
+    trace = active_trace(trace_parent)
+    if trace is not None and len(_staged) < _ACTIVE_MAX:
+        _staged.append((epoch_time, trace))
+
+
+def epoch_run(epoch_time: int, index: int):
+    """Scope of one ``run_epoch`` on the engine thread: closes the
+    ``serve.epoch.wait`` span of every request whose row this epoch holds
+    (handler's commit → now; observed into ``serve.epoch.wait.ms`` for
+    every request, those that waited nothing included) and returns the
+    ``epoch.run`` interval of the timeline."""
+    if _staged:
+        now = time.time()
+        held = [tr for t, tr in _staged if t <= epoch_time]
+        _staged[:] = [(t, tr) for t, tr in _staged if t > epoch_time]
+        for trace in held:
+            start = trace.committed_at if trace.committed_at is not None else now
+            waited_s = max(0.0, now - start)
+            trace.add_span("serve.epoch.wait", start, waited_s, epoch=epoch_time)
+            _metrics.get_registry().histogram(
+                "serve.epoch.wait.ms",
+                "REST row committed -> start of the epoch that holds it (ms)",
+                buckets=_metrics.MS_BUCKETS,
+                route=trace.route,
+            ).observe(waited_s * 1000.0, trace_id=trace.trace_id)
+    return interval("engine", "epoch.run", epoch=epoch_time, index=index)
+
+
+# ---------------------------------------------------------------------------
+# The host timeline: intervals of the threads' own work, on the spans' clock
+# ---------------------------------------------------------------------------
+
+
+class _Interval:
+    """One open interval; closing it (``end`` or leaving its ``with``)
+    writes the record."""
+
+    __slots__ = ("track", "name", "start", "attributes", "_annotation")
+
+    def __init__(self, track: str, name: str, start: float, attributes: dict | None):
+        self.track = track
+        self.name = name
+        self.start = start
+        self.attributes = attributes
+        # an annotation only while a profiler session listens (a check of
+        # 20 ns against the 300 ns of an annotation nobody records)
+        annotation = _TraceAnnotation or _load_annotation()
+        self._annotation = annotation(name) if annotation.is_enabled() else None
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *_exc) -> None:
+        end(self)
+
+
+_TraceAnnotation = None  # jax.profiler's, imported at the first interval
+
+
+def _load_annotation():
+    global _TraceAnnotation
+    from jax.profiler import TraceAnnotation
+
+    _TraceAnnotation = TraceAnnotation
+    return TraceAnnotation
+
+
+_OFF = nullcontext()
+_switch = [True, 0.0]  # [tracing on?, wall time of the next look at the knob]
+_timeline: deque[tuple] = deque(maxlen=TIMELINE_MAX)
+_totals: dict[tuple[str, str], list[float]] = {}  # (track, name) -> [seconds, count]
+_timeline_lock = threading.Lock()
+
+
+def begin(
+    track: str, name: str, start: float | None = None, **attributes: Any
+) -> _Interval | None:
+    """Open an interval on ``track`` (a thread's lane: ``sched``,
+    ``engine``, ``executor``, ``serve``) and return its handle for
+    :func:`end` — ``None`` while tracing is off.  While a profiler session
+    listens the same interval is a ``jax.profiler.TraceAnnotation``, so a
+    capture with TraceMe on shows the host's phases above the device's
+    operations."""
+    now = time.time()
+    if now >= _switch[1]:
+        _switch[1] = now + _SWITCH_TTL_S
+        enabled()
+    if not _switch[0]:
+        return None
+    # ``None`` for no attributes: a record of strings and floats alone is
+    # one the garbage collector stops tracking, and the ring holds 32k
+    return _Interval(track, name, now if start is None else start, attributes or None)
+
+
+def end(token: _Interval | None, **attributes: Any) -> float | None:
+    """Close an interval (a no-op for ``None``); returns its end, which
+    the next phase of the same thread may take as its start."""
+    if token is None:
+        return None
+    if token._annotation is not None:
+        token._annotation.__exit__(None, None, None)
+    if attributes:
+        token.attributes = {**(token.attributes or {}), **attributes}
+    now = time.time()
+    key = (token.track, token.name)
+    with _timeline_lock:
+        _timeline.append(
+            (token.track, token.name, token.start, now, token.attributes)
+        )
+        total = _totals.get(key)
+        if total is None:
+            total = _totals[key] = [0.0, 0]
+        total[0] += now - token.start
+        total[1] += 1
+    return now
+
+
+def interval(track: str, name: str, **attributes: Any):
+    """``with tracing.interval(track, name): ...`` — one interval around
+    the block."""
+    return begin(track, name, **attributes) or _OFF
+
+
+def switch(token: _Interval | None, name: str, **attributes: Any) -> _Interval | None:
+    """Close ``token`` and open ``name`` on the same track at the same
+    instant: consecutive phases of one thread tile its time."""
+    if token is None:
+        return None
+    return begin(token.track, name, start=end(token), **attributes)
+
+
+def timeline(since: float | None = None, until: float | None = None) -> list[dict]:
+    """The closed intervals that overlap the wall-clock window
+    ``[since, until]``, oldest first."""
+    with _timeline_lock:
+        records = list(_timeline)
+    return sorted(
+        (
+            {"track": t, "name": n, "start": s, "end": e, "attributes": a or {}}
+            for t, n, s, e, a in records
+            if (until is None or s <= until) and (since is None or e >= since)
+        ),
+        key=lambda r: r["start"],
+    )
+
+
+def phase_totals() -> dict[tuple[str, str], tuple[float, int]]:
+    """``(track, name) -> (seconds, count)`` since process start."""
+    with _timeline_lock:
+        return {key: (total[0], int(total[1])) for key, total in _totals.items()}
+
+
+def _phase_gauges() -> dict[str, float]:
+    out: dict[str, float] = {}
+    for (track, name), (seconds, count) in phase_totals().items():
+        labels = f"{{track={track},name={name}}}"
+        out[f"host.phase.seconds{labels}"] = seconds
+        out[f"host.phase.count{labels}"] = float(count)
+    return out
+
+
 def snapshot() -> dict[str, Any]:
     """The tracing section of a flight-recorder dump: ring occupancy
     plus the slowest and newest traces WITH their span trees, so a
@@ -412,6 +620,12 @@ def snapshot() -> dict[str, Any]:
         "buffered": buffered,
         "slowest": slowest_requests(10),
         "recent": recent_requests(10),
+        # the host timeline as per-name totals only: the ring itself is
+        # megabytes, and a post-mortem asks where the threads' time went
+        "timeline": {
+            f"{track}/{name}": {"seconds": seconds, "count": count}
+            for (track, name), (seconds, count) in phase_totals().items()
+        },
     }
 
 
@@ -423,6 +637,11 @@ def reset_for_tests() -> None:
     with _active_lock:
         _active.clear()
         _by_key.clear()
+    _staged.clear()
+    with _timeline_lock:
+        _timeline.clear()
+        _totals.clear()
+    _switch[1] = 0.0  # look at the knob again at the next interval
 
 
 # the ring gauges ride every scrape (the /status ``requests`` section and
@@ -430,6 +649,7 @@ def reset_for_tests() -> None:
 _metrics.get_registry().register_collector(
     "trace.requests.state", requests_state
 )
+_metrics.get_registry().register_collector("host.phase.state", _phase_gauges)
 
 
 # ---------------------------------------------------------------------------

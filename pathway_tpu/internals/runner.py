@@ -17,6 +17,7 @@ from contextlib import nullcontext as _nullcontext
 from typing import Any, Callable
 
 from pathway_tpu.engine import dataflow as df
+from pathway_tpu.engine import tracing as _tracing
 from pathway_tpu.internals.parse_graph import G
 from pathway_tpu.internals.table import Lowerer, Table
 
@@ -505,7 +506,6 @@ def _run_once(
         # flight-recorder dump carries the finished-request ring
         # (waterfalls) and the SLO burn/budget snapshot
         from pathway_tpu.engine import slo as _slo
-        from pathway_tpu.engine import tracing as _tracing
 
         _tracing.set_exporter(telemetry)
         _slo.install(registry)
@@ -626,9 +626,7 @@ def _run_once(
         _serving_cleanup.set_pressure_supplier(None)
         # the trace exporter holds this run's Telemetry: clear it before
         # telemetry.close() so no late span enqueues into a closed queue
-        from pathway_tpu.engine import tracing as _tracing_cleanup
-
-        _tracing_cleanup.set_exporter(None)
+        _tracing.set_exporter(None)
         if promote_watcher is not None:
             promote_watcher.stop()
         if worker_ctx is not None:
@@ -1328,7 +1326,7 @@ def _event_loop(
                 if telemetry is not None
                 else _nullcontext()
             )
-            with span:
+            with span, _tracing.epoch_run(t, result.epochs):
                 scope.run_epoch(t)
             epoch_hist.observe((_time.perf_counter() - t0) * 1000.0)
             blackbox.record("epoch", time=t, index=result.epochs)
@@ -1560,7 +1558,7 @@ def _event_loop_coordinated(
             if telemetry is not None
             else _nullcontext()
         )
-        with span:
+        with span, _tracing.epoch_run(t, result.epochs):
             scope.run_epoch(t)
         epoch_hist.observe((_time.perf_counter() - t0) * 1000.0)
         blackbox.record(

@@ -39,8 +39,8 @@ DELETE = "_pw_delete"  # row dict flag for deletions / upserts
 # client is answered 504 immediately) instead of burning an epoch
 DEADLINE_TS = "_pw_deadline_ts"
 # row dict field: W3C traceparent of the request that emitted this row
-# (engine/tracing.py) — staging records a child span on the request's
-# trace so connector queue time is attributable per request
+# (engine/tracing.py) — staging tells the trace which epoch holds the row,
+# so what the request waits for that epoch is attributable per request
 TRACE_STAMP = "_pw_trace"
 
 
@@ -457,14 +457,12 @@ class _QueuePoller:
             vrow = tuple(values)
             self.input_node.insert(key, vrow, self._time, diff)
             tp = row.get(TRACE_STAMP)
-            if tp is not None:
+            if tp is not None and diff > 0:
+                # the request's ``serve.epoch.wait`` span closes where the
+                # epoch that holds this row starts (internals/runner.py)
                 from pathway_tpu.engine import tracing as _tracing
 
-                tr = _tracing.active_trace(tp)
-                if tr is not None:
-                    tr.add_span(
-                        "serve.stage", _time.time(), 0.0, epoch=self._time
-                    )
+                _tracing.note_staged(tp, self._time)
             if self.persist_state is not None and not self.persist_state.operator_mode:
                 self.persist_state.log.record(key, vrow, diff)
             self._staged = True

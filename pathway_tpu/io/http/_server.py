@@ -341,6 +341,10 @@ class _RestSubject(Reader):
                 # trace's scope when it computes this row (the epoch-
                 # thread hop of the trace)
                 tracing.bind_key(key, trace)
+                if trace is not None:
+                    # where ``serve.epoch.wait`` starts; stamped before the
+                    # row leaves, since the engine thread may stage it at once
+                    trace.committed_at = _time.time()
                 emit(row)
                 emit(COMMIT)
                 pipeline_started = _time.time()

@@ -392,10 +392,10 @@ def _ffn(lp, h, cfg: DecoderConfig, *, full_capacity: bool = False):
             "wd": lp["wd"],
         }
         return moe_ffn(params, h, mcfg, full_capacity=full_capacity)
-    return (
-        _mm(jax.nn.silu(_mm(h, lp["wg"])) * _mm(h, lp["wu"]), lp["wd"]),
-        jnp.float32(0.0),
-    )
+    with jax.named_scope("mlp.gate_up"):
+        gated = jax.nn.silu(_mm(h, lp["wg"])) * _mm(h, lp["wu"])
+    with jax.named_scope("mlp.down"):
+        return _mm(gated, lp["wd"]), jnp.float32(0.0)
 
 
 def decoder_layer(lp, x, positions, mask, cfg: DecoderConfig, *, full_capacity=False):
@@ -806,23 +806,28 @@ def paged_decode_step(tree, k_pool, v_pool, block_tables, seq_lens, token,
 
     def layer(x, lp):
         lp, kp, vp = lp
-        h = _rms(x, lp["ln0"], cfg.norm_eps)
-        q = _mm(h, lp["wq"]).reshape(S, 1, cfg.heads, D)
-        k = _mm(h, lp["wk"]).reshape(S, 1, KH, D)
-        v = _mm(h, lp["wv"]).reshape(S, 1, KH, D)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
-        kp = attention_ops.scatter_kv_pages(kp, block_tables, positions, k)
-        vp = attention_ops.scatter_kv_pages(vp, block_tables, positions, v)
-        ctx = attention_ops.paged_gqa_attention(q, kp, vp, block_tables, mask)
-        x = x + _mm(ctx, lp["wo"])
+        with jax.named_scope("attn.qkv"):
+            h = _rms(x, lp["ln0"], cfg.norm_eps)
+            q = _mm(h, lp["wq"]).reshape(S, 1, cfg.heads, D)
+            k = _mm(h, lp["wk"]).reshape(S, 1, KH, D)
+            v = _mm(h, lp["wv"]).reshape(S, 1, KH, D)
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
+        with jax.named_scope("kv.write"):
+            kp = attention_ops.scatter_kv_pages(kp, block_tables, positions, k)
+            vp = attention_ops.scatter_kv_pages(vp, block_tables, positions, v)
+        with jax.named_scope("attn.paged"):
+            ctx = attention_ops.paged_gqa_attention(q, kp, vp, block_tables, mask)
+        with jax.named_scope("attn.out"):
+            x = x + _mm(ctx, lp["wo"])
         h = _rms(x, lp["ln1"], cfg.norm_eps)
         mlp, _ = _ffn(lp, h, cfg, full_capacity=True)
         return x + mlp, (kp, vp)
 
     x, (k_pool, v_pool) = lax.scan(layer, x, (tree["layers"], k_pool, v_pool))
-    x = _rms(x, tree["final_norm"], cfg.norm_eps)
-    logits = _mm(x[:, 0, :], tree["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        x = _rms(x, tree["final_norm"], cfg.norm_eps)
+        logits = _mm(x[:, 0, :], tree["lm_head"]).astype(jnp.float32)
     return logits, k_pool, v_pool
 
 
@@ -865,28 +870,33 @@ def paged_prefill_chunk(tree, k_pool, v_pool, block_tables, chunk_ids,
 
     def layer(x, lp):
         lp, kp, vp = lp
-        h = _rms(x, lp["ln0"], cfg.norm_eps)
-        q = _mm(h, lp["wq"]).reshape(S, T, cfg.heads, D)
-        k = _mm(h, lp["wk"]).reshape(S, T, KH, D)
-        v = _mm(h, lp["wv"]).reshape(S, T, KH, D)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
-        kp = attention_ops.scatter_kv_pages(kp, block_tables, write_positions, k)
-        vp = attention_ops.scatter_kv_pages(vp, block_tables, write_positions, v)
-        ctx = attention_ops.paged_gqa_attention(q, kp, vp, block_tables, mask)
-        x = x + _mm(ctx, lp["wo"])
+        with jax.named_scope("attn.qkv"):
+            h = _rms(x, lp["ln0"], cfg.norm_eps)
+            q = _mm(h, lp["wq"]).reshape(S, T, cfg.heads, D)
+            k = _mm(h, lp["wk"]).reshape(S, T, KH, D)
+            v = _mm(h, lp["wv"]).reshape(S, T, KH, D)
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
+        with jax.named_scope("kv.write"):
+            kp = attention_ops.scatter_kv_pages(kp, block_tables, write_positions, k)
+            vp = attention_ops.scatter_kv_pages(vp, block_tables, write_positions, v)
+        with jax.named_scope("attn.paged"):
+            ctx = attention_ops.paged_gqa_attention(q, kp, vp, block_tables, mask)
+        with jax.named_scope("attn.out"):
+            x = x + _mm(ctx, lp["wo"])
         h = _rms(x, lp["ln1"], cfg.norm_eps)
         mlp, _ = _ffn(lp, h, cfg, full_capacity=True)
         return x + mlp, (kp, vp)
 
     x, (k_pool, v_pool) = lax.scan(layer, x, (tree["layers"], k_pool, v_pool))
-    x = _rms(x, tree["final_norm"], cfg.norm_eps)
-    last = jnp.take_along_axis(
-        x,
-        jnp.maximum(chunk_lens - 1, 0)[:, None, None].repeat(cfg.hidden, 2),
-        axis=1,
-    )[:, 0, :]
-    logits = _mm(last, tree["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        x = _rms(x, tree["final_norm"], cfg.norm_eps)
+        last = jnp.take_along_axis(
+            x,
+            jnp.maximum(chunk_lens - 1, 0)[:, None, None].repeat(cfg.hidden, 2),
+            axis=1,
+        )[:, 0, :]
+        logits = _mm(last, tree["lm_head"]).astype(jnp.float32)
     return logits, k_pool, v_pool
 
 

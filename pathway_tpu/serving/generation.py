@@ -41,6 +41,7 @@ from typing import Any
 
 import numpy as np
 
+from pathway_tpu.engine import tracing
 from pathway_tpu.internals.config import env_bool, env_int
 
 __all__ = [
@@ -111,7 +112,10 @@ class GenRequest:
 class _Slot:
     """Device-slot state: which request occupies row ``i`` of the batch."""
 
-    __slots__ = ("req", "pages", "seq_len", "prefill_done", "prompt_len")
+    __slots__ = (
+        "req", "pages", "seq_len", "prefill_done", "prompt_len",
+        "prefill_started", "prefill_chunks", "prefill_enqueue_s",
+    )
 
     def __init__(self, req: GenRequest):
         self.req = req
@@ -119,6 +123,13 @@ class _Slot:
         self.seq_len = 0  # tokens written into the paged cache
         self.prompt_len = len(req.prompt_ids)
         self.prefill_done = False
+        # the request's ``generate.prefill`` span, closed at the first
+        # sync after its last chunk: wall time of the first chunk's
+        # enqueue (None again once the span is written), chunks so far
+        # and their summed dispatch time
+        self.prefill_started: float | None = None
+        self.prefill_chunks = 0
+        self.prefill_enqueue_s = 0.0
 
 
 class GenerationScheduler:
@@ -195,12 +206,13 @@ class GenerationScheduler:
         cfg = self.cfg
 
         def _decode(tree, kp, vp, bt, sl, lg, key, temp, top_p, min_p):
-            greedy_tok = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-            sampled = dec.sample_logits(
-                lg, key, jnp.maximum(temp, 1e-6)[:, None],
-                top_p=top_p[:, None], min_p=min_p[:, None],
-            )
-            tok = jnp.where(temp > 0.0, sampled, greedy_tok)
+            with jax.named_scope("sample"):
+                greedy_tok = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+                sampled = dec.sample_logits(
+                    lg, key, jnp.maximum(temp, 1e-6)[:, None],
+                    top_p=top_p[:, None], min_p=min_p[:, None],
+                )
+                tok = jnp.where(temp > 0.0, sampled, greedy_tok)
             lg2, kp, vp = dec.paged_decode_step(tree, kp, vp, bt, sl, tok, cfg)
             return tok, lg2, kp, vp
 
@@ -224,6 +236,13 @@ class GenerationScheduler:
         self._tick_failures = 0
         self._last_tick_error: str | None = None
         self._tok_window: list[tuple[float, int]] = []  # (t, tokens) per tick
+        self._ticks = 0
+        # the timeline's ``device.inflight`` interval: open from an enqueue
+        # that found the device drained to the return of the sync that
+        # drains it again, with the programs enqueued meanwhile
+        self._inflight = None
+        self._inflight_programs = 0
+        self._phase = None  # the running tick's open phase on the timeline
 
         from pathway_tpu.engine import metrics as em
 
@@ -253,7 +272,8 @@ class GenerationScheduler:
             "generate.churn.synthetic",
             "synthetic burst requests injected by the request_churn fault",
         )
-        self._gauges = reg  # gauges updated per tick in _update_gauges
+        # the generation panel's gauges, evaluated when scraped
+        reg.register_collector("generate.state", self._gauge_state)
 
         from pathway_tpu.engine import flight_recorder as _blackbox
 
@@ -282,7 +302,6 @@ class GenerationScheduler:
         :class:`DeadlineExceededError` when the request arrives already
         lapsed."""
         from pathway_tpu.engine import serving as edge
-        from pathway_tpu.engine import tracing
 
         if max_new_tokens >= self.max_cache:
             raise ValueError(
@@ -356,13 +375,16 @@ class GenerationScheduler:
     def _loop(self) -> None:
         while True:
             with self._lock:
+                idle = None  # one interval per wait for work, not per time-out
                 while (
                     self._running
                     and not self._queue
                     and all(s is None for s in self._slots)
                 ):
-                    self._update_gauges()
+                    if idle is None:
+                        idle = tracing.begin("sched", "sched.idle")
                     self._lock.wait(timeout=0.5)
+                tracing.end(idle)
                 if not self._running:
                     return
             try:
@@ -378,6 +400,7 @@ class GenerationScheduler:
                     len(self._queue) + sum(s is not None for s in self._slots),
                 )
                 self._fail_all(exc)
+                self._drained(failed=True)
 
     def shutdown(self) -> None:
         """Stop the worker; queued/active requests fail rather than hang."""
@@ -408,30 +431,57 @@ class GenerationScheduler:
     # -- the tick ----------------------------------------------------------
 
     def _tick(self) -> None:
+        """One tick.  Its phases (``tick.admit``, ``tick.prefill.prepare``,
+        ``tick.prefill.enqueue``, ``tick.decode.prepare``,
+        ``tick.decode.enqueue``, ``tick.decode.sync``, ``tick.deliver``)
+        tile it on the timeline's ``sched`` track: each ends where the
+        next starts."""
         t0 = time.monotonic()
-        with self._lock:
-            self._evict_lapsed(t0)
-            self._admit(t0)
-            prefill_rows = [
-                i for i, s in enumerate(self._slots)
-                if s is not None and not s.prefill_done
-            ]
-            decode_rows = [
-                i for i, s in enumerate(self._slots)
-                if s is not None and s.prefill_done
-            ]
-        if prefill_rows:
-            newly_ready = self._run_prefill(prefill_rows)
-            decode_rows.extend(newly_ready)
-        if decode_rows:
-            self._run_decode(decode_rows, t0)
-        with self._lock:
-            self._update_gauges()
-        dt = time.monotonic() - t0
-        self._tok_window.append((t0, len(decode_rows)))
-        if len(self._tok_window) > 256:
-            del self._tok_window[:128]
-        del dt
+        self._ticks += 1
+        self._phase = tracing.begin("sched", "tick.admit", tick=self._ticks)
+        try:
+            with self._lock:
+                self._evict_lapsed(t0)
+                self._admit(t0)
+                prefill_rows = [
+                    i for i, s in enumerate(self._slots)
+                    if s is not None and not s.prefill_done
+                ]
+                decode_rows = [
+                    i for i, s in enumerate(self._slots)
+                    if s is not None and s.prefill_done
+                ]
+            if prefill_rows:
+                decode_rows.extend(self._run_prefill(prefill_rows))
+            if decode_rows:
+                self._run_decode(decode_rows)
+            self._tok_window.append((t0, len(decode_rows)))
+            if len(self._tok_window) > 256:
+                del self._tok_window[:128]
+        finally:
+            tracing.end(self._phase)
+            self._phase = None
+
+    def _next_phase(self, name: str, **attributes: Any) -> None:
+        self._phase = tracing.switch(self._phase, name, **attributes)
+
+    def _enqueued(self) -> None:
+        """A program is about to be enqueued: the device is in flight from
+        here until :meth:`_drained`."""
+        if self._inflight is None:
+            self._inflight = tracing.begin("sched", "device.inflight")
+            self._inflight_programs = 0
+        self._inflight_programs += 1
+
+    def _drained(self, failed: bool = False) -> None:
+        """The sync that drains the device returned (or the tick failed and
+        nothing is waited for any more)."""
+        if self._inflight is not None:
+            attributes: dict[str, Any] = {"programs": self._inflight_programs}
+            if failed:
+                attributes["failed"] = True
+            tracing.end(self._inflight, **attributes)
+            self._inflight = None
 
     def _evict_lapsed(self, now: float) -> None:
         """Shed active rows whose deadline lapsed mid-generation, and
@@ -564,12 +614,13 @@ class GenerationScheduler:
         returns the rows whose prompt completed (now decode-ready)."""
         jnp = self._jnp
         T = self.prefill_chunk
+        self._next_phase("tick.prefill.prepare", rows=len(rows))
         ids = np.zeros((self.slots, T), np.int32)
         chunk_lens = np.zeros(self.slots, np.int32)
         starts = np.zeros(self.slots, np.int32)
         take = np.zeros(self.slots, bool)
         finishing: list[int] = []
-        traced_chunks: list[tuple] = []
+        chunked: list[_Slot] = []
         with self._lock:
             for i in rows:
                 slot = self._slots[i]
@@ -584,46 +635,39 @@ class GenerationScheduler:
                 ids[i, :n] = chunk
                 chunk_lens[i] = n
                 starts[i] = done
-                if slot.req.trace is not None:
-                    traced_chunks.append((slot.req.trace, n, done))
-                if done + n >= slot.prompt_len:
+                chunked.append(slot)
+                slot.seq_len = done + n
+                self._seq_lens[i] = slot.seq_len
+                if slot.seq_len >= slot.prompt_len:
                     take[i] = True
+                    slot.prefill_done = True
                     finishing.append(i)
             G = self._table_width()
             bt = self._block_tables[:, :G].copy()
-        chunk_started = time.time()
+        self._next_phase("tick.prefill.enqueue")
+        self._enqueued()
+        enqueue_started = time.time()
+        # asynchronous: the call returns once the chunk is enqueued, its
+        # work ends with the next sync (``_run_decode``)
         self._logits, self._k_pool, self._v_pool = self._prefill_fn(
             self.lm.params, self._k_pool, self._v_pool, jnp.asarray(bt),
             jnp.asarray(ids), jnp.asarray(chunk_lens), jnp.asarray(starts),
             self._logits, jnp.asarray(take),
         )
         self._m_prefill_chunks.inc()
-        if traced_chunks:
-            # one shared prefill program, one span per traced request —
-            # the wall duration is the whole chunk's (work is fused), the
-            # attributes are the request's own chunk geometry
-            chunk_s = max(0.0, time.time() - chunk_started)
-            for trace, n, done in traced_chunks:
-                trace.add_span(
-                    "generate.prefill.chunk", chunk_started, chunk_s,
-                    chunk_len=int(n), prompt_start=int(done),
-                )
-        with self._lock:
-            for i in rows:
-                slot = self._slots[i]
-                if slot is None:
-                    continue
-                n = int(chunk_lens[i])
-                slot.seq_len += n
-                self._seq_lens[i] = slot.seq_len
-                if take[i]:
-                    slot.prefill_done = True
+        enqueue_s = max(0.0, time.time() - enqueue_started)
+        for slot in chunked:
+            if slot.prefill_chunks == 0:
+                slot.prefill_started = enqueue_started
+            slot.prefill_chunks += 1
+            slot.prefill_enqueue_s += enqueue_s
         return finishing
 
-    def _run_decode(self, rows: list[int], now: float) -> None:
+    def _run_decode(self, rows: list[int]) -> None:
         """One continuous decode step: sample every decode-ready row's
         next token, write paged KV, deliver/evict finished rows."""
         jax, jnp = self._jax, self._jnp
+        self._next_phase("tick.decode.prepare", rows=len(rows))
         with self._lock:
             for i in rows:
                 slot = self._slots[i]
@@ -636,14 +680,20 @@ class GenerationScheduler:
             top_ps = self._top_ps.copy()
             min_ps = self._min_ps.copy()
         self._key, sub = jax.random.split(self._key)
+        self._next_phase("tick.decode.enqueue")
+        self._enqueued()
         tok, self._logits, self._k_pool, self._v_pool = self._decode_fn(
             self.lm.params, self._k_pool, self._v_pool, jnp.asarray(bt),
             jnp.asarray(sl), self._logits, sub, jnp.asarray(temps),
             jnp.asarray(top_ps), jnp.asarray(min_ps),
         )
         self._m_decode_steps.inc()
+        self._next_phase("tick.decode.sync")
         htok = np.asarray(tok)  # the one host sync per tick
         t_now = time.monotonic()
+        synced = time.time()
+        self._drained()
+        self._next_phase("tick.deliver")
         eos = self.lm.eos_id
         produced = 0
         with self._lock:
@@ -655,6 +705,18 @@ class GenerationScheduler:
                 t = int(htok[i])
                 slot.seq_len += 1
                 self._seq_lens[i] = slot.seq_len
+                if slot.prefill_started is not None:
+                    # the first sync after the request's last chunk: its
+                    # prefill, from the first chunk's enqueue, ends here
+                    if req.trace is not None:
+                        req.trace.add_span(
+                            "generate.prefill", slot.prefill_started,
+                            max(0.0, synced - slot.prefill_started),
+                            chunks=slot.prefill_chunks,
+                            prompt_len=slot.prompt_len,
+                            enqueue_s=slot.prefill_enqueue_s,
+                        )
+                    slot.prefill_started = None
                 if req.first_token_at is None:
                     req.first_token_at = t_now
                     req.first_token_wall = time.time()
@@ -698,40 +760,31 @@ class GenerationScheduler:
 
     # -- observability -----------------------------------------------------
 
-    def _update_gauges(self) -> None:
-        reg = self._gauges
-        active = sum(1 for s in self._slots if s is not None)
-        a = self.allocator
-        reg.gauge("generate.slots.active", "occupied generation slots").set(active)
-        reg.gauge("generate.slots.total", "configured generation slots").set(
-            self.slots
-        )
-        reg.gauge("generate.queue.depth", "requests queued for a slot").set(
-            len(self._queue)
-        )
-        reg.gauge("generate.pages.used", "KV pool pages holding live tokens").set(
-            a.used_pages
-        )
-        reg.gauge("generate.pages.total", "KV pool pages (page 0 reserved)").set(
-            self.num_pages - 1
-        )
-        reg.gauge(
-            "generate.kv.bytes.live", "KV bytes backing live tokens"
-        ).set(a.live_bytes)
-        reg.gauge(
-            "generate.kv.bytes.peak", "high-water mark of live KV bytes"
-        ).set(a.peak_bytes)
-        reg.gauge(
-            "generate.kv.bytes.dense",
-            "what the dense slots x max_cache layout would hold resident",
-        ).set(self.dense_kv_bytes)
+    def _gauge_state(self) -> dict[str, float]:
+        """The ``generate.state`` collector: the panel's gauges, computed
+        when someone scrapes them and not on every tick."""
         now = time.monotonic()
-        window = [(t, n) for (t, n) in self._tok_window if now - t <= 5.0]
+        window = [(t, n) for (t, n) in list(self._tok_window) if now - t <= 5.0]
         span = (now - window[0][0]) if len(window) > 1 else 0.0
-        rate = sum(n for _, n in window) / span if span > 0 else 0.0
-        reg.gauge(
-            "generate.tokens_per_s", "sustained decode throughput (5 s window)"
-        ).set(rate)
+        a = self.allocator
+        with self._lock:
+            return {
+                "generate.slots.active": float(
+                    sum(1 for s in self._slots if s is not None)
+                ),
+                "generate.slots.total": float(self.slots),
+                "generate.queue.depth": float(len(self._queue)),
+                "generate.pages.used": float(a.used_pages),
+                "generate.pages.total": float(self.num_pages - 1),
+                "generate.kv.bytes.live": float(a.live_bytes),
+                "generate.kv.bytes.peak": float(a.peak_bytes),
+                # what the dense slots x max_cache layout would hold resident
+                "generate.kv.bytes.dense": float(self.dense_kv_bytes),
+                # sustained decode throughput over the last 5 s
+                "generate.tokens_per_s": (
+                    sum(n for _, n in window) / span if span > 0 else 0.0
+                ),
+            }
 
     def snapshot(self) -> dict[str, Any]:
         """Generation panel for ``/status`` dumps and the flight recorder."""

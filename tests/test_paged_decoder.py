@@ -273,3 +273,47 @@ def test_paged_pool_scales_with_live_tokens():
     assert a.peak_bytes <= dense // 4
     for pages in held:
         a.release(pages)
+
+
+# ---------------------------------------------------------------------------
+# Scope names in the serving programs (ISSUE 26): metadata only
+# ---------------------------------------------------------------------------
+
+SCOPES = (
+    "attn.qkv", "kv.write", "attn.paged", "attn.out", "mlp.gate_up", "mlp.down", "lm_head",
+)
+
+
+def _serving_program(which: str):
+    """The scheduler's jitted body, on the tiny preset's shapes."""
+    S, T, pages, page = 2, 4, 9, 8
+    tree = dec.init_decoder_params(CFG, seed=0)
+    kp, vp = dec.init_kv_pool(CFG, pages, page)
+    bt = jnp.zeros((S, 2), jnp.int32)
+    lens = jnp.zeros((S,), jnp.int32)
+    if which == "decode":
+        fn = lambda t, k, v: dec.paged_decode_step(t, k, v, bt, lens, lens, CFG)  # noqa: E731
+    else:
+        ids = jnp.zeros((S, T), jnp.int32)
+        fn = lambda t, k, v: dec.paged_prefill_chunk(t, k, v, bt, ids, lens, lens, CFG)  # noqa: E731
+    return jax.jit(fn).lower(tree, kp, vp)
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_scope_names_are_metadata_only(which, monkeypatch):
+    import contextlib
+    import re
+
+    def instructions(lowered) -> int:
+        text = lowered.compile().as_text()
+        return len(re.findall(r"^\s*(?:ROOT )?%?[\w.\-]+ = ", text, flags=re.M))
+
+    scoped = _serving_program(which)
+    names = scoped.as_text(debug_info=True)
+    for scope in SCOPES:
+        assert scope in names, scope
+    with_scopes = instructions(scoped)
+    monkeypatch.setattr(jax, "named_scope", lambda _name: contextlib.nullcontext())
+    bare = _serving_program(which)
+    assert "attn.qkv" not in bare.as_text(debug_info=True)
+    assert with_scopes == instructions(bare) and with_scopes > 50

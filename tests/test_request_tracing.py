@@ -154,11 +154,11 @@ def test_ambient_scope_and_span_context_manager():
     assert tracing.current_trace() is None
     with tracing.trace_scope(t):
         assert tracing.current_trace() is t
-        with t.span("serve.stage", source="rest"):
+        with t.span("serve.epoch.wait", source="rest"):
             pass
     assert tracing.current_trace() is None
     (span,) = t.spans
-    assert span["name"] == "serve.stage"
+    assert span["name"] == "serve.epoch.wait"
     assert span["attributes"]["source"] == "rest"
     # None-scope is a no-op (tracing disabled costs one branch)
     with tracing.trace_scope(None):
@@ -439,7 +439,12 @@ def test_generation_spans_and_ttft_matches_histogram():
     assert "generate.queue" in names
     assert "generate.ttft" in names
     assert "generate.decode" in names
-    assert names.count("generate.prefill.chunk") >= 2  # 6 tokens, chunk 4
+    # one span for the whole prefill: 6 tokens in chunks of 4, from the
+    # first chunk's enqueue to the sync that hands out the first token
+    (prefill,) = [s for s in t.spans if s["name"] == "generate.prefill"]
+    assert prefill["attributes"]["chunks"] == 2
+    assert prefill["attributes"]["prompt_len"] == 6
+    assert 0.0 < prefill["attributes"]["enqueue_s"] <= prefill["duration_s"]
     (ttft,) = [s for s in t.spans if s["name"] == "generate.ttft"]
     (decode,) = [s for s in t.spans if s["name"] == "generate.decode"]
     assert ttft["attributes"]["prompt_len"] == 6
