@@ -324,9 +324,16 @@ METRICS: dict[str, tuple[str, str]] = {
         "histogram", "request submit to first generated token (ms) — "
         "the latency continuous batching exists to bound under churn"),
     "generate.prefill.chunks": (
-        "counter", "chunked-prefill programs dispatched (fixed "
-        "PATHWAY_GENERATE_PREFILL_CHUNK width, interleaved with decode "
-        "ticks)"),
+        "counter", "prefill programs dispatched, interleaved with decode "
+        "ticks: one per waiting prompt and tick at the ladder width that "
+        "covers what is left of it (PATHWAY_GENERATE_PREFILL_CHUNK at "
+        "most), one shared by the slots with least left"),
+    "generate.prefill.tokens": (
+        "counter", "prompt tokens dispatched in prefill programs"),
+    "generate.prefill.padded": (
+        "counter", "token rows of prefill programs that held no prompt "
+        "token (rows x width dispatched less generate.prefill.tokens): "
+        "arithmetic spent on padding"),
     "generate.decode.steps": (
         "counter", "continuous decode ticks dispatched (one token per "
         "active slot per tick)"),

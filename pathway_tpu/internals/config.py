@@ -338,10 +338,13 @@ ENV_KNOBS: tuple[EnvKnob, ...] = (
        "KV pool size in pages (page 0 is the reserved null page); 0 "
        "auto-sizes to half the dense worst case, floored so one "
        "full-cache request always fits", "generate"),
-    _k("PATHWAY_GENERATE_PREFILL_CHUNK", "int", 32,
-       "prompt tokens prefilled per tick per slot — chunked prefill "
-       "interleaves with decode so a long prompt cannot stall other "
-       "requests' token cadence", "generate"),
+    _k("PATHWAY_GENERATE_PREFILL_CHUNK", "int", 512,
+       "the most prompt tokens one prefill program holds, i.e. the "
+       "longest one prefill may hold up a decode tick; a waiting prompt "
+       "is prefilled alone at the smallest width of a short ladder "
+       "derived from this (512 -> 32 / 256 / 512) that covers what is "
+       "left of it, and slots with no more left than the narrowest "
+       "width share one program", "generate"),
     _k("PATHWAY_GENERATE_QUEUE", "int", 128,
        "max requests queued for a generation slot; overflow is "
        "answered 429 + Retry-After (page-pool exhaustion backpressures "

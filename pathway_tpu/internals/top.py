@@ -494,6 +494,15 @@ def render_top(
                 if q in ttft
             )
             lines.append(f"  ttft: {qs}")
+        chunks = generation.get("generate.prefill.chunks") or 0.0
+        if chunks:
+            real = generation.get("generate.prefill.tokens") or 0.0
+            padded = generation.get("generate.prefill.padded") or 0.0
+            lines.append(
+                f"  prefill: {int(chunks)} program(s) · {int(real)} prompt "
+                f"token(s) · {100.0 * padded / max(real + padded, 1.0):.0f}% "
+                f"of rows padding"
+            )
         churn = generation.get("generate.churn.synthetic")
         if churn:
             lines.append(f"  churn: {int(churn)} synthetic burst request(s)")

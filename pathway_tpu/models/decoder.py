@@ -682,7 +682,8 @@ def decode_chunk(
 # mask ever reaches into it.  The continuous-batching scheduler
 # (pathway_tpu/serving/generation.py) owns the host-side PageAllocator
 # and drives the two device programs below; all compiled shapes are
-# static (slot count fixed, block-table width bucketed), so churning
+# static (slot count fixed, prefill shapes few, block-table width
+# bucketed), so churning
 # request mixes replay warm programs — `jax.cache.miss == 0` in steady
 # state.
 
@@ -844,10 +845,12 @@ def paged_prefill_chunk(tree, k_pool, v_pool, block_tables, chunk_ids,
     each slot's LAST chunk token``, k_pool, v_pool)``; rows with
     ``chunk_lens == 0`` produce garbage logits the scheduler ignores.
 
-    ``T`` is a fixed compile-time width: long prompts run several fixed
-    chunks instead of one variable program, which is what lets the
-    scheduler interleave prefill with decode without a decode-tick stall
-    (and without recompiles).
+    ``S`` and ``T`` are compile-time sizes and the scheduler chooses them
+    from a few (``serving/generation.py::prefill_shape``): one row as
+    wide as the prompt that waits, or every slot at a narrow width.  A
+    prompt longer than the widest runs several chunks instead of one
+    variable program, which is what lets the scheduler interleave prefill
+    with decode without a long decode-tick stall (and without recompiles).
     """
     from pathway_tpu.ops import attention as attention_ops
 

@@ -16,18 +16,25 @@ that loop with the vLLM/Ragged-Paged-Attention serving shape (PAPERS.md):
   with live tokens.  Admission reserves a request's worst case up front:
   the pool can never OOM mid-generation; requests queue (bounded) at
   the edge instead.
-* **Chunked prefill** — prompts prefill in fixed-width chunks interleaved
-  with decode ticks, so a long prompt cannot stall every other request's
-  token cadence (no head-of-line blocking; pinned by the
-  ``request_churn`` chaos test).
+* **Chunked prefill** — a prompt prefills in programs shaped by what is
+  left of it (:func:`prefill_shape`): alone, as one row of the smallest
+  rung of a short ladder of widths that covers it, so the weights are
+  read once for a prompt that fits the widest rung and no padded slot is
+  multiplied by them; slots with little left (one-token requests, tails)
+  share one ``[slots, narrowest]`` program.  Prefill interleaves with
+  decode ticks, so a long prompt cannot stall every other request's
+  token cadence for longer than one widest-rung program per waiting
+  prompt (no head-of-line blocking; pinned by the ``request_churn``
+  chaos test).
 * **Deadlines** — requests carry the PR 17 :class:`engine.serving
   .Deadline`; a row that lapses mid-generation is shed at the next tick
   and counted under ``serve.deadline.exceeded{where=decode}``.
 
-Every device program has a static shape: slot count fixed, prefill chunk
-width fixed, block-table width bucketed to powers of two — a churning
-request mix replays warm compiled programs (``jax.cache.miss == 0``
-steady-state, pinned in ``tests/test_jax_accounting.py``).
+Every device program has a static shape: slot count fixed, prefill
+rows and width one of the few :func:`prefill_ladder` allows, block-table
+width bucketed to powers of two — a churning request mix replays warm
+compiled programs (``jax.cache.miss == 0`` steady-state, pinned in
+``tests/test_jax_accounting.py``).
 """
 
 from __future__ import annotations
@@ -47,6 +54,8 @@ from pathway_tpu.internals.config import env_bool, env_int
 __all__ = [
     "GenRequest",
     "GenerationScheduler",
+    "prefill_ladder",
+    "prefill_shape",
     "reset_shared_schedulers",
     "shared_scheduler",
 ]
@@ -57,6 +66,39 @@ def _pow2_bucket(n: int, cap: int) -> int:
     while b < n and b < cap:
         b <<= 1
     return min(b, cap)
+
+
+# Timed on a v5e at Mistral-7B widths, 24 layers (PERF.md, PR 28): one row
+# of 32, 64, 128 or 256 tokens costs 19.0 / 19.2 / 19.9 / 22.6 ms, about
+# one read of the weights whatever the width, and from there the width is
+# paid for: 512 cost 38.6 ms, 1024 82.2.  So rungs halve from the widest
+# down to 256 and no further, and under them lies the one narrow rung
+# whose program every slot shares (8 slots x 32 = 256 rows, 24.5 ms).
+_NARROW_RUNG = 32
+_PAID_FROM = 256
+_MAX_RUNGS = 4
+
+
+def prefill_ladder(widest: int) -> tuple[int, ...]:
+    """The widths a prefill program may take, ascending, derived from the
+    most prompt tokens one program holds: ``512 -> (32, 256, 512)``.  A
+    small ``widest`` (the tests' 4 and 8) is the only rung."""
+    rungs = [widest]
+    while len(rungs) < _MAX_RUNGS - 1 and rungs[-1] // 2 >= _PAID_FROM:
+        rungs.append(rungs[-1] // 2)
+    if widest > _NARROW_RUNG:
+        rungs.append(_NARROW_RUNG)
+    return tuple(reversed(rungs))
+
+
+def prefill_shape(remaining: int, ladder: tuple[int, ...], slots: int) -> tuple[int, int]:
+    """``(rows, width)`` of the program that prefills a slot with
+    ``remaining`` prompt tokens left: the smallest rung that covers them
+    (the widest for a longer prompt, which takes several programs), as
+    one row of its own; at the narrowest rung, as one of ``slots`` rows
+    shared with every other slot that has as little left."""
+    width = next((r for r in ladder if r >= remaining), ladder[-1])
+    return (slots if width == ladder[0] else 1), width
 
 
 class GenRequest:
@@ -115,6 +157,7 @@ class _Slot:
     __slots__ = (
         "req", "pages", "seq_len", "prefill_done", "prompt_len",
         "prefill_started", "prefill_chunks", "prefill_enqueue_s",
+        "prefill_width",
     )
 
     def __init__(self, req: GenRequest):
@@ -125,11 +168,12 @@ class _Slot:
         self.prefill_done = False
         # the request's ``generate.prefill`` span, closed at the first
         # sync after its last chunk: wall time of the first chunk's
-        # enqueue (None again once the span is written), chunks so far
-        # and their summed dispatch time
+        # enqueue (None again once the span is written), chunks so far,
+        # their summed dispatch time and the widest of them
         self.prefill_started: float | None = None
         self.prefill_chunks = 0
         self.prefill_enqueue_s = 0.0
+        self.prefill_width = 0
 
 
 class GenerationScheduler:
@@ -172,6 +216,10 @@ class GenerationScheduler:
             else env_int("PATHWAY_GENERATE_QUEUE")
         )
         self.pages_per_seq = -(-self.max_cache // self.page_size)
+        # no program is wider than the cache a slot can hold
+        self._ladder = prefill_ladder(
+            min(self.prefill_chunk, self.pages_per_seq * self.page_size)
+        )
         n_pages = pages if pages is not None else env_int("PATHWAY_GENERATE_PAGES")
         if n_pages <= 0:
             # auto: half the dense worst case (the whole point of paging),
@@ -216,12 +264,14 @@ class GenerationScheduler:
             lg2, kp, vp = dec.paged_decode_step(tree, kp, vp, bt, sl, tok, cfg)
             return tok, lg2, kp, vp
 
-        def _prefill(tree, kp, vp, bt, ids, cl, st, old_lg, take):
+        def _prefill(tree, kp, vp, bt, ids, cl, st, old_lg, lanes, take):
             lg, kp, vp = dec.paged_prefill_chunk(
                 tree, kp, vp, bt, ids, cl, st, cfg
             )
-            lg = jnp.where(take[:, None], lg, old_lg)
-            return lg, kp, vp
+            # row r of the program is slot ``lanes[r]``: where its prompt
+            # ended, its logits replace that slot's (others are dropped)
+            dest = jnp.where(take, lanes, old_lg.shape[0])
+            return old_lg.at[dest].set(lg, mode="drop"), kp, vp
 
         self._decode_fn = jax.jit(_decode)
         self._prefill_fn = jax.jit(_prefill)
@@ -255,6 +305,13 @@ class GenerationScheduler:
         )
         self._m_prefill_chunks = reg.counter(
             "generate.prefill.chunks", "chunked-prefill programs dispatched"
+        )
+        self._m_prefill_tokens = reg.counter(
+            "generate.prefill.tokens", "prompt tokens dispatched to prefill"
+        )
+        self._m_prefill_padded = reg.counter(
+            "generate.prefill.padded",
+            "token rows of prefill programs that held no prompt token",
         )
         self._m_decode_steps = reg.counter(
             "generate.decode.steps", "continuous decode ticks dispatched"
@@ -600,51 +657,85 @@ class GenerationScheduler:
         self._top_ps[i] = 1.0
         self._min_ps[i] = 0.0
 
-    def _table_width(self) -> int:
-        """Power-of-two block-table width covering every active slot —
-        the bucketed static gather width of the compiled step."""
+    def _table_width(self, lanes: list[int] | None = None) -> int:
+        """Power-of-two block-table width covering the slots ``lanes`` (all
+        of them by default) — the bucketed static gather width of the
+        compiled step."""
         most = 1
-        for s in self._slots:
-            if s is not None and len(s.pages) > most:
+        for i, s in enumerate(self._slots):
+            if s is not None and len(s.pages) > most and (
+                lanes is None or i in lanes
+            ):
                 most = len(s.pages)
         return _pow2_bucket(most, self.pages_per_seq)
 
     def _run_prefill(self, rows: list[int]) -> list[int]:
-        """One fixed-width prefill chunk for every prefilling slot;
-        returns the rows whose prompt completed (now decode-ready)."""
+        """The tick's prefill programs, shaped by what waits
+        (:func:`prefill_shape`): a one-row program for every slot with
+        more left than the narrowest rung holds, oldest request first,
+        then one program shared by the slots with less; returns the rows
+        whose prompt completed (now decode-ready)."""
+        with self._lock:
+            left = {
+                i: s.prompt_len - s.seq_len
+                for i in rows if (s := self._slots[i]) is not None
+            }
+            oldest_first = sorted(
+                left, key=lambda i: self._slots[i].req.submitted_at
+            )
+        finishing: list[int] = []
+        shared: list[int] = []
+        for i in oldest_first:
+            n_rows, width = prefill_shape(left[i], self._ladder, self.slots)
+            if n_rows == 1:
+                finishing += self._prefill_program([i], [i], width)
+            else:
+                shared.append(i)
+        if shared:
+            finishing += self._prefill_program(
+                shared, list(range(self.slots)), self._ladder[0]
+            )
+        return finishing
+
+    def _prefill_program(
+        self, rows: list[int], lanes: list[int], T: int
+    ) -> list[int]:
+        """One prefill program ``[len(lanes), T]``: row ``r`` is slot
+        ``lanes[r]`` and holds that slot's next chunk where the slot is
+        in ``rows``, nothing otherwise.  Returns the slots whose prompt
+        ended."""
         jnp = self._jnp
-        T = self.prefill_chunk
+        R = len(lanes)
         self._next_phase("tick.prefill.prepare", rows=len(rows))
-        ids = np.zeros((self.slots, T), np.int32)
-        chunk_lens = np.zeros(self.slots, np.int32)
-        starts = np.zeros(self.slots, np.int32)
-        take = np.zeros(self.slots, bool)
+        ids = np.zeros((R, T), np.int32)
+        chunk_lens = np.zeros(R, np.int32)
+        starts = np.zeros(R, np.int32)
+        take = np.zeros(R, bool)
         finishing: list[int] = []
         chunked: list[_Slot] = []
         with self._lock:
-            for i in rows:
+            for r, i in enumerate(lanes):
                 slot = self._slots[i]
-                if slot is None:
+                if slot is None or i not in rows:
                     continue
                 done = slot.seq_len
                 n = min(T, slot.prompt_len - done)
                 if n <= 0:
                     continue
                 self._ensure_pages(i, done + n)
-                chunk = slot.req.prompt_ids[done:done + n]
-                ids[i, :n] = chunk
-                chunk_lens[i] = n
-                starts[i] = done
+                ids[r, :n] = slot.req.prompt_ids[done:done + n]
+                chunk_lens[r] = n
+                starts[r] = done
                 chunked.append(slot)
                 slot.seq_len = done + n
                 self._seq_lens[i] = slot.seq_len
                 if slot.seq_len >= slot.prompt_len:
-                    take[i] = True
+                    take[r] = True
                     slot.prefill_done = True
                     finishing.append(i)
-            G = self._table_width()
-            bt = self._block_tables[:, :G].copy()
-        self._next_phase("tick.prefill.enqueue")
+            G = self._table_width(lanes)
+            bt = self._block_tables[lanes, :G]
+        self._next_phase("tick.prefill.enqueue", rows=R, width=T)
         self._enqueued()
         enqueue_started = time.time()
         # asynchronous: the call returns once the chunk is enqueued, its
@@ -652,15 +743,19 @@ class GenerationScheduler:
         self._logits, self._k_pool, self._v_pool = self._prefill_fn(
             self.lm.params, self._k_pool, self._v_pool, jnp.asarray(bt),
             jnp.asarray(ids), jnp.asarray(chunk_lens), jnp.asarray(starts),
-            self._logits, jnp.asarray(take),
+            self._logits, jnp.asarray(lanes, jnp.int32), jnp.asarray(take),
         )
         self._m_prefill_chunks.inc()
+        real = int(chunk_lens.sum())
+        self._m_prefill_tokens.inc(real)
+        self._m_prefill_padded.inc(R * T - real)
         enqueue_s = max(0.0, time.time() - enqueue_started)
         for slot in chunked:
             if slot.prefill_chunks == 0:
                 slot.prefill_started = enqueue_started
             slot.prefill_chunks += 1
             slot.prefill_enqueue_s += enqueue_s
+            slot.prefill_width = max(slot.prefill_width, T)
         return finishing
 
     def _run_decode(self, rows: list[int]) -> None:
@@ -713,6 +808,7 @@ class GenerationScheduler:
                             "generate.prefill", slot.prefill_started,
                             max(0.0, synced - slot.prefill_started),
                             chunks=slot.prefill_chunks,
+                            width=slot.prefill_width,
                             prompt_len=slot.prompt_len,
                             enqueue_s=slot.prefill_enqueue_s,
                         )

@@ -238,9 +238,10 @@ def test_lapsed_queued_request_is_shed_from_queue():
 
 
 def test_chunked_prefill_does_not_stall_short_prompts():
-    """Fairness: while a long prompt prefills in fixed chunks, a short
-    prompt admitted alongside it reaches its first token immediately —
-    the long prompt cannot monopolize the device between decode ticks."""
+    """Fairness: while a long prompt prefills in chunks (here the one
+    rung an explicit ``prefill_chunk=4`` leaves), a short prompt admitted
+    alongside it reaches its first token immediately — the long prompt
+    cannot monopolize the device between decode ticks."""
     lm = _lm()
     sched = generation.GenerationScheduler(
         lm, slots=2, page_size=16, prefill_chunk=4, queue_limit=8
@@ -269,7 +270,13 @@ def test_request_churn_fault_no_head_of_line_blocking():
     """The request_churn chaos pin: a synthetic burst lands mid-long-
     generation, every burst request reaches its first token while the
     long generation is STILL running, and the long request completes
-    untouched."""
+    untouched.
+
+    The bound the pin stands for (ISSUE 28): a waiting prompt holds the
+    others' next decode step up for no longer than ONE program of the
+    widest rung (``PATHWAY_GENERATE_PREFILL_CHUNK`` tokens, one row) per
+    prompt that waits in that tick; the burst's one-token prompts fit the
+    narrowest rung and share ONE ``[slots, narrowest]`` program."""
     lm = _lm()
     faults.install_plan(
         faults.FaultPlan(

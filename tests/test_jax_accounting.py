@@ -141,10 +141,11 @@ def test_paged_decode_churn_records_zero_misses(jitted_encoder):
     """THE continuous-batching pin (ISSUE 18): a churning request mix —
     mixed prompt lengths, admissions into freed slots, chunked prefill
     interleaved with decode — replays WARM compiled programs.  Slot count
-    and prefill width are fixed and the block-table gather width is
-    bucketed to powers of two, so after one warm pass over the trace the
-    same trace (fresh host arrays every tick) must record ZERO cache
-    misses."""
+    is fixed, prefill shapes come from a ladder of at most four widths
+    (one here: an explicit ``prefill_chunk``) and the block-table gather
+    width is bucketed to powers of two, so after one warm pass over the
+    trace the same trace (fresh host arrays every tick) must record ZERO
+    cache misses."""
     del jitted_encoder  # only need the module-scoped accounting install
     from pathway_tpu.models.decoder import shared_decoder
     from pathway_tpu.serving.generation import GenRequest, GenerationScheduler
