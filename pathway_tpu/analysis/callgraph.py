@@ -147,6 +147,7 @@ class Index:
         self._local_imports_cache: dict[
             str, tuple[dict[str, str], dict[str, tuple[str, str]]]
         ] = {}
+        self._own_nodes_cache: dict[int, list[ast.AST]] = {}
         for f in project.files:
             self._index_file(f)
         self._infer_attr_types()
@@ -459,14 +460,20 @@ class Index:
         return env
 
     def _own_nodes(self, func: FuncInfo) -> Iterable[ast.AST]:
-        """Walk ``func``'s body, not descending into nested defs."""
-        stack: list[ast.AST] = list(ast.iter_child_nodes(func.node))
-        while stack:
-            node = stack.pop()
-            if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef, ast.Lambda)):
-                continue
-            yield node
-            stack.extend(ast.iter_child_nodes(node))
+        """``func``'s body, not descending into nested defs.  Walked once a
+        function: the rules ask for it many times a call site."""
+        nodes = self._own_nodes_cache.get(id(func.node))
+        if nodes is None:
+            nodes = []
+            stack: list[ast.AST] = list(ast.iter_child_nodes(func.node))
+            while stack:
+                node = stack.pop()
+                if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef, ast.Lambda)):
+                    continue
+                nodes.append(node)
+                stack.extend(ast.iter_child_nodes(node))
+            self._own_nodes_cache[id(func.node)] = nodes
+        return nodes
 
     def _call_return_class(
         self, call: ast.Call, caller: FuncInfo

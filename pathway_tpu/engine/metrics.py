@@ -337,6 +337,35 @@ METRICS: dict[str, tuple[str, str]] = {
     "generate.decode.steps": (
         "counter", "continuous decode ticks dispatched (one token per "
         "active slot per tick)"),
+    "generate.moe.decode.pairs": (
+        "counter", "token-expert pairs the decode steps computed on the "
+        "experts held here (a model with routed layers; a pair the router "
+        "gave an expert held on another chip is not computed and not "
+        "counted)"),
+    "generate.moe.prefill.pairs": (
+        "counter", "token-expert pairs the prefill programs computed on "
+        "the experts held here"),
+    "generate.moe.decode.experts_hit": (
+        "counter", "held experts that met at least one token, summed over "
+        "the routed layers of every decode step: over generate.decode."
+        "steps and the routed layers, the experts a step reads a layer"),
+    "generate.moe.prefill.experts_hit": (
+        "counter", "held experts that met at least one token, summed over "
+        "the routed layers of every prefill program (divisor: generate."
+        "prefill.chunks x routed layers)"),
+    "generate.kv.pages.global": (
+        "gauge", "pages of the allocator's pool in use: the cache of the "
+        "layers that keep every token (all layers of a model of one kind)"),
+    "generate.kv.pages.window": (
+        "gauge", "ring pages that hold a token, a window layer: a slot's "
+        "ring fills as its sequence grows and stays at ceil(window / page) "
+        "+ 1 pages whatever the context"),
+    "generate.kv.window.pages_released": (
+        "counter", "ring pages that held a token, a window layer, when "
+        "their slot was released"),
+    "generate.kv.window.slots_released": (
+        "counter", "slots released that held a sequence in their ring "
+        "(pages_released over slots_released: ring pages a slot)"),
     "generate.tick.failures": (
         "counter", "generation scheduler ticks that raised; every queued "
         "and active request of the tick was failed with the error"),

@@ -503,6 +503,21 @@ def render_top(
                 f"token(s) · {100.0 * padded / max(real + padded, 1.0):.0f}% "
                 f"of rows padding"
             )
+        moe_pairs = (generation.get("generate.moe.decode.pairs") or 0.0) + (
+            generation.get("generate.moe.prefill.pairs") or 0.0
+        )
+        if moe_pairs:
+            # routed experts (a share of them held here): how much expert
+            # work a token brings, and how many experts a decode step reads
+            tokens = (generation.get("generate.prefill.tokens") or 0.0) + (
+                generation.get("generate.tokens") or 0.0
+            )
+            steps = generation.get("generate.decode.steps") or 0.0
+            hit = generation.get("generate.moe.decode.experts_hit") or 0.0
+            lines.append(
+                f"  experts: {moe_pairs / max(tokens, 1.0):.1f} pair(s) a token "
+                f"· {hit / max(steps, 1.0):.1f} hit a decode step"
+            )
         churn = generation.get("generate.churn.synthetic")
         if churn:
             lines.append(f"  churn: {int(churn)} synthetic burst request(s)")

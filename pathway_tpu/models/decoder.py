@@ -55,6 +55,25 @@ def _bucket_prompt_len(n: int, cap: int) -> int:
 
 
 @dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """What may differ between the layers of one model: a model is a
+    sequence of runs of like layers (``DecoderConfig.layer_runs``), each
+    run a stack scanned by one layer body that its kind parameterises."""
+
+    kv_heads: int
+    # sliding-window attention over the last ``window`` positions (None =
+    # every earlier position); a window layer's paged cache is a ring
+    window: int | None = None
+    rope_theta: float = 10000.0
+    # a learned logit per query head that joins the softmax and carries no
+    # value (the run's ``sink`` leaf)
+    sink: bool = False
+    # routed experts (width ``intermediate`` each) or one dense SwiGLU
+    routed: bool = False
+    intermediate: int = 14336
+
+
+@dataclasses.dataclass(frozen=True)
 class DecoderConfig:
     vocab_size: int = 32000
     hidden: int = 4096
@@ -66,12 +85,21 @@ class DecoderConfig:
     rope_theta: float = 10000.0
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
-    # experts > 0 switches the MLP to Mixtral-style sparse MoE: per-layer
-    # router + stacked expert SwiGLU weights, dispatched by the GShard
-    # machinery in parallel/moe.py (expert axis shardable over the mesh)
+    # experts > 0 switches the MLP to sparse MoE: per-layer router +
+    # stacked expert SwiGLU weights (parallel/moe.py: the capacity-based
+    # GShard dispatch for training, the sorted grouped product for
+    # serving; the expert axis is shardable over the mesh).  It counts the
+    # experts HELD here: the router is ``experts_published`` wide where
+    # that is set (one chip's share of an expert-parallel layer) and this
+    # chip's experts start at ``experts_first``
     experts: int = 0
     experts_top_k: int = 2
     expert_capacity_factor: float = 2.0
+    experts_published: int = 0
+    experts_first: int = 0
+    # "softmax" (Mixtral) or "sigmoid" with a per-expert correction bias
+    # on the choice (``noaux_tc``)
+    experts_scoring: str = "softmax"
     # Mistral-v0.1-style sliding-window attention: each query attends to
     # at most the last `sliding_window` positions (None = full causal)
     sliding_window: int | None = None
@@ -79,10 +107,68 @@ class DecoderConfig:
     # the scan body): activation memory drops from O(layers) to O(1)
     # layers at ~1/3 extra FLOPs — how long-sequence fine-tunes fit HBM
     remat: bool = False
+    # head widths where they are not hidden // heads: query and key heads
+    # ``qk_head_dim`` wide, value heads ``v_head_dim``, rotary on the first
+    # ``rotary_dim`` of a query / key head, values scaled by ``value_scale``
+    qk_head_dim: int | None = None
+    v_head_dim: int | None = None
+    rotary_dim: int | None = None
+    value_scale: float = 1.0
+    # layers of different kinds in one model: ``((kind, count), ...)`` in
+    # layer order.  None: every layer is ``self.kind`` (the fields above).
+    # Only the scheduler's path (paged_decode_step / paged_prefill_chunk)
+    # and the full forward (decoder_layer, causal_lm_logits) take runs
+    runs: tuple[tuple[LayerKind, int], ...] | None = None
 
     @property
     def head_dim(self) -> int:
-        return self.hidden // self.heads
+        return self.qk_head_dim or self.hidden // self.heads
+
+    @property
+    def v_dim(self) -> int:
+        return self.v_head_dim or self.head_dim
+
+    @property
+    def kind(self) -> LayerKind:
+        """The one kind of a model whose layers are all alike."""
+        return LayerKind(
+            kv_heads=self.kv_heads, window=self.sliding_window,
+            rope_theta=self.rope_theta, routed=self.experts > 0,
+            intermediate=self.intermediate,
+        )
+
+    @property
+    def layer_runs(self) -> tuple[tuple[LayerKind, int], ...]:
+        return self.runs or ((self.kind, self.layers),)
+
+    @property
+    def routed_layers(self) -> int:
+        return sum(n for kind, n in self.layer_runs if kind.routed)
+
+
+def run_stacks(cfg: DecoderConfig, layers, *pools):
+    """``(kind, stacked layer params, *that run's pools)`` for each run.
+    A model of one kind keeps its ``tree["layers"]`` dict and its pools as
+    they are; a model with ``cfg.runs`` holds a tuple of each, one entry a
+    run."""
+    if cfg.runs is None:
+        return [(cfg.kind, layers, *pools)]
+    return [
+        (kind, layers[r], *(p[r] for p in pools))
+        for r, (kind, _n) in enumerate(cfg.runs)
+    ]
+
+
+def _require_uniform(cfg: DecoderConfig, what: str) -> None:
+    """The static generation path knows one kind of layer."""
+    if cfg.runs is not None:
+        raise NotImplementedError(
+            f"{what} serves models whose layers are all alike; this "
+            "configuration mixes layer kinds (window and global attention, "
+            "dense and routed FFN) and is served by the scheduler's path: "
+            "GenerationScheduler / JaxChat over paged_prefill_chunk and "
+            "paged_decode_step"
+        )
 
 
 PRESETS: dict[str, DecoderConfig] = {
@@ -94,7 +180,9 @@ PRESETS: dict[str, DecoderConfig] = {
         max_len=2048,
     ),
     # the MoE sibling of the Mistral family the reference's Adaptive RAG
-    # template serves (block-sparse FFN, 8 experts, top-2 routing)
+    # template serves (block-sparse FFN, 8 experts, top-2 routing).  Both
+    # generation paths serve it through parallel/moe.py::moe_serve (softmax
+    # scores, all 8 experts held): sorted pairs, one grouped product
     "mixtral-8x7b-instruct": DecoderConfig(
         rope_theta=1e6, experts=8, experts_top_k=2, max_len=8192,
     ),
@@ -108,12 +196,160 @@ PRESETS: dict[str, DecoderConfig] = {
         intermediate=128, max_len=128, dtype=jnp.float32,
         experts=4, experts_top_k=2,
     ),
+    # every kind of layer MiMo-V2.5 has, tiny (the CPU tests' and the
+    # benchmark's rehearsal preset): pattern G, W, W, W, W, G, W with the
+    # first layer dense; a window shorter than the tests' sequences and
+    # longer than a page; key heads wider than value heads; KV heads that
+    # differ by kind; one of four shares of 16 sigmoid-routed experts
+    # (``TINY_HYBRID_HF`` below, through the reader a ``config.json`` takes)
 }
 
 
+# ``model_type`` values of a ``config.json`` whose keys are the llama
+# family's (one block, ``head_dim = hidden // heads``)
+_LLAMA_TYPES = ("llama", "mistral", "mixtral")
+
+
+def decoder_config_from_hf(hf: dict) -> DecoderConfig:
+    """The shape a ``transformers`` ``config.json`` describes, by its
+    ``model_type``; a type this reader does not know raises, for a
+    llama-shaped model built from whatever keys are found would be another
+    model under this one's name."""
+    model_type = hf.get("model_type")
+    if model_type == "mimo_v2":
+        return _mimo_v2_config(hf)
+    if model_type not in _LLAMA_TYPES:
+        raise ValueError(
+            f"config.json has model_type {model_type!r}; decoder_config_for "
+            f"reads {', '.join(_LLAMA_TYPES)} and mimo_v2"
+        )
+    return DecoderConfig(
+        vocab_size=hf.get("vocab_size", 32000),
+        hidden=hf.get("hidden_size", 4096),
+        layers=hf.get("num_hidden_layers", 32),
+        heads=hf.get("num_attention_heads", 32),
+        kv_heads=hf.get("num_key_value_heads", hf.get("num_attention_heads", 32)),
+        intermediate=hf.get("intermediate_size", 14336),
+        max_len=min(hf.get("max_position_embeddings", 4096), 8192),
+        rope_theta=float(hf.get("rope_theta", 10000.0)),
+        norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
+        experts=hf.get("num_local_experts", 0),
+        experts_top_k=hf.get("num_experts_per_tok", 2),
+        sliding_window=hf.get("sliding_window"),
+    )
+
+
+def _mimo_v2_config(hf: dict) -> DecoderConfig:
+    """``model_type: mimo_v2`` (MiMo-V2-Flash / MiMo-V2.5, language model):
+    window and global attention layers by ``hybrid_layer_pattern``, dense
+    and routed FFNs by ``moe_layer_freq``, key heads wider than value
+    heads, rotary on part of a head, a sink logit in window layers,
+    sigmoid-scored experts chosen with a correction bias.
+
+    ``n_routed_experts`` counts the experts held here; where the file is
+    one chip's share of an expert-parallel layer it states the router's
+    published width as ``n_routed_experts_published`` and which share this
+    is as ``expert_shard_index`` (experts ``index * held`` onwards).  A
+    setting this forward does not implement raises."""
+    unread = {
+        "scoring_func": ("sigmoid",), "topk_method": ("noaux_tc",),
+        "n_group": (1, None), "topk_group": (1, None),
+        "n_shared_experts": (None, 0), "routed_scaling_factor": (None, 1.0),
+        "norm_topk_prob": (True,), "attention_bias": (False, None),
+        "hidden_act": ("silu",), "tie_word_embeddings": (False, None),
+    }
+    for key, allowed in unread.items():
+        if hf.get(key) not in allowed:
+            raise NotImplementedError(
+                f"mimo_v2 config: {key}={hf.get(key)!r} is not implemented "
+                f"(this forward takes {allowed})"
+            )
+    scaling = hf.get("rope_scaling") or {}
+    if scaling.get("rope_type", scaling.get("type", "default")) != "default":
+        raise NotImplementedError(f"mimo_v2 config: rope_scaling {scaling!r}")
+    L, heads, D = hf["num_hidden_layers"], hf["num_attention_heads"], hf["head_dim"]
+    Dv = hf.get("v_head_dim", D)
+    if (hf.get("swa_num_attention_heads", heads), hf.get("swa_head_dim", D),
+            hf.get("swa_v_head_dim", Dv)) != (heads, D, Dv):
+        raise NotImplementedError(
+            "mimo_v2 config: window layers with other query heads or head "
+            "widths than global layers"
+        )
+    pattern, routed = hf["hybrid_layer_pattern"][:L], hf["moe_layer_freq"][:L]
+    if len(pattern) != L or len(routed) != L:
+        raise ValueError(
+            f"mimo_v2 config: {L} layers but hybrid_layer_pattern / "
+            f"moe_layer_freq describe {len(pattern)} / {len(routed)}"
+        )
+    runs: list[tuple[LayerKind, int]] = []
+    for window, moe in zip(pattern, routed):
+        kind = LayerKind(
+            kv_heads=hf["swa_num_key_value_heads" if window else "num_key_value_heads"],
+            window=hf["sliding_window"] if window else None,
+            rope_theta=float(hf["swa_rope_theta" if window else "rope_theta"]),
+            sink=bool(hf.get(
+                "add_swa_attention_sink_bias" if window
+                else "add_full_attention_sink_bias", False
+            )),
+            routed=bool(moe),
+            intermediate=hf["moe_intermediate_size" if moe else "intermediate_size"],
+        )
+        if runs and runs[-1][0] == kind:
+            runs[-1] = (kind, runs[-1][1] + 1)
+        else:
+            runs.append((kind, 1))
+    held = hf["n_routed_experts"]
+    published = hf.get("n_routed_experts_published", held)
+    first = hf.get("expert_shard_index", 0) * held
+    if first + held > published:
+        raise ValueError(
+            f"mimo_v2 config: experts [{first}, {first + held}) of {published}"
+        )
+    return DecoderConfig(
+        vocab_size=hf["vocab_size"], hidden=hf["hidden_size"], layers=L,
+        heads=heads, kv_heads=hf["num_key_value_heads"],
+        intermediate=hf["intermediate_size"],
+        max_len=min(hf.get("max_position_embeddings", 4096), 8192),
+        rope_theta=float(hf["rope_theta"]),
+        norm_eps=float(hf.get("layernorm_epsilon", 1e-5)),
+        dtype=jnp.dtype(hf.get("torch_dtype", "bfloat16")),
+        experts=held, experts_top_k=hf["num_experts_per_tok"],
+        experts_published=published, experts_first=first,
+        experts_scoring="sigmoid",
+        qk_head_dim=D, v_head_dim=Dv,
+        # 0.334 x 192 = 64.1: the even number of dims under it
+        rotary_dim=int(hf.get("partial_rotary_factor", 1.0) * D) // 2 * 2,
+        value_scale=float(hf.get("attention_value_scale") or 1.0),
+        runs=tuple(runs),
+    )
+
+
+# ``chipbench/configs/mimo-v2.5-bge-rag.json``'s ``tiny.decoder`` block is
+# this dictionary (a test holds them equal)
+TINY_HYBRID_HF = {
+    "model_type": "mimo_v2", "vocab_size": 512, "hidden_size": 64,
+    "num_hidden_layers": 7, "num_attention_heads": 4,
+    "num_key_value_heads": 1, "swa_num_key_value_heads": 2,
+    "head_dim": 24, "v_head_dim": 16, "partial_rotary_factor": 0.334,
+    "rope_theta": 10000000, "swa_rope_theta": 10000,
+    "sliding_window": 24, "attention_value_scale": 0.707,
+    "add_full_attention_sink_bias": False, "add_swa_attention_sink_bias": True,
+    "hybrid_layer_pattern": [0, 1, 1, 1, 1, 0, 1],
+    "moe_layer_freq": [0, 1, 1, 1, 1, 1, 1],
+    "intermediate_size": 128, "moe_intermediate_size": 32,
+    "n_routed_experts": 4, "n_routed_experts_published": 16,
+    "expert_shard_index": 0, "num_experts_per_tok": 4,
+    "scoring_func": "sigmoid", "topk_method": "noaux_tc", "n_group": 1,
+    "topk_group": 1, "norm_topk_prob": True, "hidden_act": "silu",
+    "layernorm_epsilon": 1e-05, "max_position_embeddings": 128,
+    "torch_dtype": "float32",
+}
+PRESETS["pw-tiny-hybrid-decoder"] = decoder_config_from_hf(TINY_HYBRID_HF)
+
+
 def decoder_config_for(model_name: str) -> DecoderConfig:
-    """Preset lookup, or the shape read from a local llama-family
-    ``config.json`` (``transformers`` save directory)."""
+    """Preset lookup, or the shape read from a local ``config.json``
+    (``transformers`` save directory) by its ``model_type``."""
     import json
     import os
 
@@ -122,21 +358,7 @@ def decoder_config_for(model_name: str) -> DecoderConfig:
     cfg_path = os.path.join(model_name, "config.json")
     if os.path.isfile(cfg_path):
         with open(cfg_path) as f:
-            hf = json.load(f)
-        return DecoderConfig(
-            vocab_size=hf.get("vocab_size", 32000),
-            hidden=hf.get("hidden_size", 4096),
-            layers=hf.get("num_hidden_layers", 32),
-            heads=hf.get("num_attention_heads", 32),
-            kv_heads=hf.get("num_key_value_heads", hf.get("num_attention_heads", 32)),
-            intermediate=hf.get("intermediate_size", 14336),
-            max_len=min(hf.get("max_position_embeddings", 4096), 8192),
-            rope_theta=float(hf.get("rope_theta", 10000.0)),
-            norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
-            experts=hf.get("num_local_experts", 0),
-            experts_top_k=hf.get("num_experts_per_tok", 2),
-            sliding_window=hf.get("sliding_window"),
-        )
+            return decoder_config_from_hf(json.load(f))
     # an unknown name would otherwise build (and compile) a random 7B —
     # fail loudly instead, a typo should not cost 14 GB and minutes
     raise ValueError(
@@ -175,6 +397,16 @@ def init_decoder_params(cfg: DecoderConfig, seed: int = 0):
     def norm_init(key, shape, fan_in):
         return _norm_init(key, np.float32(np.sqrt(fan_in)), shape, cfg.dtype)
 
+    if cfg.runs is not None:
+        return {
+            "embed": norm_init(keys[0], (cfg.vocab_size, H), H),
+            "final_norm": jnp.ones((H,), cfg.dtype),
+            "lm_head": norm_init(keys[1], (H, cfg.vocab_size), H),
+            "layers": tuple(
+                _init_run(cfg, kind, n, jax.random.fold_in(keys[2], r), norm_init)
+                for r, (kind, n) in enumerate(cfg.runs)
+            ),
+        }
     layers = {
         "ln0": jnp.ones((L, H), cfg.dtype),
         "ln1": jnp.ones((L, H), cfg.dtype),
@@ -211,6 +443,48 @@ def init_decoder_params(cfg: DecoderConfig, seed: int = 0):
     }
 
 
+def _init_run(cfg: DecoderConfig, kind: LayerKind, n: int, key, norm_init):
+    """One run's stacked leaves: the fused ``wqkv`` (queries, then keys,
+    then values, as ``attention_projection_layout: fused_qkv`` lays them),
+    ``wo`` over the value heads, a sink logit per query head where the
+    kind has one (normal x 0.5: not nought, or a test would not see it),
+    and the dense SwiGLU or the router (f32, at its published width, with
+    the ``noaux_tc`` correction bias, normal x 0.02) and the experts HELD
+    (their draw keyed by the first expert's index, so that another share
+    of the layer draws other experts)."""
+    H, NH, D, Dv = cfg.hidden, cfg.heads, cfg.head_dim, cfg.v_dim
+    keys = jax.random.split(key, 8)
+    qkv = NH * D + kind.kv_heads * (D + Dv)
+    run = {
+        "ln0": jnp.ones((n, H), cfg.dtype),
+        "ln1": jnp.ones((n, H), cfg.dtype),
+        "wqkv": norm_init(keys[0], (n, H, qkv), H),
+        "wo": norm_init(keys[1], (n, NH * Dv, H), NH * Dv),
+    }
+    if kind.sink:
+        run["sink"] = 0.5 * jax.random.normal(keys[2], (n, NH), jnp.float32)
+    F = kind.intermediate
+    if not kind.routed:
+        run.update({
+            "wg": norm_init(keys[3], (n, H, F), H),
+            "wu": norm_init(keys[4], (n, H, F), H),
+            "wd": norm_init(keys[5], (n, F, H), F),
+        })
+        return run
+    E, width = cfg.experts, cfg.experts_published or cfg.experts
+    held = [jax.random.fold_in(k, cfg.experts_first) for k in keys[3:6]]
+    run.update({
+        "moe_router": jax.random.normal(keys[6], (n, H, width), jnp.float32)
+        / np.sqrt(H),
+        "wg": norm_init(held[0], (n, E, H, F), H),
+        "wu": norm_init(held[1], (n, E, H, F), H),
+        "wd": norm_init(held[2], (n, E, F, H), F),
+    })
+    if cfg.experts_scoring == "sigmoid":
+        run["moe_bias"] = 0.02 * jax.random.normal(keys[7], (n, width), jnp.float32)
+    return run
+
+
 def tp_param_specs(cfg: DecoderConfig, axis: str = "model"):
     """Tensor-parallel PartitionSpecs: attention heads and FFN width sharded
     over ``axis``; contractions back to hidden leave XLA one all-reduce per
@@ -220,6 +494,7 @@ def tp_param_specs(cfg: DecoderConfig, axis: str = "model"):
     width — each chip owns ``E / |axis|`` whole experts and the GShard
     dispatch/combine einsums lower to ``all_to_all`` (expert parallelism
     in serving)."""
+    _require_uniform(cfg, "tp_param_specs")
     layer_specs = {
         "ln0": P(None, None),
         "ln1": P(None, None),
@@ -310,6 +585,12 @@ def quantize_decoder_tree(tree):
     paths, not HBM-bound matmuls.  Inference-only: training keeps float
     trees.
     """
+    if not isinstance(tree["layers"], dict):
+        raise NotImplementedError(
+            "quantize_decoder_tree takes a model whose layers are all alike "
+            "(the static path's tree); a tree of runs is served float by the "
+            "scheduler's path"
+        )
     quant_names = {"wq", "wk", "wv", "wo", "wg", "wu", "wd"}
     for name in quant_names:
         w = tree["layers"].get(name)
@@ -350,40 +631,75 @@ def _rope(x, positions, theta):
     return out.astype(x.dtype)
 
 
-def _attend(q, k, v, mask, cfg: DecoderConfig):
-    """GQA attention.  q ``[B, S, NH, D]``; k/v ``[B, C, KH, D]``;
-    mask ``[B, S, C]`` boolean (True = attend)."""
-    B, S, NH, D = q.shape
-    KH = k.shape[2]
-    G = NH // KH
-    qg = q.reshape(B, S, KH, G, D)
-    scores = jnp.einsum(
-        "bskgd,bckd->bkgsc", qg, k, preferred_element_type=jnp.float32
-    ) / np.sqrt(D)
-    scores = jnp.where(mask[:, None, None, :, :], scores, -1e9)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    ctx = jnp.einsum("bkgsc,bckd->bskgd", probs, v)
-    return ctx.reshape(B, S, NH * D)
+def _rope_part(x, positions, theta, rot: int | None):
+    """Rotary on the first ``rot`` dims of each head (all of them where
+    ``rot`` is None or the head's width), the rest untouched."""
+    if rot is None or rot == x.shape[-1]:
+        return _rope(x, positions, theta)
+    return jnp.concatenate(
+        [_rope(x[..., :rot], positions, theta), x[..., rot:]], axis=-1
+    )
 
 
-def _ffn(lp, h, cfg: DecoderConfig, *, full_capacity: bool = False):
-    """SwiGLU MLP — dense, or Mixtral-style sparse MoE when
-    ``cfg.experts > 0`` (GShard dispatch from ``parallel/moe.py``; the
-    expert axis of ``wg/wu/wd`` is shardable over a mesh axis, see
-    ``tp_param_specs``).  Returns ``(out, aux)`` with the load-balance
-    auxiliary loss (0 for dense).  ``full_capacity`` selects the lossless
-    dispatch the single-token decode path needs (capacity drops there
-    would silently degrade generations)."""
-    if cfg.experts:
-        from pathway_tpu.parallel.moe import MoEConfig, moe_ffn
+def _qkv(lp, x, positions, cfg: DecoderConfig, kind: LayerKind):
+    """Input norm, projections, rotary, value scale of one layer: ``q
+    [..., NH, D]``, ``k [..., KH, D]``, ``v [..., KH, Dv]`` from the
+    residual stream ``x [..., H]`` (a run of kinds holds the fused
+    ``wqkv``, a model of one kind ``wq`` / ``wk`` / ``wv``)."""
+    lead = x.shape[:-1]
+    KH, D, Dv = kind.kv_heads, cfg.head_dim, cfg.v_dim
+    h = _rms(x, lp["ln0"], cfg.norm_eps)
+    if "wqkv" in lp:
+        nq, nk = cfg.heads * D, KH * D
+        qkv = _mm(h, lp["wqkv"])
+        q = qkv[..., :nq].reshape(*lead, cfg.heads, D)
+        k = qkv[..., nq:nq + nk].reshape(*lead, KH, D)
+        v = qkv[..., nq + nk:].reshape(*lead, KH, Dv)
+    else:
+        q = _mm(h, lp["wq"]).reshape(*lead, cfg.heads, D)
+        k = _mm(h, lp["wk"]).reshape(*lead, KH, D)
+        v = _mm(h, lp["wv"]).reshape(*lead, KH, Dv)
+    q = _rope_part(q, positions, kind.rope_theta, cfg.rotary_dim)
+    k = _rope_part(k, positions, kind.rope_theta, cfg.rotary_dim)
+    if cfg.value_scale != 1.0:
+        v = v * jnp.asarray(cfg.value_scale, v.dtype)
+    return q, k, v
+
+
+def _attend(q, k, v, mask, cfg: DecoderConfig, sink=None):
+    """GQA attention.  q ``[B, S, NH, D]``; k ``[B, C, KH, D]``, v ``[B,
+    C, KH, Dv]``; mask ``[B, S, C]`` boolean (True = attend); ``sink``
+    ``[NH]``, where the layer has one, joins each head's softmax as one
+    more logit that carries no value."""
+    from pathway_tpu.ops.attention import gqa_attention
+
+    return gqa_attention(q, k, v, mask, sink)
+
+
+def _ffn(lp, h, cfg: DecoderConfig, kind: LayerKind | None = None, *,
+         serving: bool = False, valid=None):
+    """SwiGLU MLP — dense, or sparse MoE where the layer's kind is routed
+    (``parallel/moe.py``; the expert axis of ``wg/wu/wd`` is shardable over
+    a mesh axis, see ``tp_param_specs``).  Training (``serving=False``)
+    returns ``(out, aux)`` with the load-balance auxiliary loss of the
+    capacity-based dispatch (0 for dense).  ``serving`` routes without
+    capacity (a drop would silently degrade a generation) and returns
+    ``(out, stats)``: the ``[pairs, experts_hit]`` of ``moe_serve`` over
+    the ``valid`` tokens, or for a dense layer the same 0."""
+    kind = kind or cfg.kind
+    if kind.routed:
+        from pathway_tpu.parallel.moe import MoEConfig, moe_ffn, moe_serve
 
         mcfg = MoEConfig(
             hidden=cfg.hidden,
             experts=cfg.experts,
-            intermediate=cfg.intermediate,
+            intermediate=kind.intermediate,
             top_k=cfg.experts_top_k,
             capacity_factor=cfg.expert_capacity_factor,
             dtype=cfg.dtype,
+            scoring=cfg.experts_scoring,
+            router_width=cfg.experts_published,
+            first_expert=cfg.experts_first,
         )
         params = {
             "router": lp["moe_router"],
@@ -391,74 +707,98 @@ def _ffn(lp, h, cfg: DecoderConfig, *, full_capacity: bool = False):
             "wu": lp["wu"],
             "wd": lp["wd"],
         }
-        return moe_ffn(params, h, mcfg, full_capacity=full_capacity)
+        if "moe_bias" in lp:
+            params["bias"] = lp["moe_bias"]
+        if "moe_layer" in lp:
+            params["layer"] = lp["moe_layer"]
+        if serving:
+            out, pairs, hit = moe_serve(params, h, mcfg, valid)
+            return out, jnp.stack([pairs, hit])
+        if mcfg.scoring != "softmax" or mcfg.router_width not in (0, mcfg.experts):
+            raise NotImplementedError(
+                "training routes by softmax over experts that are all held "
+                "(moe_ffn); a share of sigmoid-routed experts is served, "
+                "not trained"
+            )
+        return moe_ffn(params, h, mcfg)
     with jax.named_scope("mlp.gate_up"):
         gated = jax.nn.silu(_mm(h, lp["wg"])) * _mm(h, lp["wu"])
     with jax.named_scope("mlp.down"):
         return _mm(gated, lp["wd"]), jnp.float32(0.0)
 
 
-def decoder_layer(lp, x, positions, mask, cfg: DecoderConfig, *, full_capacity=False):
-    """One pre-norm transformer block (GQA attention + SwiGLU/MoE MLP).
+def decoder_layer(lp, x, positions, mask, cfg: DecoderConfig,
+                  kind: LayerKind | None = None, *, serving=False):
+    """One pre-norm transformer block (GQA attention + SwiGLU/MoE MLP) of
+    ``kind`` (the model's one kind by default).
 
-    ``lp`` holds a single layer's weights (no leading layer axis).
-    Returns ``(x, (k, v), aux)`` — the new residual stream, this layer's
-    key/value projections ``[B, S, KH, D]``, and the MoE load-balance aux
-    loss (0 for dense).  Shared by the scanned trunk below and the
-    pipeline-parallel stage runner (``parallel/pipeline.py``), so both
-    paths compute identical math.  ``full_capacity`` selects lossless MoE
-    dispatch (serving) vs the capacity-drop policy (training).
+    ``lp`` holds a single layer's weights (no leading layer axis); ``mask``
+    is the caller's, with the kind's window in it.  Returns ``(x, (k, v),
+    aux)`` — the new residual stream, this layer's key/value projections
+    ``[B, S, KH, D]`` / ``[B, S, KH, Dv]``, and the MoE load-balance aux
+    loss (0 for dense; under ``serving`` the routing's ``[pairs,
+    experts_hit]``).  Shared by the scanned trunk below and the pipeline-
+    parallel stage runner (``parallel/pipeline.py``), so both paths compute
+    identical math.  ``serving`` selects lossless MoE routing vs the
+    capacity-drop policy (training).
     """
-    B, S = x.shape[0], x.shape[1]
-    KH, D = cfg.kv_heads, cfg.head_dim
-    h = _rms(x, lp["ln0"], cfg.norm_eps)
-    q = _mm(h, lp["wq"]).reshape(B, S, cfg.heads, D)
-    k = _mm(h, lp["wk"]).reshape(B, S, KH, D)
-    v = _mm(h, lp["wv"]).reshape(B, S, KH, D)
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
-    x = x + _mm(_attend(q, k, v, mask, cfg), lp["wo"])
+    kind = kind or cfg.kind
+    q, k, v = _qkv(lp, x, positions, cfg, kind)
+    x = x + _mm(_attend(q, k, v, mask, cfg, lp.get("sink")), lp["wo"])
     h = _rms(x, lp["ln1"], cfg.norm_eps)
-    mlp, aux = _ffn(lp, h, cfg, full_capacity=full_capacity)
+    mlp, aux = _ffn(lp, h, cfg, kind, serving=serving)
     x = x + mlp
     return x, (k, v), aux
 
 
 def _causal_trunk(
-    tree, ids, lengths, cfg: DecoderConfig, cache_len: int, *, full_capacity=False
+    tree, ids, lengths, cfg: DecoderConfig, cache_len: int, *, serving=False
 ):
-    """Shared causal forward: final-norm token reps + K/V caches."""
+    """Shared causal forward: final-norm token reps + K/V caches (of a
+    model of one kind; a model of runs, whose caches differ by kind, gets
+    None for them: its caches are the scheduler's)."""
     B, S = ids.shape
     x = tree["embed"][ids]  # [B, S, H]
     positions = jnp.arange(S)[None, :].repeat(B, axis=0)
     valid = positions < lengths[:, None]  # [B, S]
     causal = jnp.tril(jnp.ones((S, S), bool))
-    if cfg.sliding_window is not None:
-        # each query sees at most the last `sliding_window` keys
-        causal = causal & _sw_mask(
-            jnp.arange(S)[:, None], jnp.arange(S)[None, :], cfg.sliding_window
-        )
-    mask = causal[None, :, :] & valid[:, None, :]  # [B, S(q), S(kv)]
+    keep_cache = cfg.runs is None
+    k_cache = v_cache = None
+    aux_sum = jnp.float32(0.0)
+    for kind, layers in run_stacks(cfg, tree["layers"]):
+        seen = causal
+        if kind.window is not None:
+            # each query sees at most the last `window` keys
+            seen = seen & _sw_mask(
+                jnp.arange(S)[:, None], jnp.arange(S)[None, :], kind.window
+            )
+        mask = seen[None, :, :] & valid[:, None, :]  # [B, S(q), S(kv)]
 
-    def layer(x, lp):
-        x, (k, v), aux = decoder_layer(
-            lp, x, positions, mask, cfg, full_capacity=full_capacity
-        )
-        # zero K/V beyond each row's real length: decode_step scatters new
-        # entries additively, which requires untouched slots to hold zeros
-        keep = valid[:, :, None, None].astype(k.dtype)
-        pad = ((0, 0), (0, cache_len - S), (0, 0), (0, 0))
-        return x, (jnp.pad(k * keep, pad), jnp.pad(v * keep, pad), aux)
+        def layer(x, lp, kind=kind, mask=mask):
+            x, (k, v), aux = decoder_layer(
+                lp, x, positions, mask, cfg, kind, serving=serving
+            )
+            if not keep_cache:
+                return x, aux
+            # zero K/V beyond each row's real length: decode_step scatters new
+            # entries additively, which requires untouched slots to hold zeros
+            keep = valid[:, :, None, None].astype(k.dtype)
+            pad = ((0, 0), (0, cache_len - S), (0, 0), (0, 0))
+            return x, (jnp.pad(k * keep, pad), jnp.pad(v * keep, pad), aux)
 
-    if cfg.remat:
-        # scan-over-remat: backward recomputes each layer's activations
-        # from its residual-stream input instead of storing them.
-        # prevent_cse=False: safe (and recommended) inside lax.scan, and
-        # skips the optimization barriers that would block layer fusion
-        layer = jax.checkpoint(layer, prevent_cse=False)
-    x, (k_cache, v_cache, auxs) = lax.scan(layer, x, tree["layers"])
+        if cfg.remat:
+            # scan-over-remat: backward recomputes each layer's activations
+            # from its residual-stream input instead of storing them.
+            # prevent_cse=False: safe (and recommended) inside lax.scan, and
+            # skips the optimization barriers that would block layer fusion
+            layer = jax.checkpoint(layer, prevent_cse=False)
+        x, ys = lax.scan(layer, x, layers)
+        if keep_cache:
+            k_cache, v_cache, ys = ys
+        if not serving:
+            aux_sum = aux_sum + ys.sum()
     x = _rms(x, tree["final_norm"], cfg.norm_eps)
-    return x, k_cache, v_cache, auxs.sum()
+    return x, k_cache, v_cache, aux_sum
 
 
 def prefill(tree, ids, lengths, cfg: DecoderConfig, cache_len: int):
@@ -468,10 +808,11 @@ def prefill(tree, ids, lengths, cfg: DecoderConfig, cache_len: int):
     real token and caches of shape ``[L, B, cache_len, KH, D]`` with the
     prompt keys/values written at positions ``[0, S)``.
     """
+    _require_uniform(cfg, "prefill (the static path)")
     # serving path: lossless MoE dispatch — a capacity drop here would
     # corrupt the K/V cache conditioning every later decode step
     x, k_cache, v_cache, _ = _causal_trunk(
-        tree, ids, lengths, cfg, cache_len, full_capacity=True
+        tree, ids, lengths, cfg, cache_len, serving=True
     )
     last = jnp.take_along_axis(
         x, (lengths - 1)[:, None, None].repeat(cfg.hidden, 2), axis=1
@@ -480,21 +821,24 @@ def prefill(tree, ids, lengths, cfg: DecoderConfig, cache_len: int):
     return logits, k_cache, v_cache
 
 
-def causal_lm_logits(tree, ids, lengths, cfg: DecoderConfig):
-    """All-position logits ``[B, S, vocab]`` (f32) for next-token training.
+def causal_lm_logits(tree, ids, lengths, cfg: DecoderConfig, *, serving=False):
+    """All-position logits ``[B, S, vocab]`` (f32) for next-token training,
+    or with ``serving`` the full forward as the serving paths route it (no
+    expert capacity): what the paged programs are held against.
 
     The unused K/V scan outputs are dead code under ``jax.grad``/``jit`` —
     XLA eliminates them, so training pays no cache-materialization cost.
     """
-    return causal_lm_logits_and_aux(tree, ids, lengths, cfg)[0]
+    return causal_lm_logits_and_aux(tree, ids, lengths, cfg, serving=serving)[0]
 
 
-def causal_lm_logits_and_aux(tree, ids, lengths, cfg: DecoderConfig):
+def causal_lm_logits_and_aux(tree, ids, lengths, cfg: DecoderConfig, *, serving=False):
     """``(logits [B, S, vocab] f32, aux)`` — aux is the summed MoE
-    load-balance loss over layers (0 for dense configs); MoE training
-    adds it to the LM loss so routing stays spread over experts."""
+    load-balance loss over layers (0 for dense configs, and under
+    ``serving``); MoE training adds it to the LM loss so routing stays
+    spread over experts."""
     S = ids.shape[1]
-    x, _, _, aux = _causal_trunk(tree, ids, lengths, cfg, S)
+    x, _, _, aux = _causal_trunk(tree, ids, lengths, cfg, S, serving=serving)
     return _mm(x, tree["lm_head"]).astype(jnp.float32), aux
 
 
@@ -505,6 +849,7 @@ def decode_step(tree, k_cache, v_cache, token, pos, cfg: DecoderConfig):
     ``pos``.  Cache capacity is static; ``pos`` is data, so every step of a
     generation reuses the same compiled program.
     """
+    _require_uniform(cfg, "decode_step (the static path)")
     B = token.shape[0]
     C = k_cache.shape[2]
     KH, D = cfg.kv_heads, cfg.head_dim
@@ -529,7 +874,7 @@ def decode_step(tree, k_cache, v_cache, token, pos, cfg: DecoderConfig):
         vc = vc + onehot[:, :, None, None] * v
         x = x + _mm(_attend(q, kc, vc, mask, cfg), lp["wo"])
         h = _rms(x, lp["ln1"], cfg.norm_eps)
-        mlp, _ = _ffn(lp, h, cfg, full_capacity=True)
+        mlp, _ = _ffn(lp, h, cfg, serving=True)
         x = x + mlp
         return x, (kc, vc)
 
@@ -626,6 +971,7 @@ def decode_chunk(
     matching the per-token host loop this replaces.
     """
 
+    _require_uniform(cfg, "decode_chunk (the static path)")
     use_rep = rep_penalty is not None
 
     def body(carry, _):
@@ -688,11 +1034,39 @@ def decode_chunk(
 # state.
 
 
-def init_kv_pool(cfg: DecoderConfig, num_pages: int, page_size: int):
-    """Preallocate the paged KV pool: ``(k_pool, v_pool)``, each
-    ``[L, num_pages, page_size, KH, D]``.  Page 0 is the null page."""
-    shape = (cfg.layers, num_pages, page_size, cfg.kv_heads, cfg.head_dim)
-    return jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype)
+def ring_pages(window: int, page_size: int) -> int:
+    """Pages of a window layer's ring, a slot: the window and one page
+    more, so that a page can be overwritten while the window still reads
+    every token it reaches."""
+    return -(-window // page_size) + 1
+
+
+def uses_ring(cfg: DecoderConfig, kind: LayerKind) -> bool:
+    """A window layer of a model of runs keeps a bounded ring a slot; a
+    model of one kind keeps every token in its block table, windowed or
+    not, as it did."""
+    return cfg.runs is not None and kind.window is not None
+
+
+def init_kv_pool(cfg: DecoderConfig, num_pages: int, page_size: int, slots: int = 0):
+    """Preallocate the paged KV pool: ``(k_pool, v_pool)``, ``[L,
+    num_pages, page_size, KH, D]`` (values ``Dv`` wide).  Page 0 is the
+    null page.  A model of runs gets a tuple of pools, one a run: a global
+    run's like the above, a window run's ``[L, 1 + slots * ring, ...]``
+    with slot ``i``'s ring at pages ``1 + i * ring`` onwards, fixed."""
+    def pool(n, kind):
+        pages = num_pages
+        if uses_ring(cfg, kind):
+            pages = 1 + slots * ring_pages(kind.window, page_size)
+        lead = (n, pages, page_size, kind.kv_heads)
+        return (
+            jnp.zeros(lead + (cfg.head_dim,), cfg.dtype),
+            jnp.zeros(lead + (cfg.v_dim,), cfg.dtype),
+        )
+
+    if cfg.runs is None:
+        return pool(cfg.layers, cfg.kind)
+    return tuple(zip(*(pool(n, kind) for kind, n in cfg.runs)))
 
 
 class PageExhaustedError(RuntimeError):
@@ -772,68 +1146,166 @@ class PageAllocator:
         return self.peak_pages * self.page_size * self.bytes_per_token
 
 
-def kv_bytes_per_token(cfg: DecoderConfig) -> int:
+def kv_bytes_per_token(cfg: DecoderConfig, *, growing_only: bool = False) -> int:
     """K + V bytes one token occupies across all layers — the paged-vs-
-    dense accounting unit."""
+    dense accounting unit.  ``growing_only`` leaves out the layers whose
+    cache is a ring (:func:`uses_ring`): what a page of the allocator's
+    pool holds, a token."""
     itemsize = jnp.dtype(cfg.dtype).itemsize
-    return 2 * cfg.layers * cfg.kv_heads * cfg.head_dim * itemsize
+    return sum(
+        n * kind.kv_heads * (cfg.head_dim + cfg.v_dim) * itemsize
+        for kind, n in cfg.layer_runs
+        if not (growing_only and uses_ring(cfg, kind))
+    )
+
+
+def kv_ring_bytes_per_slot(cfg: DecoderConfig, page_size: int) -> int:
+    """K + V bytes of one slot's rings across the window layers."""
+    itemsize = jnp.dtype(cfg.dtype).itemsize
+    return sum(
+        n * ring_pages(kind.window, page_size) * page_size
+        * kind.kv_heads * (cfg.head_dim + cfg.v_dim) * itemsize
+        for kind, n in cfg.layer_runs if uses_ring(cfg, kind)
+    )
+
+
+def _paged_trunk(tree, k_pool, v_pool, x, cfg: DecoderConfig, *, tables, rings,
+                 positions, write_positions, mask, valid, starts, lens):
+    """The layers of a paged program over the rows ``x [S, T, H]``: every
+    run scanned by the one layer body, which its kind parameterises.
+
+    A layer whose cache is the slot's block table (``tables [S, G]``)
+    scatters its K/V at ``write_positions`` and attends through the gather
+    under ``mask [S, T, G*page]``.  A layer whose cache is the slot's ring
+    (``rings [S, R]``; :func:`uses_ring`) attends to what the ring held
+    before this program (``starts [S]`` tokens) and to the program's own
+    rows, by position, then writes the last of its ``lens [S]`` rows that
+    the ring keeps.  ``valid [S, T]`` marks the rows that hold a token.
+    Returns ``(x, k_pool, v_pool, stats)``; ``stats`` is the routed
+    layers' summed ``[pairs, experts_hit]`` (noughts without routed layers).
+    """
+    from pathway_tpu.ops import attention as attention_ops
+
+    def body(kind, experts):
+        ring = uses_ring(cfg, kind)
+        scope = "attn.paged" if cfg.runs is None else (
+            "attn.window" if ring else "attn.global"
+        )
+        if ring:
+            # by position, and the same for every layer of the run
+            cap = rings.shape[1] * jax.tree_util.tree_leaves(k_pool)[0].shape[2]
+            ring_sees = attention_ops.ring_mask(
+                starts, positions, valid, kind.window, cap
+            )
+            ring_at = attention_ops.ring_write_positions(positions, valid, lens, cap)
+
+        def layer(x, lp):
+            lp, kp, vp, *index = lp
+            if experts:
+                lp = {**lp, **experts, "moe_layer": index[0]}
+            with jax.named_scope("attn.qkv"):
+                q, k, v = _qkv(lp, x, positions, cfg, kind)
+            if ring:
+                with jax.named_scope(scope):
+                    ctx = attention_ops.ring_gqa_attention(
+                        q, k, v, kp, vp, rings, ring_sees, lp.get("sink")
+                    )
+                with jax.named_scope("kv.write"):
+                    kp = attention_ops.scatter_kv_pages(kp, rings, ring_at, k)
+                    vp = attention_ops.scatter_kv_pages(vp, rings, ring_at, v)
+            else:
+                with jax.named_scope("kv.write"):
+                    kp = attention_ops.scatter_kv_pages(kp, tables, write_positions, k)
+                    vp = attention_ops.scatter_kv_pages(vp, tables, write_positions, v)
+                with jax.named_scope(scope):
+                    ctx = attention_ops.paged_gqa_attention(
+                        q, kp, vp, tables, mask, lp.get("sink")
+                    )
+            with jax.named_scope("attn.out"):
+                x = x + _mm(ctx, lp["wo"])
+            h = _rms(x, lp["ln1"], cfg.norm_eps)
+            mlp, stats = _ffn(lp, h, cfg, kind, serving=True, valid=valid)
+            if kind.routed:
+                return x + mlp, (kp, vp, stats)
+            return x + mlp, (kp, vp)
+
+        return layer
+
+    k_out, v_out, stats = [], [], jnp.zeros((2,), jnp.int32)
+    for kind, layers, kp, vp in run_stacks(cfg, tree["layers"], k_pool, v_pool):
+        xs, experts = (layers, kp, vp), {}
+        if kind.routed:
+            # the run's expert stacks stay whole and the body is told its
+            # layer's index in them (``moe_serve`` says why)
+            experts = {name: layers[name] for name in ("wg", "wu", "wd")}
+            rest = {k: v for k, v in layers.items() if k not in experts}
+            xs = (rest, kp, vp, jnp.arange(kp.shape[0], dtype=jnp.int32))
+        x, ys = lax.scan(body(kind, experts), x, xs)
+        k_out.append(ys[0])
+        v_out.append(ys[1])
+        if kind.routed:
+            stats = stats + ys[2].sum(0)
+    if cfg.runs is None:
+        return x, k_out[0], v_out[0], stats
+    return x, tuple(k_out), tuple(v_out), stats
+
+
+def _split_tables(cfg: DecoderConfig, block_tables):
+    """``(tables, rings)``: a model of runs is handed both, a model of one
+    kind its block tables alone."""
+    return block_tables if cfg.runs is not None else (block_tables, None)
 
 
 def paged_decode_step(tree, k_pool, v_pool, block_tables, seq_lens, token,
-                      cfg: DecoderConfig):
+                      cfg: DecoderConfig, *, active=None, with_stats=False):
     """One generation step over paged KV: ``token`` ``[S]`` is written at
     each slot's next position (``seq_lens`` ``[S]``), attention gathers
-    the slot's pages.  Returns ``(logits [S, V], k_pool, v_pool)``.
+    the slot's pages.  Returns ``(logits [S, V], k_pool, v_pool)``, and
+    with ``with_stats`` the routed layers' ``[pairs, experts_hit]`` after
+    them.
 
     Shape-identical math to ``decode_step`` (pinned by tests): the
     gathered context is just the dense cache rearranged through the block
     table, and masked positions contribute exactly zero either way.
     Inactive slots (block table all null) write into and gather from the
     null page — finite garbage, masked everywhere, freeing the scheduler
-    from shipping an active-mask into the program.
+    from shipping an active-mask into the program.  A model of runs
+    (``cfg.runs``) takes ``block_tables`` as ``(tables, rings)`` and the
+    mask ``active [S]`` of the slots that decode: a slot's ring is its own
+    whether it decodes or not, so a slot that does not must not write, and
+    the routed layers count and compute the active rows only.
     """
-    from pathway_tpu.ops import attention as attention_ops
-
+    tables, rings = _split_tables(cfg, block_tables)
     S = token.shape[0]
-    page = k_pool.shape[2]
-    C = block_tables.shape[1] * page
-    KH, D = cfg.kv_heads, cfg.head_dim
+    page = jax.tree_util.tree_leaves(k_pool)[0].shape[2]
+    C = tables.shape[1] * page
     x = tree["embed"][token][:, None, :]  # [S, 1, H]
     positions = seq_lens[:, None]  # [S, 1]
     idx = jnp.arange(C)[None, None, :]
     mask = idx <= seq_lens[:, None, None]  # [S, 1, C]
     if cfg.sliding_window is not None:
         mask = mask & _sw_mask(seq_lens[:, None, None], idx, cfg.sliding_window)
-
-    def layer(x, lp):
-        lp, kp, vp = lp
-        with jax.named_scope("attn.qkv"):
-            h = _rms(x, lp["ln0"], cfg.norm_eps)
-            q = _mm(h, lp["wq"]).reshape(S, 1, cfg.heads, D)
-            k = _mm(h, lp["wk"]).reshape(S, 1, KH, D)
-            v = _mm(h, lp["wv"]).reshape(S, 1, KH, D)
-            q = _rope(q, positions, cfg.rope_theta)
-            k = _rope(k, positions, cfg.rope_theta)
-        with jax.named_scope("kv.write"):
-            kp = attention_ops.scatter_kv_pages(kp, block_tables, positions, k)
-            vp = attention_ops.scatter_kv_pages(vp, block_tables, positions, v)
-        with jax.named_scope("attn.paged"):
-            ctx = attention_ops.paged_gqa_attention(q, kp, vp, block_tables, mask)
-        with jax.named_scope("attn.out"):
-            x = x + _mm(ctx, lp["wo"])
-        h = _rms(x, lp["ln1"], cfg.norm_eps)
-        mlp, _ = _ffn(lp, h, cfg, full_capacity=True)
-        return x + mlp, (kp, vp)
-
-    x, (k_pool, v_pool) = lax.scan(layer, x, (tree["layers"], k_pool, v_pool))
+    if active is None and rings is not None:
+        active = jnp.ones((S,), bool)
+    valid = None if active is None else active[:, None]
+    write_positions = positions
+    if active is not None:
+        write_positions = jnp.where(valid, positions, jnp.int32(2**30))
+    x, k_pool, v_pool, stats = _paged_trunk(
+        tree, k_pool, v_pool, x, cfg, tables=tables, rings=rings,
+        positions=positions, write_positions=write_positions, mask=mask,
+        valid=valid, starts=seq_lens, lens=jnp.ones((S,), jnp.int32),
+    )
     with jax.named_scope("lm_head"):
         x = _rms(x, tree["final_norm"], cfg.norm_eps)
         logits = _mm(x[:, 0, :], tree["lm_head"]).astype(jnp.float32)
+    if with_stats:
+        return logits, k_pool, v_pool, stats
     return logits, k_pool, v_pool
 
 
 def paged_prefill_chunk(tree, k_pool, v_pool, block_tables, chunk_ids,
-                        chunk_lens, start, cfg: DecoderConfig):
+                        chunk_lens, start, cfg: DecoderConfig, *, with_stats=False):
     """Prefill ONE chunk of each slot's prompt against paged KV.
 
     ``chunk_ids`` ``[S, T]`` holds the next ``chunk_lens[s]`` prompt
@@ -844,6 +1316,7 @@ def paged_prefill_chunk(tree, k_pool, v_pool, block_tables, chunk_ids,
     prefill split along the query axis.  Returns ``(logits [S, V]`` at
     each slot's LAST chunk token``, k_pool, v_pool)``; rows with
     ``chunk_lens == 0`` produce garbage logits the scheduler ignores.
+    ``with_stats`` adds the routed layers' ``[pairs, experts_hit]``.
 
     ``S`` and ``T`` are compile-time sizes and the scheduler chooses them
     from a few (``serving/generation.py::prefill_shape``): one row as
@@ -851,13 +1324,14 @@ def paged_prefill_chunk(tree, k_pool, v_pool, block_tables, chunk_ids,
     prompt longer than the widest runs several chunks instead of one
     variable program, which is what lets the scheduler interleave prefill
     with decode without a long decode-tick stall (and without recompiles).
+    A row may be wider than a window layer's ring: its queries attend
+    inside the row and to what the ring held, and only the row's last
+    tokens, those the ring keeps, are written (``_paged_trunk``).
     """
-    from pathway_tpu.ops import attention as attention_ops
-
-    S, T = chunk_ids.shape
-    page = k_pool.shape[2]
-    C = block_tables.shape[1] * page
-    KH, D = cfg.kv_heads, cfg.head_dim
+    tables, rings = _split_tables(cfg, block_tables)
+    T = chunk_ids.shape[1]
+    page = jax.tree_util.tree_leaves(k_pool)[0].shape[2]
+    C = tables.shape[1] * page
     x = tree["embed"][chunk_ids]  # [S, T, H]
     positions = start[:, None] + jnp.arange(T)[None, :]  # [S, T]
     valid_q = jnp.arange(T)[None, :] < chunk_lens[:, None]  # [S, T]
@@ -870,28 +1344,11 @@ def paged_prefill_chunk(tree, k_pool, v_pool, block_tables, chunk_ids,
     mask = (idx <= positions[:, :, None]) & valid_q[:, :, None]
     if cfg.sliding_window is not None:
         mask = mask & _sw_mask(positions[:, :, None], idx, cfg.sliding_window)
-
-    def layer(x, lp):
-        lp, kp, vp = lp
-        with jax.named_scope("attn.qkv"):
-            h = _rms(x, lp["ln0"], cfg.norm_eps)
-            q = _mm(h, lp["wq"]).reshape(S, T, cfg.heads, D)
-            k = _mm(h, lp["wk"]).reshape(S, T, KH, D)
-            v = _mm(h, lp["wv"]).reshape(S, T, KH, D)
-            q = _rope(q, positions, cfg.rope_theta)
-            k = _rope(k, positions, cfg.rope_theta)
-        with jax.named_scope("kv.write"):
-            kp = attention_ops.scatter_kv_pages(kp, block_tables, write_positions, k)
-            vp = attention_ops.scatter_kv_pages(vp, block_tables, write_positions, v)
-        with jax.named_scope("attn.paged"):
-            ctx = attention_ops.paged_gqa_attention(q, kp, vp, block_tables, mask)
-        with jax.named_scope("attn.out"):
-            x = x + _mm(ctx, lp["wo"])
-        h = _rms(x, lp["ln1"], cfg.norm_eps)
-        mlp, _ = _ffn(lp, h, cfg, full_capacity=True)
-        return x + mlp, (kp, vp)
-
-    x, (k_pool, v_pool) = lax.scan(layer, x, (tree["layers"], k_pool, v_pool))
+    x, k_pool, v_pool, stats = _paged_trunk(
+        tree, k_pool, v_pool, x, cfg, tables=tables, rings=rings,
+        positions=positions, write_positions=write_positions, mask=mask,
+        valid=valid_q, starts=start, lens=chunk_lens,
+    )
     with jax.named_scope("lm_head"):
         x = _rms(x, tree["final_norm"], cfg.norm_eps)
         last = jnp.take_along_axis(
@@ -900,6 +1357,8 @@ def paged_prefill_chunk(tree, k_pool, v_pool, block_tables, chunk_ids,
             axis=1,
         )[:, 0, :]
         logits = _mm(last, tree["lm_head"]).astype(jnp.float32)
+    if with_stats:
+        return logits, k_pool, v_pool, stats
     return logits, k_pool, v_pool
 
 
@@ -915,6 +1374,7 @@ def verify_block(tree, k_cache, v_cache, tokens, pos0, cfg: DecoderConfig):
     decoding (all K target-model logits for the draft block at the cost
     of one matmul sweep instead of K).
     """
+    _require_uniform(cfg, "verify_block (the static path)")
     B, K = tokens.shape
     C = k_cache.shape[2]
     KH, D = cfg.kv_heads, cfg.head_dim
@@ -943,7 +1403,7 @@ def verify_block(tree, k_cache, v_cache, tokens, pos0, cfg: DecoderConfig):
         vc = vc + jnp.einsum("bkcx,bkhd->bchd", onehot, v)
         x = x + _mm(_attend(q, kc, vc, mask, cfg), lp["wo"])
         h = _rms(x, lp["ln1"], cfg.norm_eps)
-        mlp, _ = _ffn(lp, h, cfg, full_capacity=True)
+        mlp, _ = _ffn(lp, h, cfg, serving=True)
         x = x + mlp
         return x, (kc, vc)
 
@@ -995,6 +1455,7 @@ def speculative_decode_chunk(
     positions) writes nothing for positions >= C, so overflow writes are
     no-ops by construction.
     """
+    _require_uniform(cfg, "speculative_decode_chunk (the static path)")
     B = logits.shape[0]
     C = k_cache.shape[2]
 

@@ -182,14 +182,14 @@ def gather_kv_pages(pool, block_tables):
     return g.reshape(S, G * pool.shape[1], pool.shape[2], pool.shape[3])
 
 
-def paged_gqa_attention(q, k_pool, v_pool, block_tables, mask):
-    """GQA attention against paged KV: q ``[S, T, NH, D]``, pools
-    ``[P, page, KH, D]``, block_tables ``[S, G]``, mask ``[S, T, G*page]``
-    boolean (True = attend).  Same math as the dense decode path
-    (``models/decoder.py::_attend``) over the gathered context, so paged
-    and dense generations agree token-for-token."""
-    k = gather_kv_pages(k_pool, block_tables)  # [S, C, KH, D]
-    v = gather_kv_pages(v_pool, block_tables)
+def gqa_attention(q, k, v, mask, sink=None):
+    """Grouped-query attention over a contiguous context: q ``[S, T, NH,
+    D]``, k ``[S, C, KH, D]``, v ``[S, C, KH, Dv]`` (value heads may be
+    narrower than key heads), mask ``[S, T, C]`` boolean (True = attend).
+    ``sink`` ``[NH]``, where given, is a learned logit per query head that
+    joins the softmax and carries no value: ``p_ij = exp(s_ij) / (exp(b_h)
+    + sum_j' exp(s_ij'))``.  Softmax in float32.  Returns ``[S, T, NH *
+    Dv]``."""
     S, T, NH, D = q.shape
     KH = k.shape[2]
     G = NH // KH
@@ -198,9 +198,81 @@ def paged_gqa_attention(q, k_pool, v_pool, block_tables, mask):
         "stkgd,sckd->skgtc", qg, k, preferred_element_type=jnp.float32
     ) / (D**0.5)
     scores = jnp.where(mask[:, None, None, :, :], scores, -1e9)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    if sink is None:
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    else:
+        logit = jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(1, KH, G, 1, 1), scores.shape[:-1] + (1,)
+        )
+        probs = jax.nn.softmax(jnp.concatenate([scores, logit], axis=-1), axis=-1)
+        probs = probs[..., :-1].astype(q.dtype)
     ctx = jnp.einsum("skgtc,sckd->stkgd", probs, v)
-    return ctx.reshape(S, T, NH * D)
+    return ctx.reshape(S, T, NH * v.shape[-1])
+
+
+def paged_gqa_attention(q, k_pool, v_pool, block_tables, mask, sink=None):
+    """GQA attention against paged KV: q ``[S, T, NH, D]``, pools
+    ``[P, page, KH, D]``, block_tables ``[S, G]``, mask ``[S, T, G*page]``
+    boolean (True = attend).  Same math as the dense decode path
+    (``models/decoder.py::_attend``) over the gathered context, so paged
+    and dense generations agree token-for-token."""
+    k = gather_kv_pages(k_pool, block_tables)  # [S, C, KH, D]
+    v = gather_kv_pages(v_pool, block_tables)
+    return gqa_attention(q, k, v, mask, sink)
+
+
+def ring_mask(starts, positions, valid, window: int, cap: int):
+    """Which of a slot's ring entries and of a program's own rows each row
+    of the program attends to: ``[S, T, cap + T]`` boolean.
+
+    A window layer keeps, a slot, ``R`` pages of its pool as a ring of
+    ``cap = R * page`` entries: the token at position ``p`` lives at entry
+    ``p % cap``, so the ring holds the last ``cap`` tokens and never
+    grows.  Before this program the slot had written ``starts [S]``
+    tokens, so entry ``j`` holds position ``j + cap * ((starts - 1 - j) //
+    cap)`` (nothing yet where ``j >= starts``).  The program's rows sit at
+    ``positions [S, T]`` (``valid`` marks those that hold a token) and may
+    be more than the ring holds: each attends, causally and inside
+    ``window``, to the ring's entries and the program's own rows by their
+    positions.  The same for every window layer of a program."""
+    entry = jnp.arange(cap, dtype=starts.dtype)[None, :]
+    held = entry + cap * ((starts[:, None] - 1 - entry) // cap)
+    nowhere = jnp.int32(-(2**30))
+    key_pos = jnp.concatenate(
+        [
+            jnp.where(entry < starts[:, None], held, nowhere),
+            jnp.where(valid, positions, -nowhere),
+        ],
+        axis=1,
+    )[:, None, :]  # [S, 1, cap + T]
+    q_pos = positions[:, :, None]
+    return (key_pos <= q_pos) & (key_pos > q_pos - window) & valid[:, :, None]
+
+
+def ring_gqa_attention(q, k_new, v_new, k_pool, v_pool, rings, mask, sink=None):
+    """Sliding-window attention of a program's rows ``q`` / ``k_new`` /
+    ``v_new`` ``[S, T, ...]`` against each slot's ring (the pages ``rings
+    [S, R]`` of this layer's pools, as they were before this program) and
+    the rows themselves, under ``mask`` (:func:`ring_mask`).  The caller
+    writes the rows the ring keeps afterwards (:func:`ring_write_positions`),
+    so nothing a row still needs has been overwritten."""
+    k_old = gather_kv_pages(k_pool, rings)  # [S, cap, KH, D]
+    v_old = gather_kv_pages(v_pool, rings)
+    return gqa_attention(
+        q, jnp.concatenate([k_old, k_new], axis=1),
+        jnp.concatenate([v_old, v_new], axis=1), mask, sink,
+    )
+
+
+def ring_write_positions(positions, valid, lens, cap: int):
+    """Where :func:`scatter_kv_pages` writes a program's rows into a ring
+    of ``cap`` entries: row ``t`` of a slot's ``lens [S]`` rows at entry
+    ``position % cap`` if it is among the slot's last ``cap`` (an earlier
+    row would be overwritten by a later one of the same program), else,
+    like a row that holds no token, past the table: the null page."""
+    t = jnp.arange(positions.shape[1], dtype=lens.dtype)[None, :]
+    keep = valid & (t >= lens[:, None] - cap)
+    return jnp.where(keep, positions % cap, jnp.int32(2**30))
 
 
 def scatter_kv_pages(pool, block_tables, positions, values):

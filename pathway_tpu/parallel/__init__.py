@@ -56,6 +56,7 @@ from pathway_tpu.parallel.moe import (
     make_ep_mesh,
     make_moe_train_step,
     moe_ffn,
+    moe_serve,
 )
 from pathway_tpu.parallel.pipeline import (
     make_pipelined_causal_lm,
@@ -90,6 +91,7 @@ __all__ = [
     "make_ep_mesh",
     "make_moe_train_step",
     "moe_ffn",
+    "moe_serve",
     "make_pp_mesh",
     "pp_param_specs",
     "place_pp_params",

@@ -11,10 +11,14 @@ exchange to ``all_to_all`` over ICI from the sharding annotations alone
 
 Design points, all MXU/XLA-motivated:
 
-* **Static capacity.**  Each expert processes a fixed ``capacity`` of
-  token slots per batch; overflow tokens are dropped from that expert
-  (their residual stream passes through unchanged).  Static shapes keep
-  the whole layer one compiled program — no data-dependent reshapes.
+* **Static capacity (training, ``moe_ffn``).**  Each expert processes a
+  fixed ``capacity`` of token slots per batch; overflow tokens are dropped
+  from that expert (their residual stream passes through unchanged).
+  Static shapes keep the whole layer one compiled program.
+* **No capacity (serving, ``moe_serve``).**  The (token, expert) pairs
+  that fall on the experts this chip holds are sorted by expert and go
+  through one grouped product (``jax.lax.ragged_dot``): no pair is
+  dropped and no expert is multiplied by a token it was not given.
 * **Top-k routing with renormalised gates** (k=2 default, the
   Mixtral/GShard setting): the combine weights of the selected experts
   are renormalised to sum to 1, so with identical experts the layer
@@ -53,13 +57,17 @@ class MoEConfig:
     # tensor stays LINEAR in the total token count (C scales with Tg, not
     # T).  0 disables grouping (one global group).
     group_size: int = 4096
-    # the lossless serving path (full_capacity=True) sets C = Tg, making
-    # the dispatch/combine tensors [G, Tg, E, Tg] — quadratic in the
-    # group size.  Serving therefore uses this smaller group (and maps
-    # over groups one at a time) so large-batch MoE prefill cannot
-    # pressure HBM; 0 falls back to group_size.
-    serving_group_size: int = 1024
     dtype: Any = jnp.float32
+    # how a router logit becomes a score: "softmax" over all experts
+    # (Mixtral, GShard) or "sigmoid" of each (DeepSeek-V3-style
+    # ``noaux_tc``, where a learned per-expert correction bias joins the
+    # score for the CHOICE only, never the weight)
+    scoring: str = "softmax"
+    # serving (``moe_serve``) on one chip's share of an expert-parallel
+    # layer: the router is ``router_width`` wide (0 = ``experts``) and this
+    # chip holds experts ``[first_expert, first_expert + experts)`` of them
+    router_width: int = 0
+    first_expert: int = 0
 
     def capacity(self, n_tokens: int) -> int:
         """Static per-expert token slots for an ``n_tokens`` group."""
@@ -167,10 +175,9 @@ def moe_ffn(
     x: jnp.ndarray,
     cfg: MoEConfig,
     mesh: Mesh | None = None,
-    *,
-    full_capacity: bool = False,
 ):
-    """MoE feed-forward over tokens ``x [..., H]`` → ``(y [..., H], aux)``.
+    """MoE feed-forward over tokens ``x [..., H]`` → ``(y [..., H], aux)``,
+    the training form: static capacity, overflow dropped.
 
     Pure function of sharded inputs: under ``jit`` with ``ep_param_specs``
     placements, the ``gtec,gth->gech`` dispatch einsum (token-sharded ×
@@ -181,29 +188,16 @@ def moe_ffn(
 
     Tokens beyond the group size are chunked into GShard groups and
     dispatched group-locally (one ragged tail group padded and masked),
-    keeping dispatch memory linear in the token count.
-    ``full_capacity=True`` gives every token guaranteed slots — capacity
-    ``C = Tg`` per group, which no expert can exceed, still linear in the
-    token count (``T·E·Tg`` dispatch elements).  The serving paths
-    (prefill and single-token decode) use it: capacity drops there would
-    silently degrade generations.  Because ``C = Tg`` makes the per-group
-    tensors quadratic in the group size, serving uses the smaller
-    ``cfg.serving_group_size`` and processes groups one at a time
-    (``lax.map``), bounding transient HBM to a single group.  Training
-    keeps the capacity-factor drop policy (and the fully vmapped groups),
-    which is what makes routing learnable under a static budget.
+    keeping dispatch memory linear in the token count.  The capacity-
+    factor drop policy is what makes routing learnable under a static
+    budget; serving, where a drop would silently degrade a generation,
+    goes through :func:`moe_serve`.
     """
     orig_shape = x.shape
     H = orig_shape[-1]
     xt = x.reshape(-1, H)
     T = xt.shape[0]
     group_size = cfg.group_size
-    if full_capacity and cfg.serving_group_size:
-        group_size = (
-            min(group_size, cfg.serving_group_size)
-            if group_size
-            else cfg.serving_group_size
-        )
     if not group_size or T <= group_size:
         G, Tg = 1, T
     else:
@@ -212,50 +206,145 @@ def moe_ffn(
     pad = G * Tg - T
     if pad:
         xt = jnp.concatenate([xt, jnp.zeros((pad, H), xt.dtype)], axis=0)
-    C = Tg if full_capacity else cfg.capacity(Tg)
+    C = cfg.capacity(Tg)
     xg = xt.reshape(G, Tg, H)
     router_logits = xg.astype(jnp.float32) @ params["router"]  # [G, Tg, E]
     valid = (jnp.arange(G * Tg) < T).reshape(G, Tg)
 
-    def groups_ffn(router_logits, valid, xg):
-        """Dispatch → expert FFN → combine, vectorized over the leading
-        group axis; returns (y [G, Tg, H], aux [G])."""
-        dispatch, combine, aux_g = jax.vmap(
-            lambda lg, vg: _routing(lg, cfg, C, vg)
-        )(router_logits, valid)
-        dispatch = dispatch.astype(cfg.dtype)
-        expert_in = jnp.einsum("gtec,gth->gech", dispatch, xg.astype(cfg.dtype))
-        if mesh is not None and "expert" in mesh.axis_names:
-            expert_in = jax.lax.with_sharding_constraint(
-                expert_in, NamedSharding(mesh, P(None, "expert", None, None))
-            )
-        h = jax.nn.silu(_qeinsum("gech,ehf->gecf", expert_in, params["wg"]))
-        h = h * _qeinsum("gech,ehf->gecf", expert_in, params["wu"])
-        expert_out = _qeinsum("gecf,efh->gech", h, params["wd"])
-        if mesh is not None and "expert" in mesh.axis_names:
-            expert_out = jax.lax.with_sharding_constraint(
-                expert_out, NamedSharding(mesh, P(None, "expert", None, None))
-            )
-        y = jnp.einsum("gtec,gech->gth", combine.astype(cfg.dtype), expert_out)
-        return y, aux_g
-
-    if full_capacity and G > 1:
-        # one group live at a time: the [Tg, E, Tg] serving dispatch
-        # tensors never materialize for all groups together
-        y_g, aux_g = jax.lax.map(
-            lambda a: jax.tree_util.tree_map(
-                lambda t: t[0], groups_ffn(a[0][None], a[1][None], a[2][None])
-            ),
-            (router_logits, valid, xg),
+    dispatch, combine, aux_g = jax.vmap(
+        lambda lg, vg: _routing(lg, cfg, C, vg)
+    )(router_logits, valid)
+    dispatch = dispatch.astype(cfg.dtype)
+    expert_in = jnp.einsum("gtec,gth->gech", dispatch, xg.astype(cfg.dtype))
+    if mesh is not None and "expert" in mesh.axis_names:
+        expert_in = jax.lax.with_sharding_constraint(
+            expert_in, NamedSharding(mesh, P(None, "expert", None, None))
         )
-    else:
-        y_g, aux_g = groups_ffn(router_logits, valid, xg)
+    h = jax.nn.silu(_qeinsum("gech,ehf->gecf", expert_in, params["wg"]))
+    h = h * _qeinsum("gech,ehf->gecf", expert_in, params["wu"])
+    expert_out = _qeinsum("gecf,efh->gech", h, params["wd"])
+    if mesh is not None and "expert" in mesh.axis_names:
+        expert_out = jax.lax.with_sharding_constraint(
+            expert_out, NamedSharding(mesh, P(None, "expert", None, None))
+        )
+    y_g = jnp.einsum("gtec,gech->gth", combine.astype(cfg.dtype), expert_out)
 
     # aux: weighted mean over groups by their real-token counts
     w = valid.astype(jnp.float32).sum(axis=1)
     aux = (aux_g * w).sum() / jnp.maximum(w.sum(), 1.0)
     y = y_g.reshape(G * Tg, H)[:T]
     return y.reshape(orig_shape).astype(x.dtype), aux
+
+
+def route(router_logits: jnp.ndarray, cfg: MoEConfig, bias=None):
+    """The experts each token chooses and the weight each gets: ``(idx
+    [T, K] int32, weights [T, K] f32)`` from f32 logits ``[T, E]`` over the
+    whole router width.  Scores are a softmax over the experts or a sigmoid
+    of each (``cfg.scoring``); the ``top_k`` largest of score + ``bias``
+    (the ``noaux_tc`` correction, ``[E]``, where the model has one) are
+    chosen, and the chosen experts' scores, without the bias, are
+    renormalised to sum to 1."""
+    if cfg.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(router_logits)
+    else:
+        scores = jax.nn.softmax(router_logits, axis=-1)
+    choice = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, idx = jax.lax.top_k(choice, cfg.top_k)
+    picked = jnp.take_along_axis(scores, idx, axis=-1)
+    return idx, picked / jnp.maximum(picked.sum(-1, keepdims=True), 1e-9)
+
+
+def _grouped(x, w, rows_expert, group_sizes):
+    """``x [M, A]`` rows, sorted by expert, each times its own expert's
+    ``w [E, A, B]`` (a float weight or an int8 weight-only pair)."""
+    if isinstance(w, dict) and "q" in w:
+        out = jax.lax.ragged_dot(x, w["q"].astype(x.dtype), group_sizes)
+        return out * w["s"].astype(x.dtype)[rows_expert, 0]
+    return jax.lax.ragged_dot(x, w, group_sizes)
+
+
+def _one_stack(w):
+    """``[n, E, ...]`` (every layer's experts) as ``[n * E, ...]``: no copy."""
+    return jax.tree_util.tree_map(lambda t: t.reshape((-1,) + t.shape[2:]), w)
+
+
+def moe_serve(params, x: jnp.ndarray, cfg: MoEConfig, valid=None):
+    """MoE feed-forward for serving: ``x [..., H]`` → ``(y [..., H],
+    pairs, experts_hit)``.
+
+    Every token is routed over the whole router width (``params["router"]``
+    ``[H, router_width]`` f32, ``params["bias"]`` optional); of its
+    ``top_k`` (token, expert) pairs those that fall on the experts held
+    here (``cfg.first_expert`` and the ``cfg.experts`` after it, the
+    leading axis of ``wg`` / ``wu`` / ``wd``) are sorted by expert and go
+    through one grouped product, then are summed back per token with the
+    router's weights.  No capacity, no dropped pair; what the experts held
+    elsewhere would add is left out (on one chip of an expert-parallel
+    layer that partial sum is the layer's result here, and with every
+    expert held it is the whole).  ``valid [...]`` marks real tokens:
+    padding takes no expert.  ``pairs`` counts the pairs computed here and
+    ``experts_hit`` the held experts that met at least one token (int32
+    scalars, for the scheduler's counters).
+
+    Inside a scan over stacked layers ``wg`` / ``wu`` / ``wd`` may be the
+    whole stacks ``[n, E, ...]`` with ``params["layer"]`` the layer's index
+    in them: the grouped product then runs over all ``n * E`` experts with
+    every other layer's groups empty.  It cannot read a layer's experts
+    through the scan's own slice of the stack: the product is a custom
+    call on the chip, and XLA copies all of a layer's experts for it, hit
+    or not, every step (three 537 MB copies a layer at MiMo-V2.5's widths).
+    """
+    orig_shape = x.shape
+    H = orig_shape[-1]
+    xt = x.reshape(-1, H)
+    T, K, E = xt.shape[0], cfg.top_k, cfg.experts
+    with jax.named_scope("moe.route"):
+        # float32 all the way: at the default precision the chip would
+        # multiply in bfloat16, and a choice between two experts whose
+        # scores nearly tie would turn on that rounding
+        logits = jnp.matmul(
+            xt.astype(jnp.float32), params["router"],
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        idx, weights = route(logits, cfg, params.get("bias"))
+        local = idx - cfg.first_expert
+        here = (local >= 0) & (local < E)
+        if valid is not None:
+            here = here & valid.reshape(-1)[:, None]
+        # pairs held elsewhere sort behind every held expert's rows
+        flat = jnp.where(here, local, E).reshape(-1)
+        order = jnp.argsort(flat)
+        rows_expert = flat[order]
+        group_sizes = jnp.sum(
+            flat[:, None] == jnp.arange(E, dtype=flat.dtype)[None, :], axis=0,
+            dtype=jnp.int32,
+        )
+        xs = xt[order // K]  # [T*K, H], a token's row once per pair
+    with jax.named_scope("moe.experts"):
+        wg, wu, wd = params["wg"], params["wu"], params["wd"]
+        rows, sizes = rows_expert, group_sizes
+        if "layer" in params:
+            layers = jax.tree_util.tree_leaves(wg)[0].shape[0]
+            wg, wu, wd = _one_stack(wg), _one_stack(wu), _one_stack(wd)
+            rows = params["layer"] * E + rows_expert
+            sizes = (
+                jnp.zeros((layers, E), jnp.int32).at[params["layer"]].set(group_sizes)
+            ).reshape(-1)
+        gate = _grouped(xs, wg, rows, sizes)
+        up = _grouped(xs, wu, rows, sizes)
+        out = _grouped(jax.nn.silu(gate) * up, wd, rows, sizes)
+        # rows past the groups are pairs held elsewhere: whatever the
+        # grouped product left there is not read
+        out = jnp.where((rows_expert < E)[:, None], out, 0)
+        back = jnp.argsort(order)  # undo the sort: row t*K + k again
+        # the weighted sum in float32, as the weights are
+        y = jnp.einsum(
+            "tkh,tk->th", out[back].reshape(T, K, H).astype(jnp.float32),
+            jnp.where(here, weights, 0.0),
+        )
+    pairs = jnp.sum(here, dtype=jnp.int32)
+    experts_hit = jnp.sum(group_sizes > 0, dtype=jnp.int32)
+    return y.reshape(orig_shape).astype(x.dtype), pairs, experts_hit
 
 
 def make_ep_mesh(n_devices: int, expert_parallel: int | None = None) -> Mesh:

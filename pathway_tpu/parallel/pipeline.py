@@ -105,7 +105,7 @@ def _stage_forward(stage_layers, x, valid, cfg: DecoderConfig):
     def body(x, lp):
         # the pipelined trunk is a serving path (MoE training under pp is
         # rejected), so MoE dispatch runs lossless
-        x, _, _ = decoder_layer(lp, x, positions, mask, cfg, full_capacity=True)
+        x, _, _ = decoder_layer(lp, x, positions, mask, cfg, serving=True)
         return x, None
 
     if cfg.remat:

@@ -228,14 +228,38 @@ class GenerationScheduler:
                 self.slots * self.pages_per_seq // 2, self.pages_per_seq
             ) + 1
         self.num_pages = n_pages
-        bytes_per_token = dec.kv_bytes_per_token(self.cfg)
-        self.dense_kv_bytes = self.slots * self.max_cache * bytes_per_token
+        # two kinds of cache in one manager: the layers whose cache grows
+        # with the sequence take pages from the allocator and are counted
+        # by the token; a window layer of a model of runs keeps a ring a
+        # slot (``dec.uses_ring``), fixed here and counted by the slot
+        self.dense_kv_bytes = (
+            self.slots * self.max_cache * dec.kv_bytes_per_token(self.cfg)
+        )
         self.allocator = dec.PageAllocator(
-            self.num_pages, self.page_size, bytes_per_token
+            self.num_pages, self.page_size,
+            dec.kv_bytes_per_token(self.cfg, growing_only=True),
         )
         self._k_pool, self._v_pool = dec.init_kv_pool(
-            self.cfg, self.num_pages, self.page_size
+            self.cfg, self.num_pages, self.page_size, self.slots
         )
+        self._hybrid = self.cfg.runs is not None
+        windows = {
+            k.window for k, _n in self.cfg.layer_runs if dec.uses_ring(self.cfg, k)
+        }
+        if len(windows) > 1:
+            raise NotImplementedError(
+                f"window layers of several sizes ({sorted(windows)}): one ring "
+                "table a slot serves one window"
+            )
+        self.ring_pages = (
+            dec.ring_pages(windows.pop(), self.page_size) if windows else 0
+        )
+        self.ring_bytes_per_slot = dec.kv_ring_bytes_per_slot(self.cfg, self.page_size)
+        # slot i's ring: pages 1 + i * ring onwards of every window run's
+        # pool, for as long as the scheduler lives
+        self._ring_tables = 1 + np.arange(
+            self.slots * self.ring_pages, dtype=np.int32
+        ).reshape(self.slots, self.ring_pages)
 
         import jax
         import jax.numpy as jnp
@@ -252,8 +276,16 @@ class GenerationScheduler:
         self._min_ps = np.zeros(self.slots, np.float32)
 
         cfg = self.cfg
+        # a model of runs, or with routed experts, is told which slots
+        # decode (``active``) and hands back its routing's counts: the
+        # decode step's ``[pairs, experts_hit]`` and those the prefill
+        # programs since the last step carried forward ride behind the
+        # tokens, in the one array the tick syncs anyway
+        self._counted = counted = self._hybrid or self.cfg.routed_layers > 0
+        self._no_stats = jnp.zeros((2,), jnp.int32)
+        self._prefill_stats = self._no_stats
 
-        def _decode(tree, kp, vp, bt, sl, lg, key, temp, top_p, min_p):
+        def _decode(tree, kp, vp, bt, sl, lg, key, temp, top_p, min_p, *counts):
             with jax.named_scope("sample"):
                 greedy_tok = jnp.argmax(lg, axis=-1).astype(jnp.int32)
                 sampled = dec.sample_logits(
@@ -261,17 +293,26 @@ class GenerationScheduler:
                     top_p=top_p[:, None], min_p=min_p[:, None],
                 )
                 tok = jnp.where(temp > 0.0, sampled, greedy_tok)
-            lg2, kp, vp = dec.paged_decode_step(tree, kp, vp, bt, sl, tok, cfg)
-            return tok, lg2, kp, vp
+            if not counted:
+                lg2, kp, vp = dec.paged_decode_step(tree, kp, vp, bt, sl, tok, cfg)
+                return tok, lg2, kp, vp
+            active, carried = counts
+            lg2, kp, vp, stats = dec.paged_decode_step(
+                tree, kp, vp, bt, sl, tok, cfg, active=active, with_stats=True
+            )
+            return jnp.concatenate([tok, stats, carried]), lg2, kp, vp
 
-        def _prefill(tree, kp, vp, bt, ids, cl, st, old_lg, lanes, take):
-            lg, kp, vp = dec.paged_prefill_chunk(
-                tree, kp, vp, bt, ids, cl, st, cfg
+        def _prefill(tree, kp, vp, bt, ids, cl, st, old_lg, lanes, take, *carried):
+            lg, kp, vp, *stats = dec.paged_prefill_chunk(
+                tree, kp, vp, bt, ids, cl, st, cfg, with_stats=counted
             )
             # row r of the program is slot ``lanes[r]``: where its prompt
             # ended, its logits replace that slot's (others are dropped)
             dest = jnp.where(take, lanes, old_lg.shape[0])
-            return old_lg.at[dest].set(lg, mode="drop"), kp, vp
+            out = old_lg.at[dest].set(lg, mode="drop"), kp, vp
+            if counted:
+                out += (carried[0] + stats[0],)
+            return out
 
         self._decode_fn = jax.jit(_decode)
         self._prefill_fn = jax.jit(_prefill)
@@ -281,6 +322,7 @@ class GenerationScheduler:
         self._slots: list[_Slot | None] = [None] * self.slots
         self._running = False
         self._thread: threading.Thread | None = None
+        self._peak_active = 0  # most slots taken at once: each holds its rings
         self._churn_ttfts: list[float] = []
         self._tokens_total = 0
         self._tick_failures = 0
@@ -315,6 +357,25 @@ class GenerationScheduler:
         )
         self._m_decode_steps = reg.counter(
             "generate.decode.steps", "continuous decode ticks dispatched"
+        )
+        pairs_help = "token-expert pairs computed on the experts held here"
+        hit_help = (
+            "held experts that met a token, summed over routed layers and programs"
+        )
+        # in the order they ride behind a decode step's tokens
+        self._m_moe = [
+            reg.counter("generate.moe.decode.pairs", pairs_help),
+            reg.counter("generate.moe.decode.experts_hit", hit_help),
+            reg.counter("generate.moe.prefill.pairs", pairs_help),
+            reg.counter("generate.moe.prefill.experts_hit", hit_help),
+        ]
+        self._m_window_pages_released = reg.counter(
+            "generate.kv.window.pages_released",
+            "ring pages that held a token, a window layer, when their slot was released",
+        )
+        self._m_window_slots_released = reg.counter(
+            "generate.kv.window.slots_released",
+            "slots released that held a sequence in their ring",
         )
         self._m_ttft = reg.histogram(
             "generate.ttft.ms", "request submit -> first token (ms)",
@@ -610,6 +671,7 @@ class GenerationScheduler:
                 )
             slot = _Slot(req)
             self._slots[i] = slot
+            self._peak_active = max(self._peak_active, self.slots - len(free))
             self._block_tables[i, :] = 0
             self._seq_lens[i] = 0
             self._temps[i] = req.temperature
@@ -650,12 +712,34 @@ class GenerationScheduler:
             return
         unreserve = max(slot.req.pages_reserved - len(slot.pages), 0)
         self.allocator.release(slot.pages, unreserve=unreserve)
+        if self.ring_pages and slot.seq_len:
+            self._m_window_pages_released.inc(
+                min(self.ring_pages, self.allocator.pages_for(slot.seq_len))
+            )
+            self._m_window_slots_released.inc()
         self._slots[i] = None
         self._block_tables[i, :] = 0
         self._seq_lens[i] = 0
         self._temps[i] = 0.0
         self._top_ps[i] = 1.0
         self._min_ps[i] = 0.0
+
+    def _tables(self, block_tables: np.ndarray, lanes=slice(None)):
+        """The tables a paged program takes for the slots ``lanes``: their
+        block tables, and for a model of runs their rings beside them."""
+        jnp = self._jnp
+        if not self._hybrid:
+            return jnp.asarray(block_tables)
+        return jnp.asarray(block_tables), jnp.asarray(self._ring_tables[lanes])
+
+    def _ring_pages_in_use(self) -> int:
+        """Ring pages that hold a token, a window layer: a slot's ring
+        fills as its sequence grows and then stays at its size.  Runs
+        under the lock."""
+        return sum(
+            min(self.ring_pages, self.allocator.pages_for(s.seq_len))
+            for s in self._slots if s is not None and s.seq_len
+        )
 
     def _table_width(self, lanes: list[int] | None = None) -> int:
         """Power-of-two block-table width covering the slots ``lanes`` (all
@@ -734,17 +818,21 @@ class GenerationScheduler:
                     slot.prefill_done = True
                     finishing.append(i)
             G = self._table_width(lanes)
-            bt = self._block_tables[lanes, :G]
+            bt = self._tables(self._block_tables[lanes, :G], lanes)
         self._next_phase("tick.prefill.enqueue", rows=R, width=T)
         self._enqueued()
         enqueue_started = time.time()
         # asynchronous: the call returns once the chunk is enqueued, its
         # work ends with the next sync (``_run_decode``)
-        self._logits, self._k_pool, self._v_pool = self._prefill_fn(
-            self.lm.params, self._k_pool, self._v_pool, jnp.asarray(bt),
+        out = self._prefill_fn(
+            self.lm.params, self._k_pool, self._v_pool, bt,
             jnp.asarray(ids), jnp.asarray(chunk_lens), jnp.asarray(starts),
             self._logits, jnp.asarray(lanes, jnp.int32), jnp.asarray(take),
+            *((self._prefill_stats,) if self._counted else ()),
         )
+        self._logits, self._k_pool, self._v_pool = out[:3]
+        if self._counted:
+            self._prefill_stats = out[3]
         self._m_prefill_chunks.inc()
         real = int(chunk_lens.sum())
         self._m_prefill_tokens.inc(real)
@@ -774,13 +862,19 @@ class GenerationScheduler:
             temps = self._temps.copy()
             top_ps = self._top_ps.copy()
             min_ps = self._min_ps.copy()
+        counts = ()
+        if self._counted:
+            active = np.zeros(self.slots, bool)
+            active[rows] = True
+            counts = (jnp.asarray(active), self._prefill_stats)
+            self._prefill_stats = self._no_stats
         self._key, sub = jax.random.split(self._key)
         self._next_phase("tick.decode.enqueue")
         self._enqueued()
         tok, self._logits, self._k_pool, self._v_pool = self._decode_fn(
-            self.lm.params, self._k_pool, self._v_pool, jnp.asarray(bt),
+            self.lm.params, self._k_pool, self._v_pool, self._tables(bt),
             jnp.asarray(sl), self._logits, sub, jnp.asarray(temps),
-            jnp.asarray(top_ps), jnp.asarray(min_ps),
+            jnp.asarray(top_ps), jnp.asarray(min_ps), *counts,
         )
         self._m_decode_steps.inc()
         self._next_phase("tick.decode.sync")
@@ -789,6 +883,14 @@ class GenerationScheduler:
         synced = time.time()
         self._drained()
         self._next_phase("tick.deliver")
+        prefill_pairs = 0
+        if self._counted:
+            # behind the slots' tokens: this step's and the carried prefill
+            # programs' [pairs, experts_hit]
+            counts = [int(n) for n in htok[self.slots:]]
+            for counter, n in zip(self._m_moe, counts):
+                counter.inc(n)
+            prefill_pairs = counts[2]
         eos = self.lm.eos_id
         produced = 0
         with self._lock:
@@ -811,6 +913,10 @@ class GenerationScheduler:
                             width=slot.prefill_width,
                             prompt_len=slot.prompt_len,
                             enqueue_s=slot.prefill_enqueue_s,
+                            # routed pairs of the prefill programs that
+                            # ended with this sync (this prompt's, where
+                            # one prompt prefilled at a time)
+                            pairs=prefill_pairs,
                         )
                     slot.prefill_started = None
                 if req.first_token_at is None:
@@ -864,16 +970,24 @@ class GenerationScheduler:
         span = (now - window[0][0]) if len(window) > 1 else 0.0
         a = self.allocator
         with self._lock:
+            active = sum(1 for s in self._slots if s is not None)
             return {
-                "generate.slots.active": float(
-                    sum(1 for s in self._slots if s is not None)
-                ),
+                "generate.slots.active": float(active),
+                # pages by kind of cache: the allocator's (layers that keep
+                # every token) and the rings' (a window layer's, a slot)
+                "generate.kv.pages.global": float(a.used_pages),
+                "generate.kv.pages.window": float(self._ring_pages_in_use()),
                 "generate.slots.total": float(self.slots),
                 "generate.queue.depth": float(len(self._queue)),
                 "generate.pages.used": float(a.used_pages),
                 "generate.pages.total": float(self.num_pages - 1),
-                "generate.kv.bytes.live": float(a.live_bytes),
-                "generate.kv.bytes.peak": float(a.peak_bytes),
+                # a taken slot's rings are whole: fixed memory
+                "generate.kv.bytes.live": float(
+                    a.live_bytes + active * self.ring_bytes_per_slot
+                ),
+                "generate.kv.bytes.peak": float(
+                    a.peak_bytes + self._peak_active * self.ring_bytes_per_slot
+                ),
                 # what the dense slots x max_cache layout would hold resident
                 "generate.kv.bytes.dense": float(self.dense_kv_bytes),
                 # sustained decode throughput over the last 5 s
@@ -897,8 +1011,11 @@ class GenerationScheduler:
                 "pages_total": self.num_pages - 1,
                 "pages_used": self.allocator.used_pages,
                 "pages_reserved": self.allocator.reserved,
-                "kv_bytes_live": self.allocator.live_bytes,
-                "kv_bytes_peak": self.allocator.peak_bytes,
+                "ring_pages_per_slot": self.ring_pages,
+                "kv_bytes_live": self.allocator.live_bytes
+                + active * self.ring_bytes_per_slot,
+                "kv_bytes_peak": self.allocator.peak_bytes
+                + self._peak_active * self.ring_bytes_per_slot,
                 "kv_bytes_dense": self.dense_kv_bytes,
                 "tokens_total": self._tokens_total,
                 "tick_failures": self._tick_failures,

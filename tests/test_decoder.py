@@ -297,6 +297,7 @@ def test_hf_config_dir_roundtrip(tmp_path):
     (d / "config.json").write_text(
         json.dumps(
             dict(
+                model_type="llama",
                 vocab_size=1000,
                 hidden_size=128,
                 num_hidden_layers=3,

@@ -24,6 +24,7 @@ from pathway_tpu.parallel.moe import (
     make_ep_mesh,
     make_moe_train_step,
     moe_ffn,
+    moe_serve,
 )
 
 
@@ -123,19 +124,20 @@ def test_grouped_dispatch_matches_single_group():
     assert np.isfinite(float(aux_grouped))
 
 
-def test_full_capacity_never_drops():
-    # capacity_factor tiny, but full_capacity=True guarantees every token
-    # its experts — identical experts must still reproduce the dense FFN
+def test_serving_never_drops():
+    # capacity_factor tiny: the training form drops most tokens, the
+    # serving form (sorted pairs, one grouped product) has no capacity —
+    # identical experts must still reproduce the dense FFN
     cfg = MoEConfig(hidden=8, experts=4, intermediate=16, top_k=2,
                     capacity_factor=0.1)
     params = init_moe_params(cfg, seed=10)
     for name in ("wg", "wu", "wd"):
         params[name] = jnp.broadcast_to(params[name][:1], params[name].shape)
     x = jax.random.normal(jax.random.PRNGKey(11), (24, 8), jnp.float32)
-    y, _ = moe_ffn(params, x, cfg, full_capacity=True)
+    y, pairs, hit = moe_serve(params, x, cfg)
     want = _dense_swiglu(x, params["wg"][0], params["wu"][0], params["wd"][0])
     np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=1e-5, atol=1e-5)
-    # without full capacity the same config drops most tokens
+    assert int(pairs) == 24 * 2 and 1 <= int(hit) <= 4
     y_drop, _ = moe_ffn(params, x, cfg)
     assert not np.allclose(np.asarray(y_drop), np.asarray(want), atol=1e-3)
 
@@ -154,19 +156,29 @@ def test_aux_loss_prefers_uniform_routing():
     assert float(aux_collapsed) == pytest.approx(4.0, abs=1e-2)
 
 
-def test_serving_group_map_matches_single_group():
-    # full_capacity serving path: the smaller serving group + lax.map over
-    # groups must reproduce the one-group result exactly (lossless — no
-    # token can overflow C = Tg regardless of grouping)
-    import dataclasses
-
-    base = MoEConfig(hidden=8, experts=4, intermediate=16, top_k=2,
-                     group_size=0, serving_group_size=0)
-    mapped = dataclasses.replace(base, serving_group_size=7)  # 5 groups via lax.map
-    params = init_moe_params(base, seed=12)
+def test_serving_matches_training_form_with_room():
+    # with capacity to spare nothing is dropped, and the two forms are the
+    # same mathematics: softmax top-2, renormalised, every expert held
+    cfg = MoEConfig(hidden=8, experts=4, intermediate=16, top_k=2,
+                    capacity_factor=8.0)
+    params = init_moe_params(cfg, seed=12)
     x = jax.random.normal(jax.random.PRNGKey(13), (32, 8), jnp.float32)
-    y_single, _ = moe_ffn(params, x, base, full_capacity=True)
-    y_mapped, _ = moe_ffn(params, x, mapped, full_capacity=True)
+    y_train, _ = moe_ffn(params, x, cfg)
+    y_serve, pairs, _hit = moe_serve(params, x, cfg)
     np.testing.assert_allclose(
-        np.asarray(y_mapped), np.asarray(y_single), rtol=1e-5, atol=1e-5
+        np.asarray(y_serve), np.asarray(y_train), rtol=1e-5, atol=1e-5
     )
+    assert int(pairs) == 64
+
+
+def test_serving_padding_takes_no_expert():
+    # rows that hold no token are routed nowhere: not counted, not computed
+    cfg = MoEConfig(hidden=8, experts=4, intermediate=16, top_k=2)
+    params = init_moe_params(cfg, seed=14)
+    x = jax.random.normal(jax.random.PRNGKey(15), (2, 6, 8), jnp.float32)
+    valid = jnp.arange(6)[None, :] < jnp.asarray([6, 2])[:, None]
+    y, pairs, _hit = moe_serve(params, x, cfg, valid)
+    assert int(pairs) == (6 + 2) * 2
+    assert np.all(np.asarray(y)[1, 2:] == 0.0)
+    y_all, _, _ = moe_serve(params, x, cfg)
+    np.testing.assert_allclose(np.asarray(y)[0], np.asarray(y_all)[0], atol=1e-6)
