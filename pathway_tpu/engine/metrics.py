@@ -335,8 +335,20 @@ METRICS: dict[str, tuple[str, str]] = {
         "token (rows x width dispatched less generate.prefill.tokens): "
         "arithmetic spent on padding"),
     "generate.decode.steps": (
-        "counter", "continuous decode ticks dispatched (one token per "
-        "active slot per tick)"),
+        "counter", "continuous decode steps dispatched (one token per "
+        "decoding slot per step)"),
+    "generate.decode.overlapped": (
+        "counter", "decode steps enqueued while the step before was still "
+        "unread: the device ran through the host's part of those ticks "
+        "(over generate.decode.steps: the share of steps that ran ahead)"),
+    "generate.decode.wasted": (
+        "counter", "row-steps computed for a row that had already ended: "
+        "its token read a step late was EOS, or its deadline evicted it, "
+        "with the next step already enqueued; that step's token is dropped"),
+    "generate.decode.tick.ms": (
+        "histogram", "return of one decode read to the return of the next "
+        "while the scheduler kept decoding (the device did not drain in "
+        "between): the inter-token interval a request is served at"),
     "generate.moe.decode.pairs": (
         "counter", "token-expert pairs the decode steps computed on the "
         "experts held here (a model with routed layers; a pair the router "

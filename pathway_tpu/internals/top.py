@@ -503,6 +503,21 @@ def render_top(
                 f"token(s) · {100.0 * padded / max(real + padded, 1.0):.0f}% "
                 f"of rows padding"
             )
+        steps = generation.get("generate.decode.steps") or 0.0
+        if steps:
+            # the decode step runs one ahead of the host's read: how many
+            # did, what that cost in steps for rows that had ended, and the
+            # inter-token interval it leaves
+            ahead = generation.get("generate.decode.overlapped") or 0.0
+            wasted = generation.get("generate.decode.wasted") or 0.0
+            row = (
+                f"  decode: {int(steps)} step(s) · {100.0 * ahead / steps:.0f}% "
+                f"ran ahead · {int(wasted)} wasted"
+            )
+            tick = generation.get("generate.decode.tick.ms.p50")
+            if tick is not None:
+                row += f" · p50 {tick:.1f} ms a token"
+            lines.append(row)
         moe_pairs = (generation.get("generate.moe.decode.pairs") or 0.0) + (
             generation.get("generate.moe.prefill.pairs") or 0.0
         )
@@ -512,7 +527,6 @@ def render_top(
             tokens = (generation.get("generate.prefill.tokens") or 0.0) + (
                 generation.get("generate.tokens") or 0.0
             )
-            steps = generation.get("generate.decode.steps") or 0.0
             hit = generation.get("generate.moe.decode.experts_hit") or 0.0
             lines.append(
                 f"  experts: {moe_pairs / max(tokens, 1.0):.1f} pair(s) a token "

@@ -29,6 +29,18 @@ that loop with the vLLM/Ragged-Paged-Attention serving shape (PAPERS.md):
 * **Deadlines** — requests carry the PR 17 :class:`engine.serving
   .Deadline`; a row that lapses mid-generation is shed at the next tick
   and counted under ``serve.deadline.exceeded{where=decode}``.
+* **The decode step runs one ahead of the host's read** — a step samples
+  its token on the device from logits that never leave it, so the host
+  needs step N's tokens only to hand them out and to see an EOS.  A tick
+  therefore enqueues step N+1 first and reads step N while N+1 runs: the
+  device works through the host's part of a tick instead of waiting for
+  it.  The depth is one, always.  A row that ends by count is not given
+  a step it cannot use; a row whose token turns out to be EOS, or that
+  its deadline evicted, has been given one step too many, and that
+  step's token is dropped (``generate.decode.wasted``).  A step carries
+  the requests it decoded for, so a slot released and taken again
+  meanwhile never receives the stale token, and the newcomer's prefill,
+  later in the device's order, overwrites what the stale step wrote.
 
 Every device program has a static shape: slot count fixed, prefill
 rows and width one of the few :func:`prefill_ladder` allows, block-table
@@ -44,7 +56,7 @@ import logging
 import threading
 import time
 from concurrent.futures import Future
-from typing import Any
+from typing import Any, NamedTuple
 
 import numpy as np
 
@@ -163,7 +175,8 @@ class _Slot:
     def __init__(self, req: GenRequest):
         self.req = req
         self.pages: list[int] = []
-        self.seq_len = 0  # tokens written into the paged cache
+        # tokens written into the paged cache, or enqueued to be written
+        self.seq_len = 0
         self.prompt_len = len(req.prompt_ids)
         self.prefill_done = False
         # the request's ``generate.prefill`` span, closed at the first
@@ -176,11 +189,22 @@ class _Slot:
         self.prefill_width = 0
 
 
+class _Step(NamedTuple):
+    """A decode step that is enqueued and not read yet."""
+
+    tok: Any  # its tokens, on the device
+    rows: list[tuple[int, GenRequest]]  # (slot index, request) it decoded for
+    # which of the scheduler's programs it was: its read drains the device
+    # if none was enqueued after it
+    program: int
+
+
 class GenerationScheduler:
     """Continuous-batching scheduler for one :class:`DecoderLM`.
 
     A dedicated worker thread runs the tick loop: evict → admit →
-    chunked prefill → one decode step → deliver.  ``submit_ids`` /
+    chunked prefill → enqueue the next decode step → read and deliver
+    the one before it.  ``submit_ids`` /
     ``submit`` are thread-safe and return ``concurrent.futures.Future``;
     the async serving edge (``JaxChat``) awaits them via
     ``asyncio.wrap_future``.
@@ -333,8 +357,14 @@ class GenerationScheduler:
         # that found the device drained to the return of the sync that
         # drains it again, with the programs enqueued meanwhile
         self._inflight = None
-        self._inflight_programs = 0
+        self._programs = 0  # enqueued since the scheduler was built
+        self._inflight_from = 0  # and when the open interval began
         self._phase = None  # the running tick's open phase on the timeline
+        # the decode step that runs ahead of the host's read, one at most
+        self._step: _Step | None = None
+        # when the last decode read returned, while the device has not
+        # drained since: the start of an inter-token interval
+        self._last_read_at: float | None = None
 
         from pathway_tpu.engine import metrics as em
 
@@ -357,6 +387,19 @@ class GenerationScheduler:
         )
         self._m_decode_steps = reg.counter(
             "generate.decode.steps", "continuous decode ticks dispatched"
+        )
+        self._m_decode_overlapped = reg.counter(
+            "generate.decode.overlapped",
+            "decode steps enqueued while the one before was still unread",
+        )
+        self._m_decode_wasted = reg.counter(
+            "generate.decode.wasted",
+            "row-steps computed for a row that had already ended",
+        )
+        self._m_decode_tick = reg.histogram(
+            "generate.decode.tick.ms",
+            "return of one decode read -> return of the next (ms)",
+            buckets=em.MS_BUCKETS,
         )
         pairs_help = "token-expert pairs computed on the experts held here"
         hit_help = (
@@ -498,6 +541,7 @@ class GenerationScheduler:
                     self._running
                     and not self._queue
                     and all(s is None for s in self._slots)
+                    and self._step is None
                 ):
                     if idle is None:
                         idle = tracing.begin("sched", "sched.idle")
@@ -518,7 +562,6 @@ class GenerationScheduler:
                     len(self._queue) + sum(s is not None for s in self._slots),
                 )
                 self._fail_all(exc)
-                self._drained(failed=True)
 
     def shutdown(self) -> None:
         """Stop the worker; queued/active requests fail rather than hang."""
@@ -535,6 +578,11 @@ class GenerationScheduler:
         _blackbox.get_recorder().set_generation_supplier(None)
 
     def _fail_all(self, exc: BaseException) -> None:
+        """Fail every queued and active request.  A step in flight has no
+        one left to deliver to: it is let go unread, and nothing is waited
+        for any more."""
+        self._step = None
+        self._drained(failed=True)
         with self._lock:
             victims = [r for r in self._queue]
             self._queue.clear()
@@ -549,11 +597,15 @@ class GenerationScheduler:
     # -- the tick ----------------------------------------------------------
 
     def _tick(self) -> None:
-        """One tick.  Its phases (``tick.admit``, ``tick.prefill.prepare``,
-        ``tick.prefill.enqueue``, ``tick.decode.prepare``,
-        ``tick.decode.enqueue``, ``tick.decode.sync``, ``tick.deliver``)
-        tile it on the timeline's ``sched`` track: each ends where the
-        next starts."""
+        """One tick: admit, enqueue the prefill programs of what waits,
+        enqueue the next decode step, and only then read and deliver the
+        step the tick before enqueued, while the new one runs.  A tick with
+        no row left to decode reads the step in flight at once, so an
+        answer's last token is never held back.  Its phases
+        (``tick.admit``, ``tick.prefill.prepare``, ``tick.prefill.enqueue``,
+        ``tick.decode.prepare``, ``tick.decode.enqueue``,
+        ``tick.decode.sync``, ``tick.deliver``) tile it on the timeline's
+        ``sched`` track: each ends where the next starts."""
         t0 = time.monotonic()
         self._ticks += 1
         self._phase = tracing.begin("sched", "tick.admit", tick=self._ticks)
@@ -565,14 +617,24 @@ class GenerationScheduler:
                     i for i, s in enumerate(self._slots)
                     if s is not None and not s.prefill_done
                 ]
+            if prefill_rows:
+                self._run_prefill(prefill_rows)
+            unread = self._step
+            pending = () if unread is None else {req for _i, req in unread.rows}
+            with self._lock:
+                # a row that ends by count with the step in flight is
+                # given no further one
                 decode_rows = [
                     i for i, s in enumerate(self._slots)
                     if s is not None and s.prefill_done
+                    and len(s.req.out) + (s.req in pending)
+                    < s.req.max_new_tokens
                 ]
-            if prefill_rows:
-                decode_rows.extend(self._run_prefill(prefill_rows))
-            if decode_rows:
-                self._run_decode(decode_rows)
+            if decode_rows and unread is not None:
+                self._m_decode_overlapped.inc()
+            self._step = self._enqueue_decode(decode_rows) if decode_rows else None
+            if unread is not None:
+                self._deliver(unread)
             self._tok_window.append((t0, len(decode_rows)))
             if len(self._tok_window) > 256:
                 del self._tok_window[:128]
@@ -588,14 +650,17 @@ class GenerationScheduler:
         here until :meth:`_drained`."""
         if self._inflight is None:
             self._inflight = tracing.begin("sched", "device.inflight")
-            self._inflight_programs = 0
-        self._inflight_programs += 1
+            self._inflight_from = self._programs
+        self._programs += 1
 
     def _drained(self, failed: bool = False) -> None:
         """The sync that drains the device returned (or the tick failed and
         nothing is waited for any more)."""
+        self._last_read_at = None
         if self._inflight is not None:
-            attributes: dict[str, Any] = {"programs": self._inflight_programs}
+            attributes: dict[str, Any] = {
+                "programs": self._programs - self._inflight_from
+            }
             if failed:
                 attributes["failed"] = True
             tracing.end(self._inflight, **attributes)
@@ -823,7 +888,7 @@ class GenerationScheduler:
         self._enqueued()
         enqueue_started = time.time()
         # asynchronous: the call returns once the chunk is enqueued, its
-        # work ends with the next sync (``_run_decode``)
+        # work ends with the read of the decode step behind it (``_deliver``)
         out = self._prefill_fn(
             self.lm.params, self._k_pool, self._v_pool, bt,
             jnp.asarray(ids), jnp.asarray(chunk_lens), jnp.asarray(starts),
@@ -846,22 +911,26 @@ class GenerationScheduler:
             slot.prefill_width = max(slot.prefill_width, T)
         return finishing
 
-    def _run_decode(self, rows: list[int]) -> None:
-        """One continuous decode step: sample every decode-ready row's
-        next token, write paged KV, deliver/evict finished rows."""
+    def _enqueue_decode(self, rows: list[int]) -> _Step:
+        """Enqueue one continuous decode step for the slots ``rows``: each
+        samples its next token on the device and writes its paged KV.  The
+        slots' lengths, pages and the sampling key advance here, at the
+        enqueue, so the next step can be prepared before this one is read."""
         jax, jnp = self._jax, self._jnp
         self._next_phase("tick.decode.prepare", rows=len(rows))
         with self._lock:
-            for i in rows:
-                slot = self._slots[i]
-                if slot is not None:
-                    self._ensure_pages(i, slot.seq_len + 1)
+            taken = [(i, s) for i in rows if (s := self._slots[i]) is not None]
+            for i, slot in taken:
+                self._ensure_pages(i, slot.seq_len + 1)
             G = self._table_width()
             bt = self._block_tables[:, :G].copy()
             sl = self._seq_lens.copy()
             temps = self._temps.copy()
             top_ps = self._top_ps.copy()
             min_ps = self._min_ps.copy()
+            for i, slot in taken:
+                slot.seq_len += 1
+                self._seq_lens[i] = slot.seq_len
         counts = ()
         if self._counted:
             active = np.zeros(self.slots, bool)
@@ -876,12 +945,25 @@ class GenerationScheduler:
             jnp.asarray(sl), self._logits, sub, jnp.asarray(temps),
             jnp.asarray(top_ps), jnp.asarray(min_ps), *counts,
         )
+        tok.copy_to_host_async()  # on its way before the host asks for it
         self._m_decode_steps.inc()
+        return _Step(tok, [(i, slot.req) for i, slot in taken], self._programs)
+
+    def _deliver(self, step: _Step) -> None:
+        """Read a decode step's tokens (the one host sync a tick) and hand
+        them to the requests it decoded for; finished rows are released.
+        A request that ended meanwhile (an EOS read a step late, a lapsed
+        deadline) is no longer in its slot, and its token is dropped."""
         self._next_phase("tick.decode.sync")
-        htok = np.asarray(tok)  # the one host sync per tick
+        htok = np.asarray(step.tok)
         t_now = time.monotonic()
         synced = time.time()
-        self._drained()
+        if self._last_read_at is not None:
+            self._m_decode_tick.observe((t_now - self._last_read_at) * 1e3)
+        if step.program == self._programs:
+            self._drained()  # nothing was enqueued behind it
+        else:
+            self._last_read_at = t_now
         self._next_phase("tick.deliver")
         prefill_pairs = 0
         if self._counted:
@@ -893,15 +975,14 @@ class GenerationScheduler:
             prefill_pairs = counts[2]
         eos = self.lm.eos_id
         produced = 0
+        wasted = 0
         with self._lock:
-            for i in rows:
+            for i, req in step.rows:
                 slot = self._slots[i]
-                if slot is None or not slot.prefill_done:
+                if slot is None or slot.req is not req:
+                    wasted += 1
                     continue
-                req = slot.req
                 t = int(htok[i])
-                slot.seq_len += 1
-                self._seq_lens[i] = slot.seq_len
                 if slot.prefill_started is not None:
                     # the first sync after the request's last chunk: its
                     # prefill, from the first chunk's enqueue, ends here
@@ -959,6 +1040,8 @@ class GenerationScheduler:
         if produced:
             self._tokens_total += produced
             self._m_tokens.inc(produced)
+        if wasted:
+            self._m_decode_wasted.inc(wasted)
 
     # -- observability -----------------------------------------------------
 

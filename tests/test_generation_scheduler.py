@@ -41,12 +41,19 @@ def _prompt(rng, n):
     return [int(t) for t in rng.integers(1, 500, n)]
 
 
+def _idle(sched):
+    with sched._lock:
+        return (
+            not sched._queue
+            and all(s is None for s in sched._slots)
+            and sched._step is None
+        )
+
+
 def _drive(sched, max_ticks=500):
     """Run manual ticks until idle (white-box: the thread never starts)."""
     for _ in range(max_ticks):
-        with sched._lock:
-            idle = not sched._queue and all(s is None for s in sched._slots)
-        if idle:
+        if _idle(sched):
             return
         sched._tick()
     raise AssertionError("scheduler did not drain")
@@ -197,8 +204,8 @@ def test_deadline_shed_mid_generation():
     )
     req = generation.GenRequest([5, 6, 7], 40, deadline=edge.Deadline.from_ms(60_000))
     _enqueue(sched, req)
-    sched._tick()  # admit + prefill + first decode
-    sched._tick()
+    sched._tick()  # admit + prefill + first decode step enqueued
+    sched._tick()  # the second enqueued, the first read
     assert len(req.out) >= 1 and not req.future.done()
     key = "serve.deadline.exceeded{where=decode}"
     before = em.get_registry().scalar_metrics().get(key, 0.0)
@@ -252,8 +259,10 @@ def test_chunked_prefill_does_not_stall_short_prompts():
     _enqueue(sched, long)
     _enqueue(sched, short)
     sched._tick()
-    # one tick: short finished its prompt in the first chunk and decoded
-    # its first token; long is still mid-prefill
+    sched._tick()
+    # one tick enqueues short's only chunk and its first decode step, the
+    # next reads that step: short has its first token; long is still
+    # mid-prefill
     assert short.first_token_at is not None
     assert long.first_token_at is None
     _drive(sched)
@@ -408,3 +417,321 @@ def test_allocator_never_surfaces_page_exhausted_under_churn():
         assert sched.allocator.reserved == 0
     finally:
         sched.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# The decode step that runs ahead of the host's read (ISSUE 30)
+# ---------------------------------------------------------------------------
+
+
+def _scalars():
+    return dict(em.get_registry().scalar_metrics())
+
+
+def _grew(before, name):
+    return _scalars().get(name, 0.0) - before.get(name, 0.0)
+
+
+def _drive_in_the_parents_order(sched, max_ticks=500):
+    """The order before run-ahead: every step is read as soon as it is
+    enqueued, so a tick never finds one in flight."""
+    for _ in range(max_ticks):
+        if _idle(sched):
+            return
+        sched._tick()
+        step, sched._step = sched._step, None
+        if step is not None:
+            sched._deliver(step)
+    raise AssertionError("scheduler did not drain")
+
+
+def _first_repeat_free(tokens, start=1):
+    """Index ``j >= start`` of a token that does not occur before it."""
+    return next(j for j in range(start, len(tokens)) if tokens[j] not in tokens[:j])
+
+
+@pytest.mark.parametrize("sampling", [
+    {}, {"temperature": 0.9, "top_p": 0.8},
+], ids=["greedy", "seeded-sampling"])
+@pytest.mark.parametrize("rows", [1, 3], ids=["one-row", "several-rows"])
+def test_run_ahead_gives_the_parents_tokens(rows, sampling):
+    """Requests that end by count get, token for token, what the order
+    before gave them: the same steps hold the same rows and split the
+    same keys, only the read of each comes a tick later."""
+    lm = _lm()
+    rng = np.random.default_rng(30)
+    prompts = [_prompt(rng, n) for n in (5, 11, 2)[:rows]]
+    news = (9, 4, 6)[:rows]
+    got = []
+    for drive in (_drive, _drive_in_the_parents_order):
+        sched = generation.GenerationScheduler(
+            lm, slots=4, page_size=16, prefill_chunk=8, queue_limit=16, seed=5
+        )
+        reqs = [generation.GenRequest(p, n, **sampling) for p, n in zip(prompts, news)]
+        for req in reqs:
+            _enqueue(sched, req)
+        before = _scalars()
+        drive(sched)
+        got.append([r.future.result(timeout=5) for r in reqs])
+        steps = _grew(before, "generate.decode.steps")
+        assert steps == max(news) and _grew(before, "generate.decode.wasted") == 0
+        # every step but the first was enqueued with the one before unread
+        overlapped = steps - 1 if drive is _drive else 0
+        assert _grew(before, "generate.decode.overlapped") == overlapped
+        sched.shutdown()
+    assert got[0] == got[1] and [len(out) for out in got[0]] == list(news)
+    if not sampling:
+        assert got[0] == [
+            lm.generate_ids([p], max_new_tokens=n)[0] for p, n in zip(prompts, news)
+        ]
+
+
+def test_slot_reuse_under_run_ahead_matches_static_batching():
+    """Greedy rows do not see each other: with two slots and five requests
+    (a slot is taken again a tick later than before) every answer is the
+    static path's."""
+    lm = _lm()
+    rng = np.random.default_rng(31)
+    prompts = [_prompt(rng, n) for n in (3, 11, 1, 7, 20)]
+    news = [6, 4, 8, 5, 3]
+    sched = generation.GenerationScheduler(
+        lm, slots=2, page_size=16, prefill_chunk=8, queue_limit=16
+    )
+    reqs = [generation.GenRequest(p, n) for p, n in zip(prompts, news)]
+    for req in reqs:
+        _enqueue(sched, req)
+    _drive(sched)
+    assert [r.future.result(timeout=5) for r in reqs] == [
+        lm.generate_ids([p], max_new_tokens=n)[0] for p, n in zip(prompts, news)
+    ]
+    assert sched.allocator.used_pages == 0 and sched.allocator.reserved == 0
+    sched.shutdown()
+
+
+def test_eos_read_a_step_late_drops_the_step_too_many():
+    """A token that turns out to be EOS is read with the next step already
+    enqueued for its row: that step's token is dropped, the answer is what
+    the order before gave, and the waste is counted."""
+    lm = _lm()
+    prompt = _prompt(np.random.default_rng(32), 6)
+    free_run = lm.generate_ids([prompt], max_new_tokens=12)[0]
+    j = _first_repeat_free(free_run, start=3)
+    lm.eos_id = free_run[j]
+    outs = []
+    for drive in (_drive, _drive_in_the_parents_order):
+        sched = generation.GenerationScheduler(
+            lm, slots=2, page_size=16, prefill_chunk=8, queue_limit=4
+        )
+        req = generation.GenRequest(prompt, 12)
+        _enqueue(sched, req)
+        before = _scalars()
+        drive(sched)
+        outs.append(req.future.result(timeout=5))
+        run_ahead = drive is _drive
+        assert _grew(before, "generate.decode.wasted") == (1 if run_ahead else 0)
+        assert _grew(before, "generate.decode.steps") == j + 1 + run_ahead
+        assert _grew(before, "generate.tokens") == j
+        assert sched._step is None and sched._inflight is None
+        assert sched.allocator.used_pages == 0 and sched.allocator.reserved == 0
+        sched.shutdown()
+    assert outs[0] == outs[1] == free_run[:j]
+
+
+@pytest.mark.parametrize("model,page,first_len", [
+    (MODEL, 16, 9),
+    # its window layers keep a ring a slot (4 pages of 8): the first
+    # answer overruns it, and the stale step writes into it once more
+    ("pw-tiny-hybrid-decoder", 8, 40),
+], ids=["pw-tiny-decoder", "pw-tiny-hybrid-decoder"])
+def test_slot_retaken_under_a_stale_step_answers_as_alone(model, page, first_len):
+    """One slot.  The first answer ends by EOS with a step too many in
+    flight; the request that waited takes the slot in the next tick, while
+    that step is unread.  It never receives the stale token, its prefill
+    (later in the device's order) overwrites what the stale step wrote,
+    and its answer is the one it gets alone."""
+    lm = shared_decoder(model, max_cache=128)
+    rng = np.random.default_rng(33)
+    first_prompt = [int(t) for t in rng.integers(104, 500, first_len)]
+    second_prompt = [int(t) for t in rng.integers(104, 500, 13)]
+
+    def scheduler():
+        return generation.GenerationScheduler(
+            lm, slots=1, page_size=page, prefill_chunk=64, queue_limit=4
+        )
+
+    lm.eos_id = None
+    alone = scheduler()
+    probe = generation.GenRequest(first_prompt, 10)
+    _enqueue(alone, probe)
+    _drive(alone)
+    free_run = probe.future.result(timeout=5)
+    j = _first_repeat_free(free_run, start=2)
+    lm.eos_id = free_run[j]
+    want = generation.GenRequest(second_prompt, 7)
+    _enqueue(alone, want)
+    _drive(alone)
+    alone.shutdown()
+    assert lm.eos_id not in want.future.result(timeout=5)
+
+    sched = scheduler()
+    first = generation.GenRequest(first_prompt, 10)
+    second = generation.GenRequest(second_prompt, 7)
+    _enqueue(sched, first)
+    _enqueue(sched, second)
+    before = _scalars()
+    retaken_under_a_stale_step = False
+    for _ in range(100):
+        if first.future.done() and second.future.done() and sched._step is None:
+            break
+        stale = sched._step if first.future.done() else None
+        sched._tick()
+        if stale is not None and stale.rows[0][1] is first:
+            with sched._lock:  # read in the tick that gave the slot away
+                retaken_under_a_stale_step = sched._slots[0].req is second
+    assert retaken_under_a_stale_step
+    assert first.future.result(timeout=5) == free_run[:j]
+    assert second.future.result(timeout=5) == want.future.result(timeout=5)
+    assert _grew(before, "generate.decode.wasted") == 1
+    assert sched.allocator.used_pages == 0 and sched.allocator.reserved == 0
+    sched.shutdown()
+
+
+def _no_interval_left_open(sched):
+    from pathway_tpu.engine import tracing
+
+    assert sched._step is None and sched._inflight is None
+    inflight = [r for r in tracing.timeline() if r["name"] == "device.inflight"]
+    assert inflight  # the timeline holds closed intervals only
+    return inflight
+
+
+def test_deadline_eviction_with_a_step_in_flight_reads_and_drops_it():
+    lm = _lm()
+    sched = generation.GenerationScheduler(
+        lm, slots=1, page_size=16, prefill_chunk=8, queue_limit=4
+    )
+    req = generation.GenRequest([5, 6, 7], 40, deadline=edge.Deadline.from_ms(60_000))
+    _enqueue(sched, req)
+    for _ in range(3):
+        sched._tick()
+    assert sched._step is not None and len(req.out) == 2
+    before = _scalars()
+    req.deadline = edge.Deadline.from_ms(0)
+    sched._tick()  # evicts, enqueues nothing, reads the step in flight at once
+    with pytest.raises(edge.DeadlineExceededError, match=r"\(2 token"):
+        req.future.result(timeout=1)
+    assert _grew(before, "generate.decode.wasted") == 1
+    assert _grew(before, "generate.decode.steps") == 0
+    assert "failed" not in _no_interval_left_open(sched)[-1]["attributes"]
+    assert sched.allocator.used_pages == 0 and sched.allocator.reserved == 0
+    sched.shutdown()
+
+
+def test_shutdown_with_a_step_in_flight_fails_the_future_and_lets_the_step_go():
+    lm = _lm()
+    sched = generation.GenerationScheduler(
+        lm, slots=1, page_size=16, prefill_chunk=8, queue_limit=4
+    )
+    req = generation.GenRequest([5, 6, 7], 40)
+    _enqueue(sched, req)
+    for _ in range(3):
+        sched._tick()
+    assert sched._step is not None and not req.future.done()
+    sched.shutdown()
+    assert isinstance(req.future.exception(timeout=1), edge.RequestFailedError)
+    assert _no_interval_left_open(sched)[-1]["attributes"].get("failed") is True
+    assert sched.allocator.used_pages == 0 and sched.allocator.reserved == 0
+
+
+def test_raising_tick_with_a_step_in_flight_fails_requests_not_the_thread():
+    """Through the worker thread: the fourth decode step fails to enqueue
+    with the third in flight.  Both requests of the tick fail, nothing is
+    left pending or open, and the thread serves the next request."""
+    lm = _lm()
+    sched = generation.GenerationScheduler(
+        lm, slots=2, page_size=16, prefill_chunk=8, queue_limit=4
+    )
+    step, calls = sched._decode_fn, [0]
+
+    def failing(*args):
+        calls[0] += 1
+        if calls[0] == 4:
+            raise RuntimeError("device fell over")
+        return step(*args)
+
+    sched._decode_fn = failing
+    try:
+        futures = [sched.submit_ids([3, 1, 4], max_new_tokens=30) for _ in range(2)]
+        for future in futures:
+            with pytest.raises(RuntimeError, match="device fell over"):
+                future.result(timeout=120)
+        assert _no_interval_left_open(sched)[-1]["attributes"].get("failed") is True
+        assert sched.snapshot()["tick_failures"] == 1
+        again = sched.submit_ids([3, 1, 4], max_new_tokens=5).result(timeout=120)
+        assert again == lm.generate_ids([[3, 1, 4]], max_new_tokens=5)[0]
+    finally:
+        sched.shutdown()
+    assert sched._step is None and sched._inflight is None
+
+
+def test_a_second_answer_compiles_nothing_the_first_did_not():
+    from pathway_tpu.engine.profiler import install_jax_accounting
+
+    assert install_jax_accounting(force=True)
+    lm = _lm()
+    sched = generation.GenerationScheduler(
+        lm, slots=2, page_size=16, prefill_chunk=8, queue_limit=4
+    )
+    rng = np.random.default_rng(34)
+    _enqueue(sched, generation.GenRequest(_prompt(rng, 7), 9))
+    _drive(sched)
+    before = _scalars()
+    req = generation.GenRequest(_prompt(rng, 6), 9)
+    _enqueue(sched, req)
+    _drive(sched)
+    assert len(req.future.result(timeout=5)) == 9
+    assert _grew(before, "jax.compile.count") == 0 and _grew(before, "jax.cache.miss") == 0
+    sched.shutdown()
+
+
+def test_lone_answer_overlaps_every_step_but_the_first():
+    """``generate.decode.overlapped`` = steps - 1, and as many inter-token
+    intervals in ``generate.decode.tick.ms``: the first read has none
+    before it, and the last drains the device."""
+    lm = _lm()
+    sched = generation.GenerationScheduler(
+        lm, slots=2, page_size=16, prefill_chunk=8, queue_limit=4
+    )
+
+    def tick_intervals():
+        points = em.get_registry().histogram_points()
+        return sum(p["count"] for p in points if p["name"] == "generate.decode.tick.ms")
+
+    try:
+        for _ in range(2):  # the second answer's first read follows a drain
+            before, intervals = _scalars(), tick_intervals()
+            out = sched.submit_ids([2, 7, 1, 8], max_new_tokens=11).result(timeout=120)
+            assert len(out) == 11
+            assert _grew(before, "generate.decode.steps") == 11
+            assert _grew(before, "generate.decode.overlapped") == 10
+            assert _grew(before, "generate.decode.wasted") == 0
+            assert tick_intervals() - intervals == 10
+    finally:
+        sched.shutdown()
+
+
+def test_top_shows_the_decode_line():
+    from pathway_tpu.internals.top import render_top
+
+    text = render_top(
+        {
+            "generation": {
+                "generate.slots.total": 8.0,
+                "generate.decode.steps": 640.0,
+                "generate.decode.overlapped": 630.0,
+                "generate.decode.wasted": 3.0,
+                "generate.decode.tick.ms.p50": 18.74,
+            }
+        }
+    )
+    assert "decode: 640 step(s) · 98% ran ahead · 3 wasted · p50 18.7 ms a token" in text

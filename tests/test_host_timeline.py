@@ -179,14 +179,16 @@ def test_tick_phases_tile_the_tick_and_inflight_never_overlaps():
     sched = generation.GenerationScheduler(
         lm, slots=2, page_size=16, prefill_chunk=4, queue_limit=16
     )
-    traces = [tracing.RequestTrace("/v1/generate") for _ in range(2)]
+    traces = [tracing.RequestTrace("/v1/generate") for _ in range(3)]
     reqs = [
         generation.GenRequest([3, 5, 7, 11, 13, 17, 19, 23, 29, 31], 6, trace=traces[0]),
         generation.GenRequest([2, 4, 6], 3, trace=traces[1]),
+        generation.GenRequest([8, 9, 10, 12, 14], 4, trace=traces[2]),
     ]
     with sched._lock:
         sched._queue.append(reqs[0])
     ticks = []  # (wall start, wall end) of every hand-driven tick
+    lone_sent = False
     try:
         for _ in range(100):
             if all(r.future.done() for r in reqs):
@@ -194,12 +196,17 @@ def test_tick_phases_tile_the_tick_and_inflight_never_overlaps():
             if len(ticks) == 4:  # the short one arrives while the long one decodes
                 with sched._lock:
                     sched._queue.append(reqs[1])
+            if not lone_sent and reqs[0].future.done() and reqs[1].future.done():
+                lone_sent = True  # the third meets a drained device: a lone answer
+                with sched._lock:
+                    sched._queue.append(reqs[2])
             started = time.time()
             sched._tick()
             ticks.append((started, time.time()))
     finally:
         sched.shutdown()
-    assert all(r.future.done() for r in reqs) and len(ticks) >= 8
+    assert all(r.future.done() for r in reqs) and len(ticks) >= 12
+    assert sched._step is None and sched._inflight is None
     records = [r for r in tracing.timeline() if r["track"] == "sched"]
     phases = [r for r in records if r["name"] in PHASES]
     assert {r["name"] for r in phases} == set(PHASES)
@@ -210,6 +217,10 @@ def test_tick_phases_tile_the_tick_and_inflight_never_overlaps():
         mine = [r for r in phases if started <= r["start"] and r["end"] <= ended]
         assert mine[0]["name"] == "tick.admit"
         assert all(a["end"] == b["start"] for a, b in zip(mine, mine[1:]))
+        # a decode step is enqueued before the one in flight is read
+        names = [r["name"] for r in mine]
+        if "tick.decode.enqueue" in names and "tick.decode.sync" in names:
+            assert names.index("tick.decode.enqueue") < names.index("tick.decode.sync")
         shares.append(sum(r["end"] - r["start"] for r in mine) / (ended - started))
     # each tick's phases tile its wall time (a tick the machine took the
     # thread away from, between the test's clock and the tick's, is let off)
@@ -218,20 +229,26 @@ def test_tick_phases_tile_the_tick_and_inflight_never_overlaps():
         (r for r in records if r["name"] == "device.inflight"), key=lambda r: r["start"]
     )
     assert all(a["end"] <= b["start"] for a, b in zip(inflight, inflight[1:]))
-    # the long prompt's first two ticks have no decode-ready row: its three
-    # chunks and the first decode step ride one interval, up to the first sync
-    assert inflight[0]["attributes"] == {"programs": 4}
-    assert inflight[0]["start"] < ticks[0][1] and inflight[0]["end"] > ticks[2][0]
+    # the device never drains while a step runs ahead: the long prompt's
+    # three chunks, the short one's chunk and the six steps they decode in
+    # (the short one's three among them) ride one interval; the lone answer
+    # that follows rides another, its two chunks and all four of its steps
+    assert [r["attributes"] for r in inflight] == [{"programs": 10}, {"programs": 6}]
+    assert inflight[0]["start"] < ticks[0][1] and inflight[0]["end"] > ticks[7][0]
     syncs = [r for r in phases if r["name"] == "tick.decode.sync"]
-    assert len(inflight) == len(syncs)
+    enqueues = [r for r in phases if r["name"] == "tick.decode.enqueue"]
+    assert len(syncs) == len(enqueues) == 10  # every step enqueued is read, once
     assert sum(r["attributes"]["programs"] for r in inflight) == len(syncs) + sum(
         1 for r in phases if r["name"] == "tick.prefill.enqueue"
     )
-    for interval, sync in zip(inflight, sorted(syncs, key=lambda r: r["start"])):
-        assert interval["start"] <= sync["start"] and sync["end"] <= interval["end"] + 1e-3
+    for sync in syncs:  # the read of a step lies inside the interval it rode
+        assert any(
+            interval["start"] <= sync["start"] and sync["end"] <= interval["end"] + 1e-3
+            for interval in inflight
+        )
     # one generate.prefill span a request, and it ends with the sync that
     # hands out the first token (two clocks read within the same microseconds)
-    for trace, chunks in zip(traces, (3, 1)):
+    for trace, chunks in zip(traces, (3, 1, 2)):
         (prefill,) = [s for s in trace.spans if s["name"] == "generate.prefill"]
         (ttft,) = [s for s in trace.spans if s["name"] == "generate.ttft"]
         assert prefill["attributes"]["chunks"] == chunks

@@ -224,7 +224,9 @@ def test_two_waiting_prompts_compile_nothing_a_single_prompt_did_not(lm):
         with sched._lock:
             sched._queue.extend(pair)
         sched._tick()
-        # both prefilled in that one tick, each alone at the 128 rung
+        sched._tick()
+        # both prefilled in the first tick, each alone at the 128 rung, and
+        # the second read the decode step that the first enqueued behind them
         assert all(r.first_token_at is not None for r in pair)
         _drive(sched)
         after = _scalars()
