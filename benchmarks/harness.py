@@ -129,14 +129,8 @@ SUITE: tuple[Bench, ...] = (
     Bench(
         "serving_overload", "serving_overload.py", ("smoke",), ("full",),
     ),
-    # decoder program throughput: bucketed prefill + fused decode_chunk
-    # (+ int8 / self-speculative variants) — the static serving baseline
-    Bench(
-        "decoder_throughput", "decoder_throughput.py", (), (),
-    ),
-    # continuous batching + paged KV vs static batch-to-completion on an
-    # identical Poisson churn trace — serving_continuous_speedup >= 1.5
-    # with lower TTFT p95 is the ISSUE 18 pin
+    # continuous batching + paged KV on a Poisson churn trace: goodput,
+    # TTFT and request latency of the generation scheduler
     Bench(
         "serving_generation", "serving_generation.py", ("smoke",), ("full",),
     ),

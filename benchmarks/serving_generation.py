@@ -1,23 +1,15 @@
-"""Continuous batching vs static batch-to-completion on a churning trace.
+"""The generation scheduler on a churning trace (CPU harness row).
 
-The A/B the ISSUE-18 tentpole is judged on: one Poisson-arrival request
-trace (bimodal output lengths — many short completions, a few long
-generations — the serving mix continuous batching exists for) replayed
-through BOTH serving disciplines on the same model and device budget:
+One Poisson-arrival request trace (bimodal output lengths — many short
+completions, a few long generations — the serving mix continuous batching
+exists for) replayed through ``serving.generation.GenerationScheduler``:
+finished rows are evicted and queued requests admitted every decode step,
+over the paged KV pool.
 
-* **static** — the pre-PR-18 shape: arrivals wait for the running batch,
-  each batch runs to its LONGEST member via ``DecoderLM.generate_ids``
-  (no per-row early exit: short rows pay for the long row's tokens, and
-  every waiting request's first token waits for the whole batch).
-* **continuous** — ``serving.generation.GenerationScheduler``: finished
-  rows are evicted and queued requests admitted every decode step, over
-  the paged KV pool.
-
-Reported tokens/s counts REQUESTED tokens only (the static path's
-padding tokens are waste, not goodput) over the trace makespan; TTFT and
-per-request latency come from the same per-request timestamps on both
-sides.  Both paths are fully warmed on a replay of the trace before the
-timed pass.
+Reported tokens/s counts requested tokens over the trace makespan; TTFT
+and per-request latency come from the per-request timestamps.  The
+scheduler is fully warmed on a replay of the trace before the timed pass.
+These are CPU timings: what the chip does is the ledger's (``PERF.md``).
 
 Usage: ``python benchmarks/serving_generation.py [smoke|full]``.
 Prints harness-protocol JSON lines (benchmarks/harness.py).
@@ -56,34 +48,6 @@ def build_trace(seed: int, n_requests: int, mean_gap_s: float):
     return trace
 
 
-def run_static(lm, trace, batch_cap: int):
-    """Arrival-order batch-to-completion: the static serving discipline."""
-    pending = list(trace)
-    ttfts_ms, lats_ms = [], []
-    done_at = 0.0
-    t0 = time.perf_counter()
-    while pending:
-        now = time.perf_counter() - t0
-        arrived = [r for r in pending if r[0] <= now]
-        if not arrived:
-            time.sleep(min(r[0] for r in pending) - now)
-            continue
-        batch = arrived[:batch_cap]
-        pending = [r for r in pending if r not in batch]
-        # one padded batch to the LONGEST member — generate_ids has no
-        # per-row token budget, which is exactly the static waste
-        lm.generate_ids(
-            [r[1] for r in batch],
-            max_new_tokens=max(r[2] for r in batch),
-        )
-        done_at = time.perf_counter() - t0
-        for offset, _, _ in batch:
-            # the blocking static API emits everything at completion
-            ttfts_ms.append((done_at - offset) * 1e3)
-            lats_ms.append((done_at - offset) * 1e3)
-    return done_at, ttfts_ms, lats_ms
-
-
 def run_continuous(sched, trace):
     reqs = []
     t0 = time.perf_counter()
@@ -113,8 +77,7 @@ def main() -> None:
     else:
         n_requests, mean_gap, slots = 24, 0.02, 6
 
-    # eos_id=None: every row emits exactly its requested budget, so both
-    # disciplines serve the identical token volume
+    # eos_id=None: every row emits exactly its requested budget
     lm = DecoderLM("pw-tiny-decoder", max_cache=64, eos_id=None)
     trace = build_trace(18, n_requests, mean_gap)
     requested = sum(mn for _, _, mn in trace)
@@ -124,31 +87,17 @@ def main() -> None:
         queue_limit=max(2 * n_requests, 64),
     )
     try:
-        # warm both paths: replay the trace once untimed so every
-        # bucketed program (batch sizes, table widths, decode chunks)
-        # is compiled before measurement
-        run_static(lm, trace, batch_cap=slots)
+        # warm: replay the trace once untimed so every bucketed program
+        # (prefill shapes, table widths) is compiled before measurement
         run_continuous(sched, trace)
-
-        static_span, static_ttfts, static_lats = run_static(
-            lm, trace, batch_cap=slots
-        )
         cont_span, cont_ttfts, cont_lats = run_continuous(sched, trace)
     finally:
         sched.shutdown()
 
-    static_tok_s = requested / static_span
-    cont_tok_s = requested / cont_span
     metrics = {
-        "serving_continuous_tokens_per_sec": round(cont_tok_s, 1),
-        "serving_static_tokens_per_sec": round(static_tok_s, 1),
-        "serving_continuous_speedup": round(cont_tok_s / static_tok_s, 3),
+        "serving_continuous_tokens_per_sec": round(requested / cont_span, 1),
         "serving_continuous_ttft_p50_ms": round(_pct(cont_ttfts, 50), 2),
         "serving_continuous_ttft_p95_ms": round(_pct(cont_ttfts, 95), 2),
-        "serving_static_ttft_p95_ms": round(_pct(static_ttfts, 95), 2),
-        "serving_ttft_p95_speedup": round(
-            _pct(static_ttfts, 95) / max(_pct(cont_ttfts, 95), 1e-9), 3
-        ),
         "serving_continuous_request_p99_ms": round(_pct(cont_lats, 99), 2),
     }
     for name, value in metrics.items():
@@ -161,9 +110,6 @@ def main() -> None:
                     "requested_tokens": requested,
                     "mean_gap_s": mean_gap,
                     "slots": slots,
-                    "static_median_lat_ms": round(
-                        statistics.median(static_lats), 2
-                    ),
                     "continuous_median_lat_ms": round(
                         statistics.median(cont_lats), 2
                     ),

@@ -13,8 +13,8 @@ shapes that *guarantee* recompiles before the code ever runs:
   function body whose result is not observably cached: accepted sinks
   are an assignment to ``self.<attr>`` (per-instance cache), a local
   that is later stored into a ``self`` attribute or subscript (the
-  memo-dict bucketing idiom of ``models/decoder.py:_chunk_fn``),
-  returned, or yielded.  Decorator usage (``@jax.jit``,
+  memo-dict bucketing idiom: ``self._fns[key] = fn``), returned, or
+  yielded.  Decorator usage (``@jax.jit``,
   ``@functools.partial(jax.jit, ...)``) and module/class-level wraps are
   always fine — they run once per definition.
 * ``jit-nonhashable-static`` — a ``static_argnums``/``static_argnames``

@@ -321,11 +321,6 @@ ENV_KNOBS: tuple[EnvKnob, ...] = (
        "requests to complete before the handoff fence proceeds",
        "serving"),
     # -- generation serving (pathway_tpu/serving/) --------------------------
-    _k("PATHWAY_GENERATE_CONTINUOUS", "bool", True,
-       "route `JaxChat` decoder generation through the continuous-"
-       "batching scheduler (paged KV, per-step admission); `0` reverts "
-       "to the static per-config `AsyncMicroBatcher` path "
-       "(docs/generation_serving.md)", "generate"),
     _k("PATHWAY_GENERATE_SLOTS", "int", 8,
        "generation slot count — the fixed device batch width of the "
        "continuous decode step; finished rows free their slot every "

@@ -2,24 +2,28 @@
 
 The reference serves local chat models through a host-side torch pipeline
 (``xpacks/llm/llms.py:314`` ``HFPipelineChat``); its Adaptive RAG template
-runs Mistral-7B-Instruct that way.  Here the decoder is a jit-compiled JAX
-program designed for the TPU serving split:
+runs Mistral-7B-Instruct that way.  Here the decoder is two jit-compiled
+JAX programs over a paged KV cache, driven by the continuous-batching
+scheduler (``serving/generation.py``):
 
-  * **prefill** — one bucketed-length causal forward over the whole prompt
-    that fills the KV cache and returns the first sampled logits; all the
-    FLOPs land in large bf16 matmuls on the MXU.
-  * **decode** — a single-token step against the cache, jitted once and
-    re-used for every generated token (static cache capacity, dynamic
-    position — no recompiles during generation).
+  * **paged_prefill_chunk** — a chunk of each slot's prompt, its K/V
+    scattered into the slot's pages; all the FLOPs land in large bf16
+    matmuls on the MXU.
+  * **paged_decode_step** — one token a slot against its pages, compiled
+    once and re-used for every generated token (static slot count and
+    table width, dynamic positions — no recompiles during generation).
+
+and one full causal forward (``causal_lm_logits``) for training, which is
+also what the tests hold the paged programs against.  The decoder layer
+has these two bodies: ``decoder_layer`` and ``_paged_trunk``'s.
 
 Layer parameters are stacked along a leading ``[layers, ...]`` axis and the
 trunk runs under ``lax.scan``, so a 32-layer model traces one layer once
-(fast compiles) and the cache is a single ``[layers, B, C, KH, D]`` array
-per K/V.  Weights follow the LLaMA family: RMSNorm, rotary position
-embeddings, grouped-query attention, SwiGLU MLP.  ``tp_param_specs`` /
-``tp_cache_specs`` give the tensor-parallel layout (heads and FFN sharded
-over a ``model`` mesh axis; XLA inserts the all-reduces after ``wo``/``wd``
-contractions), used by the multi-chip dry run.
+(fast compiles).  Weights follow the LLaMA family: RMSNorm, rotary position
+embeddings, grouped-query attention, SwiGLU MLP.  ``tp_param_specs`` gives
+the tensor-parallel layout (heads and FFN sharded over a ``model`` mesh
+axis; XLA inserts the all-reduces after ``wo``/``wd`` contractions), used
+by training and the multi-chip dry run.
 
 Checkpoints: a locally cached HF llama/mistral-family checkpoint maps onto
 the param tree via ``load_hf_decoder_weights``; without one (zero-egress
@@ -42,16 +46,6 @@ from jax.sharding import PartitionSpec as P
 
 from pathway_tpu.device.compile_cache import ensure_compile_cache
 from pathway_tpu.models.tokenizer import load_tokenizer, may_have_local_checkpoint
-
-
-def _bucket_prompt_len(n: int, cap: int) -> int:
-    """Power-of-two prefill bucket, clamped to the cache capacity (the
-    shared ``bucket_seq_len`` stops at 512, which a long-cache decoder
-    must exceed)."""
-    b = 16
-    while b < n and b < cap:
-        b <<= 1
-    return min(b, cap)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -115,9 +109,7 @@ class DecoderConfig:
     rotary_dim: int | None = None
     value_scale: float = 1.0
     # layers of different kinds in one model: ``((kind, count), ...)`` in
-    # layer order.  None: every layer is ``self.kind`` (the fields above).
-    # Only the scheduler's path (paged_decode_step / paged_prefill_chunk)
-    # and the full forward (decoder_layer, causal_lm_logits) take runs
+    # layer order.  None: every layer is ``self.kind`` (the fields above)
     runs: tuple[tuple[LayerKind, int], ...] | None = None
 
     @property
@@ -159,18 +151,6 @@ def run_stacks(cfg: DecoderConfig, layers, *pools):
     ]
 
 
-def _require_uniform(cfg: DecoderConfig, what: str) -> None:
-    """The static generation path knows one kind of layer."""
-    if cfg.runs is not None:
-        raise NotImplementedError(
-            f"{what} serves models whose layers are all alike; this "
-            "configuration mixes layer kinds (window and global attention, "
-            "dense and routed FFN) and is served by the scheduler's path: "
-            "GenerationScheduler / JaxChat over paged_prefill_chunk and "
-            "paged_decode_step"
-        )
-
-
 PRESETS: dict[str, DecoderConfig] = {
     # v0.1 family: sliding-window attention over the last 4096 positions
     "mistral-7b-instruct": DecoderConfig(sliding_window=4096),
@@ -180,9 +160,9 @@ PRESETS: dict[str, DecoderConfig] = {
         max_len=2048,
     ),
     # the MoE sibling of the Mistral family the reference's Adaptive RAG
-    # template serves (block-sparse FFN, 8 experts, top-2 routing).  Both
-    # generation paths serve it through parallel/moe.py::moe_serve (softmax
-    # scores, all 8 experts held): sorted pairs, one grouped product
+    # template serves (block-sparse FFN, 8 experts, top-2 routing), served
+    # through parallel/moe.py::moe_serve (softmax scores, all 8 experts
+    # held): sorted pairs, one grouped product
     "mixtral-8x7b-instruct": DecoderConfig(
         rope_theta=1e6, experts=8, experts_top_k=2, max_len=8192,
     ),
@@ -494,7 +474,11 @@ def tp_param_specs(cfg: DecoderConfig, axis: str = "model"):
     width — each chip owns ``E / |axis|`` whole experts and the GShard
     dispatch/combine einsums lower to ``all_to_all`` (expert parallelism
     in serving)."""
-    _require_uniform(cfg, "tp_param_specs")
+    if cfg.runs is not None:
+        raise NotImplementedError(
+            "tp_param_specs lays out a model whose layers are all alike; a "
+            "model of runs (cfg.runs) has no tensor-parallel layout yet"
+        )
     layer_specs = {
         "ln0": P(None, None),
         "ln1": P(None, None),
@@ -528,11 +512,6 @@ def tp_param_specs(cfg: DecoderConfig, axis: str = "model"):
     }
 
 
-def tp_cache_specs(axis: str = "model"):
-    """KV cache sharded over kv heads: ``[L, B, C, KH, D]``."""
-    return P(None, None, None, axis, None)
-
-
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
@@ -547,7 +526,7 @@ def _sw_mask(q_pos, k_pos, window: int):
     """True where key position ``k_pos`` lies inside the sliding window of
     query position ``q_pos`` (``q_pos - window < k_pos``); shapes
     broadcast.  The ONE definition of the window edge — shared by the
-    trunk, decode, verify, and pipeline masks so they cannot drift."""
+    trunk, the paged programs and the pipeline masks so they cannot drift."""
     return k_pos > q_pos - window
 
 
@@ -587,9 +566,8 @@ def quantize_decoder_tree(tree):
     """
     if not isinstance(tree["layers"], dict):
         raise NotImplementedError(
-            "quantize_decoder_tree takes a model whose layers are all alike "
-            "(the static path's tree); a tree of runs is served float by the "
-            "scheduler's path"
+            "quantize_decoder_tree takes a model whose layers are all alike; "
+            "a tree of runs is served float by the scheduler's path"
         )
     quant_names = {"wq", "wk", "wv", "wo", "wg", "wu", "wd"}
     for name in quant_names:
@@ -597,8 +575,7 @@ def quantize_decoder_tree(tree):
         if isinstance(w, dict) and "a" in w:
             raise ValueError(
                 f"layer weight {name!r} carries LoRA adapters — call "
-                "models.lora.merge_lora(tree) before quantizing (or "
-                "before speculative decoding, which quantizes its draft)"
+                "models.lora.merge_lora(tree) before quantizing"
             )
 
     def quant(w):
@@ -751,19 +728,14 @@ def decoder_layer(lp, x, positions, mask, cfg: DecoderConfig,
     return x, (k, v), aux
 
 
-def _causal_trunk(
-    tree, ids, lengths, cfg: DecoderConfig, cache_len: int, *, serving=False
-):
-    """Shared causal forward: final-norm token reps + K/V caches (of a
-    model of one kind; a model of runs, whose caches differ by kind, gets
-    None for them: its caches are the scheduler's)."""
+def _causal_trunk(tree, ids, lengths, cfg: DecoderConfig, *, serving=False):
+    """Shared causal forward: ``(final-norm token reps [B, S, H], aux)``,
+    aux the summed MoE load-balance loss (0 under ``serving``)."""
     B, S = ids.shape
     x = tree["embed"][ids]  # [B, S, H]
     positions = jnp.arange(S)[None, :].repeat(B, axis=0)
     valid = positions < lengths[:, None]  # [B, S]
     causal = jnp.tril(jnp.ones((S, S), bool))
-    keep_cache = cfg.runs is None
-    k_cache = v_cache = None
     aux_sum = jnp.float32(0.0)
     for kind, layers in run_stacks(cfg, tree["layers"]):
         seen = causal
@@ -775,16 +747,10 @@ def _causal_trunk(
         mask = seen[None, :, :] & valid[:, None, :]  # [B, S(q), S(kv)]
 
         def layer(x, lp, kind=kind, mask=mask):
-            x, (k, v), aux = decoder_layer(
+            x, _kv, aux = decoder_layer(
                 lp, x, positions, mask, cfg, kind, serving=serving
             )
-            if not keep_cache:
-                return x, aux
-            # zero K/V beyond each row's real length: decode_step scatters new
-            # entries additively, which requires untouched slots to hold zeros
-            keep = valid[:, :, None, None].astype(k.dtype)
-            pad = ((0, 0), (0, cache_len - S), (0, 0), (0, 0))
-            return x, (jnp.pad(k * keep, pad), jnp.pad(v * keep, pad), aux)
+            return x, aux
 
         if cfg.remat:
             # scan-over-remat: backward recomputes each layer's activations
@@ -792,42 +758,16 @@ def _causal_trunk(
             # prevent_cse=False: safe (and recommended) inside lax.scan, and
             # skips the optimization barriers that would block layer fusion
             layer = jax.checkpoint(layer, prevent_cse=False)
-        x, ys = lax.scan(layer, x, layers)
-        if keep_cache:
-            k_cache, v_cache, ys = ys
+        x, aux = lax.scan(layer, x, layers)
         if not serving:
-            aux_sum = aux_sum + ys.sum()
-    x = _rms(x, tree["final_norm"], cfg.norm_eps)
-    return x, k_cache, v_cache, aux_sum
-
-
-def prefill(tree, ids, lengths, cfg: DecoderConfig, cache_len: int):
-    """Causal forward over the whole (padded) prompt.
-
-    Returns ``(logits_last, k_cache, v_cache)``: logits at each row's final
-    real token and caches of shape ``[L, B, cache_len, KH, D]`` with the
-    prompt keys/values written at positions ``[0, S)``.
-    """
-    _require_uniform(cfg, "prefill (the static path)")
-    # serving path: lossless MoE dispatch — a capacity drop here would
-    # corrupt the K/V cache conditioning every later decode step
-    x, k_cache, v_cache, _ = _causal_trunk(
-        tree, ids, lengths, cfg, cache_len, serving=True
-    )
-    last = jnp.take_along_axis(
-        x, (lengths - 1)[:, None, None].repeat(cfg.hidden, 2), axis=1
-    )[:, 0, :]
-    logits = _mm(last, tree["lm_head"]).astype(jnp.float32)
-    return logits, k_cache, v_cache
+            aux_sum = aux_sum + aux.sum()
+    return _rms(x, tree["final_norm"], cfg.norm_eps), aux_sum
 
 
 def causal_lm_logits(tree, ids, lengths, cfg: DecoderConfig, *, serving=False):
     """All-position logits ``[B, S, vocab]`` (f32) for next-token training,
-    or with ``serving`` the full forward as the serving paths route it (no
+    or with ``serving`` the full forward as the serving path routes it (no
     expert capacity): what the paged programs are held against.
-
-    The unused K/V scan outputs are dead code under ``jax.grad``/``jit`` —
-    XLA eliminates them, so training pays no cache-materialization cost.
     """
     return causal_lm_logits_and_aux(tree, ids, lengths, cfg, serving=serving)[0]
 
@@ -837,65 +777,24 @@ def causal_lm_logits_and_aux(tree, ids, lengths, cfg: DecoderConfig, *, serving=
     load-balance loss over layers (0 for dense configs, and under
     ``serving``); MoE training adds it to the LM loss so routing stays
     spread over experts."""
-    S = ids.shape[1]
-    x, _, _, aux = _causal_trunk(tree, ids, lengths, cfg, S, serving=serving)
+    x, aux = _causal_trunk(tree, ids, lengths, cfg, serving=serving)
     return _mm(x, tree["lm_head"]).astype(jnp.float32), aux
 
 
-def decode_step(tree, k_cache, v_cache, token, pos, cfg: DecoderConfig):
-    """One generation step: ``token`` ``[B]`` at position ``pos`` ``[B]``.
+def sample_logits(logits, key, temp, *, top_k=None, top_p=None, min_p=None):
+    """On-device sampling: temperature, then optional min-p / top-k /
+    nucleus (top-p) truncation, then categorical.  ``logits [B, V]`` f32.
 
-    Returns ``(logits, k_cache, v_cache)`` with the new K/V written at
-    ``pos``.  Cache capacity is static; ``pos`` is data, so every step of a
-    generation reuses the same compiled program.
-    """
-    _require_uniform(cfg, "decode_step (the static path)")
-    B = token.shape[0]
-    C = k_cache.shape[2]
-    KH, D = cfg.kv_heads, cfg.head_dim
-    x = tree["embed"][token][:, None, :]  # [B, 1, H]
-    positions = pos[:, None]  # [B, 1]
-    idx = jnp.arange(C)[None, None, :]
-    mask = idx <= pos[:, None, None]  # [B, 1, C]
-    if cfg.sliding_window is not None:
-        mask = mask & _sw_mask(pos[:, None, None], idx, cfg.sliding_window)
-
-    def layer(x, lp):
-        lp, kc, vc = lp
-        h = _rms(x, lp["ln0"], cfg.norm_eps)
-        q = _mm(h, lp["wq"]).reshape(B, 1, cfg.heads, D)
-        k = _mm(h, lp["wk"]).reshape(B, 1, KH, D)
-        v = _mm(h, lp["wv"]).reshape(B, 1, KH, D)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
-        # scatter the new kv at each row's position
-        onehot = (idx[:, 0, :] == pos[:, None]).astype(kc.dtype)  # [B, C]
-        kc = kc + onehot[:, :, None, None] * k
-        vc = vc + onehot[:, :, None, None] * v
-        x = x + _mm(_attend(q, kc, vc, mask, cfg), lp["wo"])
-        h = _rms(x, lp["ln1"], cfg.norm_eps)
-        mlp, _ = _ffn(lp, h, cfg, serving=True)
-        x = x + mlp
-        return x, (kc, vc)
-
-    x, (k_cache, v_cache) = lax.scan(layer, x, (tree["layers"], k_cache, v_cache))
-    x = _rms(x, tree["final_norm"], cfg.norm_eps)
-    logits = _mm(x[:, 0, :], tree["lm_head"]).astype(jnp.float32)
-    return logits, k_cache, v_cache
-
-
-def sample_logits(logits, key, temp, *, top_k: int | None = None,
-                  top_p: float | None = None, min_p: float | None = None):
-    """On-device sampling: temperature, then optional top-k / nucleus
-    (top-p) / min-p truncation, then categorical.  ``logits [B, V]`` f32.
-
-    top-p keeps the smallest probability-sorted prefix whose mass reaches
+    min-p keeps tokens whose probability is at least ``min_p x`` the top
+    token's (the relative cutoff that adapts to how peaked the
+    distribution is); top-k keeps the ``top_k`` largest logits (0, or a k
+    past the vocabulary, keeps all); top-p keeps the smallest
+    probability-sorted prefix of what top-k left whose mass reaches
     ``top_p`` (the first token always survives, so the distribution is
-    never empty); min-p keeps tokens whose probability is at least
-    ``min_p ×`` the top token's (the relative cutoff that adapts to how
-    peaked the distribution is).  All filters set rejected logits to
-    -inf BEFORE the categorical draw, inside the compiled program;
-    ``top_p``/``min_p`` may be traced scalars.
+    never empty).  All filters set rejected logits to -inf BEFORE the
+    categorical draw, inside the compiled program, and each is data: a
+    scalar or one value a row (``[B, 1]``), so no value of it compiles
+    anything.  top-k and top-p read one descending sort.
     """
     lg = logits / temp
     if min_p is not None:
@@ -908,15 +807,17 @@ def sample_logits(logits, key, temp, *, top_k: int | None = None,
             jnp.minimum(min_p, 1.0)
         )
         lg = jnp.where(lg < cut, -jnp.inf, lg)
-    if top_k is not None:
-        # clamp: an oversized k (unvalidated client kwarg) must degrade to
-        # "no truncation", not crash the whole serving micro-batch
-        kth = jax.lax.top_k(lg, min(int(top_k), lg.shape[-1]))[0][..., -1:]
-        lg = jnp.where(lg < kth, -jnp.inf, lg)
-    if top_p is not None:
-        # top_p may be a TRACED scalar (serving varies it per request
-        # without recompiles — same treatment as temperature)
+    if top_k is not None or top_p is not None:
         sorted_lg = jnp.sort(lg, axis=-1)[..., ::-1]  # descending
+    if top_k is not None:
+        top_k = jnp.broadcast_to(jnp.asarray(top_k, jnp.int32), lg.shape[:-1] + (1,))
+        kth = jnp.take_along_axis(
+            sorted_lg, jnp.clip(top_k - 1, 0, lg.shape[-1] - 1), axis=-1
+        )
+        kth = jnp.where(top_k > 0, kth, -jnp.inf)
+        lg = jnp.where(lg < kth, -jnp.inf, lg)
+        sorted_lg = jnp.where(sorted_lg < kth, -jnp.inf, sorted_lg)
+    if top_p is not None:
         probs = jax.nn.softmax(sorted_lg, axis=-1)
         # exclusive prefix mass: token i survives while the mass BEFORE it
         # is still < top_p; the top token is forced alive so non-positive
@@ -938,100 +839,22 @@ def apply_repetition_penalty(logits, seen, penalty):
     return jnp.where(seen, scaled, logits)
 
 
-def decode_chunk(
-    tree,
-    k_cache,
-    v_cache,
-    logits,
-    pos,
-    done,
-    key,
-    temp,
-    cfg: DecoderConfig,
-    n_steps: int,
-    greedy: bool,
-    eos_id: int | None,
-    top_k: int | None = None,
-    top_p: float | None = None,
-    min_p: float | None = None,
-    rep_penalty=None,
-    seen=None,
-):
-    """``n_steps`` generation steps fused into ONE device program.
-
-    A ``lax.scan`` over sample→decode_step, with sampling and EOS masking
-    on device: the host dispatches once and syncs once per chunk instead
-    of once per token, so per-call dispatch latency is amortized over the
-    chunk.
-
-    Carries ``(logits, caches, pos, done, key)``; emits per step
-    ``(token [B], valid [B])`` where ``valid`` marks tokens the caller
-    should append (False once a row has finished or sampled EOS).  Rows
-    past their EOS keep stepping on garbage — their emissions are masked,
-    matching the per-token host loop this replaces.
-    """
-
-    _require_uniform(cfg, "decode_chunk (the static path)")
-    use_rep = rep_penalty is not None
-
-    def body(carry, _):
-        if use_rep:
-            logits, kc, vc, pos, done, key, seen = carry
-            lg_eff = apply_repetition_penalty(logits, seen, rep_penalty)
-        else:
-            logits, kc, vc, pos, done, key = carry
-            lg_eff = logits
-        key, sub = jax.random.split(key)
-        if greedy:
-            tok = jnp.argmax(lg_eff, axis=-1).astype(jnp.int32)
-        else:
-            tok = sample_logits(
-                lg_eff, sub, temp, top_k=top_k, top_p=top_p, min_p=min_p
-            )
-        if eos_id is not None:
-            stop = tok == eos_id
-        else:
-            stop = jnp.zeros_like(done)
-        valid = jnp.logical_and(~done, ~stop)
-        done = jnp.logical_or(done, stop)
-        logits, kc, vc = decode_step(tree, kc, vc, tok, pos, cfg)
-        pos = pos + 1
-        if use_rep:
-            seen = jnp.logical_or(
-                seen, jax.nn.one_hot(tok, lg_eff.shape[-1], dtype=bool)
-            )
-            return (logits, kc, vc, pos, done, key, seen), (tok, valid)
-        return (logits, kc, vc, pos, done, key), (tok, valid)
-
-    carry = (logits, k_cache, v_cache, pos, done, key)
-    if use_rep:
-        carry = carry + (seen,)
-    carry, (toks, valids) = lax.scan(body, carry, None, length=n_steps)
-    if use_rep:
-        logits, k_cache, v_cache, pos, done, key, seen = carry
-        return toks, valids, logits, k_cache, v_cache, pos, done, key, seen
-    logits, k_cache, v_cache, pos, done, key = carry
-    return toks, valids, logits, k_cache, v_cache, pos, done, key
-
-
 # ---------------------------------------------------------------------------
 # Paged KV cache (continuous-batching serving path)
 # ---------------------------------------------------------------------------
 #
-# The dense cache above is one [L, B, max_cache, KH, D] block per K/V —
-# every row pays for the worst case.  The paged layout stores KV in
-# fixed-size PAGES of a preallocated pool ([L, P, page, KH, D]) with a
-# per-slot block table mapping logical positions onto pages, so cache
-# memory scales with LIVE tokens (the Ragged Paged Attention layout,
-# PAPERS.md).  Page 0 is the reserved null page: unallocated block-table
-# entries point at it, padding writes land in it, and no slot's attention
-# mask ever reaches into it.  The continuous-batching scheduler
-# (pathway_tpu/serving/generation.py) owns the host-side PageAllocator
-# and drives the two device programs below; all compiled shapes are
-# static (slot count fixed, prefill shapes few, block-table width
-# bucketed), so churning
-# request mixes replay warm programs — `jax.cache.miss == 0` in steady
-# state.
+# A dense cache is one [L, B, max_cache, KH, D] block per K/V: every row
+# pays for the worst case.  The paged layout stores KV in fixed-size PAGES
+# of a preallocated pool ([L, P, page, KH, D]) with a per-slot block table
+# mapping logical positions onto pages, so cache memory scales with LIVE
+# tokens (the Ragged Paged Attention layout, PAPERS.md).  Page 0 is the
+# reserved null page: unallocated block-table entries point at it, padding
+# writes land in it, and no slot's attention mask ever reaches into it.
+# The continuous-batching scheduler (pathway_tpu/serving/generation.py)
+# owns the host-side PageAllocator and drives the two device programs
+# below; all compiled shapes are static (slot count fixed, prefill shapes
+# few, block-table width bucketed), so churning request mixes replay warm
+# programs — `jax.cache.miss == 0` in steady state.
 
 
 def ring_pages(window: int, page_size: int) -> int:
@@ -1264,16 +1087,17 @@ def paged_decode_step(tree, k_pool, v_pool, block_tables, seq_lens, token,
     with ``with_stats`` the routed layers' ``[pairs, experts_hit]`` after
     them.
 
-    Shape-identical math to ``decode_step`` (pinned by tests): the
-    gathered context is just the dense cache rearranged through the block
-    table, and masked positions contribute exactly zero either way.
-    Inactive slots (block table all null) write into and gather from the
-    null page — finite garbage, masked everywhere, freeing the scheduler
-    from shipping an active-mask into the program.  A model of runs
-    (``cfg.runs``) takes ``block_tables`` as ``(tables, rings)`` and the
-    mask ``active [S]`` of the slots that decode: a slot's ring is its own
-    whether it decodes or not, so a slot that does not must not write, and
-    the routed layers count and compute the active rows only.
+    The gathered context is a dense cache rearranged through the block
+    table, and masked positions contribute exactly zero, so the step's
+    logits are the full forward's at that position (pinned by tests
+    against ``causal_lm_logits``).  Inactive slots (block table all null)
+    write into and gather from the null page — finite garbage, masked
+    everywhere, freeing the scheduler from shipping an active-mask into
+    the program.  A model of runs (``cfg.runs``) takes ``block_tables`` as
+    ``(tables, rings)`` and the mask ``active [S]`` of the slots that
+    decode: a slot's ring is its own whether it decodes or not, so a slot
+    that does not must not write, and the routed layers count and compute
+    the active rows only.
     """
     tables, rings = _split_tables(cfg, block_tables)
     S = token.shape[0]
@@ -1360,136 +1184,6 @@ def paged_prefill_chunk(tree, k_pool, v_pool, block_tables, chunk_ids,
     if with_stats:
         return logits, k_pool, v_pool, stats
     return logits, k_pool, v_pool
-
-
-def verify_block(tree, k_cache, v_cache, tokens, pos0, cfg: DecoderConfig):
-    """Forward ``K`` already-chosen tokens against the cache in ONE pass.
-
-    ``tokens [B, K]`` sit at positions ``pos0 + 0..K-1`` (``pos0 [B]``);
-    the caches hold history for positions ``< pos0`` and empty (zero)
-    slots at the block's positions.  Returns ``(logits [B, K, V] f32,
-    k_cache, v_cache)`` with the block's K/V written in — exactly what
-    ``K`` sequential ``decode_step`` calls would produce, but as one
-    batched program: this is the verification pass of speculative
-    decoding (all K target-model logits for the draft block at the cost
-    of one matmul sweep instead of K).
-    """
-    _require_uniform(cfg, "verify_block (the static path)")
-    B, K = tokens.shape
-    C = k_cache.shape[2]
-    KH, D = cfg.kv_heads, cfg.head_dim
-    x = tree["embed"][tokens]  # [B, K, H]
-    positions = pos0[:, None] + jnp.arange(K)[None, :]  # [B, K]
-    idx = jnp.arange(C)[None, None, :]  # [1, 1, C]
-    # query i attends to every cache slot <= its own position (the block's
-    # K/V are scattered in before attending, so self/intra-block edges are
-    # included); sliding window bounds the lookback like decode_step
-    mask = idx <= positions[:, :, None]
-    if cfg.sliding_window is not None:
-        mask = mask & _sw_mask(positions[:, :, None], idx, cfg.sliding_window)
-    onehot = (idx[:, :, :, None] == positions[:, :, None, None]).astype(
-        cfg.dtype
-    )  # [B, K, C, 1] — scatter weights per block token
-
-    def layer(x, lp):
-        lp, kc, vc = lp
-        h = _rms(x, lp["ln0"], cfg.norm_eps)
-        q = _mm(h, lp["wq"]).reshape(B, K, cfg.heads, D)
-        k = _mm(h, lp["wk"]).reshape(B, K, KH, D)
-        v = _mm(h, lp["wv"]).reshape(B, K, KH, D)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
-        kc = kc + jnp.einsum("bkcx,bkhd->bchd", onehot, k)
-        vc = vc + jnp.einsum("bkcx,bkhd->bchd", onehot, v)
-        x = x + _mm(_attend(q, kc, vc, mask, cfg), lp["wo"])
-        h = _rms(x, lp["ln1"], cfg.norm_eps)
-        mlp, _ = _ffn(lp, h, cfg, serving=True)
-        x = x + mlp
-        return x, (kc, vc)
-
-    x, (k_cache, v_cache) = lax.scan(layer, x, (tree["layers"], k_cache, v_cache))
-    x = _rms(x, tree["final_norm"], cfg.norm_eps)
-    logits = _mm(x, tree["lm_head"]).astype(jnp.float32)
-    return logits, k_cache, v_cache
-
-
-def speculative_decode_chunk(
-    tree,
-    draft_tree,
-    k_cache,
-    v_cache,
-    logits,
-    pos,
-    cfg: DecoderConfig,
-    n_draft: int,
-    done=None,
-):
-    """One greedy speculative round: draft ``n_draft`` tokens with
-    ``draft_tree`` (sequential single-token decodes — cheap when the
-    draft is the int8-quantized tree), then verify them against ``tree``
-    with ONE ``verify_block`` sweep and accept the longest matching
-    prefix.
-
-    The emitted chain is EXACTLY the target model's greedy chain:
-    ``toks[:, 0]`` is the argmax of the incoming (target) logits, and
-    each further draft token only counts if the target's own argmax at
-    the preceding position agrees.  At least one token is accepted per
-    round (guaranteed progress); up to ``n_draft`` when the draft tracks
-    the target — which is what buys throughput: the target model then
-    runs one batched K-token sweep instead of K sequential single-token
-    steps.
-
-    Returns ``(toks [B, n_draft], n_match [B], next_logits, k_cache,
-    v_cache, pos + n_match)``; ``toks[b, :n_match[b]]`` are the accepted
-    tokens, the caches hold target-model K/V for exactly the accepted
-    positions (unaccepted writes are zeroed so the slots stay scatter-
-    ready), and ``next_logits`` are the target logits after the last
-    accepted token.
-
-    ``done [B] bool`` freezes finished rows: their ``n_match`` is 0, so
-    ``pos`` does not advance and every cache write for the round's block
-    is zeroed — a finished row's state is bit-identical across rounds.
-    Residual invariant (active rows only, final round): the block's last
-    draft positions may exceed the cache length ``C`` by up to
-    ``n_draft - 1``; ``verify_block``'s one-hot scatter (idx ==
-    positions) writes nothing for positions >= C, so overflow writes are
-    no-ops by construction.
-    """
-    _require_uniform(cfg, "speculative_decode_chunk (the static path)")
-    B = logits.shape[0]
-    C = k_cache.shape[2]
-
-    def draft_step(carry, _):
-        lg, dk, dv, p = carry
-        tok = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-        lg, dk, dv = decode_step(draft_tree, dk, dv, tok, p, cfg)
-        return (lg, dk, dv, p + 1), tok
-
-    # draft K/V lives in scan-carried copies; the real cache is untouched
-    _, toks = lax.scan(
-        draft_step, (logits, k_cache, v_cache, pos), None, length=n_draft
-    )
-    toks = toks.swapaxes(0, 1)  # [B, n_draft]
-
-    vlogits, k_cache, v_cache = verify_block(tree, k_cache, v_cache, toks, pos, cfg)
-    pred = jnp.argmax(vlogits, axis=-1).astype(jnp.int32)  # target's next-token
-    match = (toks[:, 1:] == pred[:, :-1]).astype(jnp.int32)
-    n_match = 1 + jnp.cumprod(match, axis=1).sum(axis=1)  # [B] 0..n_draft (0 = done row)
-    if done is not None:
-        n_match = jnp.where(done, 0, n_match)
-    next_logits = jnp.take_along_axis(
-        vlogits,
-        jnp.maximum(n_match - 1, 0)[:, None, None].repeat(vlogits.shape[-1], 2),
-        axis=1,
-    )[:, 0]
-    # zero the rejected positions' K/V so those slots stay additive-ready
-    cidx = jnp.arange(C)[None, :]
-    keep = ~(
-        (cidx >= (pos + n_match)[:, None]) & (cidx < (pos + n_draft)[:, None])
-    )
-    k_cache = k_cache * keep[None, :, :, None, None].astype(k_cache.dtype)
-    v_cache = v_cache * keep[None, :, :, None, None].astype(v_cache.dtype)
-    return toks, n_match, next_logits, k_cache, v_cache, pos + n_match
 
 
 # ---------------------------------------------------------------------------
@@ -1584,13 +1278,12 @@ def load_hf_decoder_weights(model_name: str, cfg: DecoderConfig):
 
 
 class DecoderLM:
-    """Local decoder LLM: tokenizer + jitted prefill/decode + sampling.
+    """A local decoder LLM as the generation scheduler takes it: config,
+    weights (float, or weight-only int8), tokenizer and cache budget.
 
-    Generation dispatches ``decode_chunk`` programs — up to 16 decode
-    steps (sampling and EOS masking included) fused into one device call,
-    with a single host sync per chunk.  Each chunk program is compiled
-    once per (batch, cache, steps-bucket) shape and reused for every
-    generation.
+    It generates nothing itself: ``serving/generation.py``'s
+    ``GenerationScheduler(lm)`` (or ``shared_scheduler``, which ``JaxChat``
+    uses) drives the paged programs below over these weights.
     """
 
     def __init__(
@@ -1621,269 +1314,18 @@ class DecoderLM:
             # weight-only int8: halves the HBM bytes every decode step
             # sweeps (decode is bandwidth-bound, so ~2x tokens/s headroom)
             self.params = quantize_decoder_tree(self.params)
-        cfg = self.config
-        self._prefill = jax.jit(
-            lambda t, ids, lens: prefill(t, ids, lens, cfg, self.max_cache)
-        )
-        # device-side multi-token decode: up to _chunk_len steps fuse into
-        # one dispatch; power-of-two step buckets keep short generations
-        # from over-running while bounding compile variants
-        self._chunk_len = 16
-        self._chunk_fns: dict[tuple, Any] = {}
-        # self-speculative decoding: int8 draft tree + jitted round fns
-        self._draft_tree = None
-        self._spec_fns: dict[int, Any] = {}
-
-    def _chunk_fn(self, greedy: bool, n_steps: int, top_k: int | None,
-                  has_top_p: bool, has_min_p: bool = False,
-                  has_rep: bool = False):
-        # top_k must be static (lax.top_k shape) but top_p/min_p/the
-        # repetition penalty are TRACED — a serving client sweeping them
-        # must not recompile per value, so the cache keys only which
-        # knobs exist (their filters cost a sort/softmax/[B,V] mask, so
-        # absent knobs compile leaner programs)
-        cache_key = (greedy, n_steps, top_k, has_top_p, has_min_p, has_rep)
-        fn = self._chunk_fns.get(cache_key)
-        if fn is None:
-            cfg = self.config
-            eos_id = self.eos_id
-
-            def chunk(t, kc, vc, lg, pos, done, key, temp, *extra):
-                i = 0
-                tp = extra[i] if has_top_p else None
-                i += int(has_top_p)
-                mp = extra[i] if has_min_p else None
-                i += int(has_min_p)
-                rp = extra[i] if has_rep else None
-                sn = extra[i + 1] if has_rep else None
-                return decode_chunk(
-                    t, kc, vc, lg, pos, done, key, temp, cfg,
-                    n_steps, greedy, eos_id, top_k, tp, mp, rp, sn,
-                )
-
-            fn = jax.jit(chunk)
-            self._chunk_fns[cache_key] = fn
-        return fn
 
     def n_params(self) -> int:
         return sum(
             int(np.prod(p.shape)) for p in jax.tree_util.tree_leaves(self.params)
         )
 
-    def generate_ids(
-        self,
-        prompt_ids: list[list[int]],
-        max_new_tokens: int = 64,
-        temperature: float = 0.0,
-        seed: int = 0,
-        top_k: int | None = None,
-        top_p: float | None = None,
-        min_p: float | None = None,
-        repetition_penalty: float | None = None,
-    ) -> list[list[int]]:
-        """Batched generation; returns the newly generated ids per row.
-
-        ``top_k``/``top_p``/``min_p`` truncate the sampling distribution
-        on device (only meaningful with ``temperature > 0``);
-        ``repetition_penalty`` (HF semantics, > 1 discourages repeats)
-        penalizes every token already in the prompt or generated so far.
-        Prompts longer than the cache budget keep their TAIL (the recent
-        context — the part chat serving cares about)."""
-        if max_new_tokens >= self.max_cache:
-            raise ValueError(
-                f"max_new_tokens={max_new_tokens} must be < max_cache={self.max_cache}"
-            )
-        if repetition_penalty is not None and repetition_penalty <= 0:
-            # HF semantics: penalty 0 would divide logits by zero (turning
-            # repeats into the unconditional winner) and negatives flip
-            # the sign branches — reject like RepetitionPenaltyLogitsProcessor
-            raise ValueError(
-                f"repetition_penalty must be > 0, got {repetition_penalty}"
-            )
-        B = len(prompt_ids)
-        limit = self.max_cache - max_new_tokens
-        prompt_ids = [p[-limit:] if len(p) > limit else p for p in prompt_ids]
-        lengths = np.array([max(len(p), 1) for p in prompt_ids], np.int32)
-        S = _bucket_prompt_len(int(lengths.max()), self.max_cache)
-        ids = np.zeros((B, S), np.int32)
-        for i, p in enumerate(prompt_ids):
-            ids[i, : len(p)] = p
-        logits, kc, vc = self._prefill(
-            self.params, jnp.asarray(ids), jnp.asarray(lengths)
-        )
-        key = jax.random.PRNGKey(seed)
-        pos = jnp.asarray(lengths)  # next write position per row
-        done = jnp.zeros(B, bool)
-        temp = jnp.float32(temperature if temperature > 0.0 else 1.0)
-        greedy = temperature <= 0.0
-        seen = None
-        if repetition_penalty is not None:
-            # HF counts the prompt too: mark every real prompt token
-            valid_pos = np.zeros((B, S), bool)
-            for i, p in enumerate(prompt_ids):
-                valid_pos[i, : len(p)] = True
-            seen0 = np.zeros((B, self.config.vocab_size), bool)
-            rows = np.repeat(np.arange(B), S)
-            np.maximum.at(
-                seen0, (rows, ids.reshape(-1)), valid_pos.reshape(-1)
-            )
-            seen = jnp.asarray(seen0)
-        out: list[list[int]] = [[] for _ in range(B)]
-        produced = 0
-        while produced < max_new_tokens:
-            remaining = max_new_tokens - produced
-            # next power-of-two bucket covering `remaining`, capped at the
-            # chunk length: short generations run exactly-sized programs
-            K = min(self._chunk_len, 1 << (remaining - 1).bit_length())
-            args = (self.params, kc, vc, logits, pos, done, key, temp)
-            if top_p is not None:
-                args += (jnp.float32(top_p),)
-            if min_p is not None:
-                args += (jnp.float32(min_p),)
-            if repetition_penalty is not None:
-                args += (jnp.float32(repetition_penalty), seen)
-            res = self._chunk_fn(
-                greedy, K, top_k, top_p is not None, min_p is not None,
-                repetition_penalty is not None,
-            )(*args)
-            if repetition_penalty is not None:
-                toks, valids, logits, kc, vc, pos, done, key, seen = res
-            else:
-                toks, valids, logits, kc, vc, pos, done, key = res
-            # one host sync per chunk (vs one per token): tokens, validity
-            # and the done flags arrive together
-            htoks = np.asarray(toks)
-            hvalid = np.asarray(valids)
-            take = min(K, remaining)
-            for t in range(take):
-                for i in range(B):
-                    if hvalid[t, i]:
-                        out[i].append(int(htoks[t, i]))
-            produced += take
-            if np.asarray(done).all():
-                break
-        return out
-
-    def generate_ids_speculative(
-        self,
-        prompt_ids: list[list[int]],
-        max_new_tokens: int = 64,
-        n_draft: int = 8,
-    ) -> list[list[int]]:
-        """Greedy generation via SELF-SPECULATIVE decoding.
-
-        Drafts ``n_draft`` tokens per round with the int8-quantized tree
-        (half the HBM sweep per draft step), verifies them with the float
-        tree in one ``verify_block`` sweep, and accepts the matching
-        prefix — the emitted chain is IDENTICAL to
-        ``generate_ids(temperature=0)`` (pinned by tests), but the float
-        model runs one batched K-token pass per round instead of K
-        single-token steps.  Worth it when the int8 draft tracks the
-        float argmax (typically >90% — see test_quantized_decoder).
-        """
-        if self.quantized:
-            raise ValueError(
-                "speculative decoding verifies with the float tree: "
-                "construct DecoderLM without quantize (the int8 draft is "
-                "built internally)"
-            )
-        if max_new_tokens >= self.max_cache:
-            raise ValueError(
-                f"max_new_tokens={max_new_tokens} must be < max_cache={self.max_cache}"
-            )
-        if self._draft_tree is None:
-            self._draft_tree = quantize_decoder_tree(self.params)
-        spec = self._spec_fns.get(n_draft)
-        if spec is None:
-            cfg = self.config
-            spec = jax.jit(
-                lambda t, d, kc, vc, lg, ps, dn: speculative_decode_chunk(
-                    t, d, kc, vc, lg, ps, cfg, n_draft, done=dn
-                )
-            )
-            self._spec_fns[n_draft] = spec
-
-        B = len(prompt_ids)
-        limit = self.max_cache - max_new_tokens
-        prompt_ids = [p[-limit:] if len(p) > limit else p for p in prompt_ids]
-        lengths = np.array([max(len(p), 1) for p in prompt_ids], np.int32)
-        S = _bucket_prompt_len(int(lengths.max()), self.max_cache)
-        ids = np.zeros((B, S), np.int32)
-        for i, p in enumerate(prompt_ids):
-            ids[i, : len(p)] = p
-        logits, kc, vc = self._prefill(
-            self.params, jnp.asarray(ids), jnp.asarray(lengths)
-        )
-        pos = jnp.asarray(lengths)
-        out: list[list[int]] = [[] for _ in range(B)]
-        done = np.zeros(B, bool)
-        while not done.all():
-            # done mask freezes finished rows on device: pos stays put and
-            # their block writes are zeroed (no work drift past cache end)
-            toks, n_match, logits, kc, vc, pos = spec(
-                self.params, self._draft_tree, kc, vc, logits, pos, jnp.asarray(done)
-            )
-            htoks = np.asarray(toks)
-            hn = np.asarray(n_match)
-            for i in range(B):
-                if done[i]:
-                    continue
-                for t in range(int(hn[i])):
-                    tok = int(htoks[i, t])
-                    if self.eos_id is not None and tok == self.eos_id:
-                        done[i] = True
-                        break
-                    out[i].append(tok)
-                    if len(out[i]) >= max_new_tokens:
-                        done[i] = True
-                        break
-        return out
-
-    def generate(
-        self,
-        prompt: str,
-        max_new_tokens: int = 64,
-        temperature: float = 0.0,
-        seed: int = 0,
-        top_k: int | None = None,
-        top_p: float | None = None,
-        min_p: float | None = None,
-        repetition_penalty: float | None = None,
-    ) -> str:
-        ids = self._encode_prompt(prompt)
-        new_ids = self.generate_ids(
-            [ids], max_new_tokens, temperature, seed,
-            top_k=top_k, top_p=top_p, min_p=min_p,
-            repetition_penalty=repetition_penalty,
-        )[0]
-        return self.tokenizer.decode(new_ids)
-
     def _encode_prompt(self, prompt: str) -> list[int]:
         """Tokenize at the MODEL limit, not the cache limit: tokenizers
         truncate from the head, but chat serving must keep the prompt's
-        TAIL — ``generate_ids`` does that tail-keeping against the cache
-        budget itself."""
+        TAIL — the scheduler does that tail-keeping against the cache
+        budget itself (``GenerationScheduler.submit_request``)."""
         return self.tokenizer.encode(prompt, max_length=self.config.max_len)
-
-    def generate_many(
-        self,
-        prompts: list[str],
-        max_new_tokens: int = 64,
-        temperature: float = 0.0,
-        seed: int = 0,
-        top_k: int | None = None,
-        top_p: float | None = None,
-        min_p: float | None = None,
-        repetition_penalty: float | None = None,
-    ) -> list[str]:
-        """One padded ragged batch through prefill+decode for all prompts."""
-        id_lists = [self._encode_prompt(p) for p in prompts]
-        outs = self.generate_ids(
-            id_lists, max_new_tokens, temperature, seed,
-            top_k=top_k, top_p=top_p, min_p=min_p,
-            repetition_penalty=repetition_penalty,
-        )
-        return [self.tokenizer.decode(o) for o in outs]
 
 
 @functools.lru_cache(maxsize=4)

@@ -10,11 +10,10 @@ adapter state (megabytes, not gigabytes) is what the optimizer carries
 and the checkpointer saves.
 
 ``_mm`` in ``models/decoder.py`` recognises the ``{"w", "a", "b"}``
-leaves, so LoRA trees run through prefill, chunked decode, and the
-pipelined trunk unchanged.  Quantization and speculative decoding (which
-builds an int8 draft internally) need plain trees — ``merge_lora`` the
-adapters back into plain weights first; ``quantize_decoder_tree``
-rejects adapted trees with that instruction.
+leaves, so LoRA trees run through the full forward, the scheduler's
+paged programs and the pipelined trunk unchanged.  Quantization needs a
+plain tree — ``merge_lora`` the adapters back into plain weights first;
+``quantize_decoder_tree`` rejects adapted trees with that instruction.
 """
 
 from __future__ import annotations

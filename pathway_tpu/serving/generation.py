@@ -1,11 +1,10 @@
 """Continuous-batching generation scheduler over the paged KV cache.
 
-The static serving path batches requests per sampling config and runs
-each batch to completion (``utils/batching.py`` → ``DecoderLM
-.generate_many``): every request waits for the slowest row in its batch,
-new arrivals wait for the whole batch to drain, and the dense KV cache
-pays ``B × max_cache`` regardless of live tokens.  This module replaces
-that loop with the vLLM/Ragged-Paged-Attention serving shape (PAPERS.md):
+The one generation path: every request, whatever its sampling, decodes
+here.  A static batch would make every request wait for the slowest row
+of its batch and every arrival for the batch to drain, over a dense KV
+cache that pays ``B × max_cache`` regardless of live tokens; this is the
+vLLM/Ragged-Paged-Attention serving shape instead (PAPERS.md):
 
 * **Slots** — a fixed device batch of ``S`` generation slots.  At every
   decode step, finished/lapsed rows are evicted immediately and queued
@@ -41,6 +40,14 @@ that loop with the vLLM/Ragged-Paged-Attention serving shape (PAPERS.md):
   the requests it decoded for, so a slot released and taken again
   meanwhile never receives the stale token, and the newcomer's prefill,
   later in the device's order, overwrites what the stale step wrote.
+* **Sampling is data, a slot** — temperature, ``top_p``, ``min_p``,
+  ``top_k`` and the repetition penalty ride into the step as one value a
+  slot, so requests of any mix share a batch and no value compiles
+  anything.  The last two read more than the step's logits (a cut at the
+  k-th largest; the tokens a slot has seen, kept on the device), so they
+  have a step program of their own, and a tick takes it only while some
+  live slot asked for either: the step every other batch runs is not
+  touched by them.
 
 Every device program has a static shape: slot count fixed, prefill
 rows and width one of the few :func:`prefill_ladder` allows, block-table
@@ -61,7 +68,7 @@ from typing import Any, NamedTuple
 import numpy as np
 
 from pathway_tpu.engine import tracing
-from pathway_tpu.internals.config import env_bool, env_int
+from pathway_tpu.internals.config import env_int
 
 __all__ = [
     "GenRequest",
@@ -118,8 +125,9 @@ class GenRequest:
 
     __slots__ = (
         "prompt_ids", "max_new_tokens", "temperature", "top_p", "min_p",
-        "deadline", "future", "loop_future", "synthetic", "submitted_at",
-        "first_token_at", "finished_at", "out", "pages_reserved",
+        "top_k", "repetition_penalty", "deadline", "future", "loop_future",
+        "synthetic", "submitted_at", "first_token_at", "finished_at", "out",
+        "pages_reserved",
         "trace", "submitted_wall", "first_token_wall",
     )
 
@@ -131,6 +139,8 @@ class GenRequest:
         temperature: float = 0.0,
         top_p: float | None = None,
         min_p: float | None = None,
+        top_k: int | None = None,
+        repetition_penalty: float | None = None,
         deadline=None,
         synthetic: bool = False,
         trace=None,
@@ -140,6 +150,8 @@ class GenRequest:
         self.temperature = temperature
         self.top_p = top_p
         self.min_p = min_p
+        self.top_k = top_k
+        self.repetition_penalty = repetition_penalty
         self.deadline = deadline
         self.future: Future = Future()
         self.synthetic = synthetic
@@ -155,6 +167,12 @@ class GenRequest:
         self.finished_at: float | None = None
         self.out: list[int] = []
         self.pages_reserved = 0
+
+    @property
+    def wants_history(self) -> bool:
+        """Whether the request's sampling reads more than the step's
+        logits: a cut at the k-th largest, or the tokens seen so far."""
+        return self.top_k is not None or self.repetition_penalty is not None
 
     @property
     def ttft_s(self) -> float | None:
@@ -298,6 +316,15 @@ class GenerationScheduler:
         self._temps = np.zeros(self.slots, np.float32)
         self._top_ps = np.ones(self.slots, np.float32)
         self._min_ps = np.zeros(self.slots, np.float32)
+        # what the history-carrying step reads besides: a slot's k (0: no
+        # cut), its repetition penalty (1: none) and, on the device, the
+        # tokens it has seen (allocated with the first request that asks)
+        self._top_ks = np.zeros(self.slots, np.int32)
+        self._penalties = np.ones(self.slots, np.float32)
+        self._seen = None
+        # live slots whose request asked for either: while there is one, a
+        # tick takes the history-carrying step
+        self._history_slots = 0
 
         cfg = self.cfg
         # a model of runs, or with routed experts, is told which slots
@@ -309,14 +336,16 @@ class GenerationScheduler:
         self._no_stats = jnp.zeros((2,), jnp.int32)
         self._prefill_stats = self._no_stats
 
-        def _decode(tree, kp, vp, bt, sl, lg, key, temp, top_p, min_p, *counts):
+        def _sample(lg, key, temp, top_p, min_p, top_k=None):
             with jax.named_scope("sample"):
                 greedy_tok = jnp.argmax(lg, axis=-1).astype(jnp.int32)
                 sampled = dec.sample_logits(
-                    lg, key, jnp.maximum(temp, 1e-6)[:, None],
+                    lg, key, jnp.maximum(temp, 1e-6)[:, None], top_k=top_k,
                     top_p=top_p[:, None], min_p=min_p[:, None],
                 )
-                tok = jnp.where(temp > 0.0, sampled, greedy_tok)
+                return jnp.where(temp > 0.0, sampled, greedy_tok)
+
+        def _advance(tree, kp, vp, bt, sl, tok, *counts):
             if not counted:
                 lg2, kp, vp = dec.paged_decode_step(tree, kp, vp, bt, sl, tok, cfg)
                 return tok, lg2, kp, vp
@@ -325,6 +354,26 @@ class GenerationScheduler:
                 tree, kp, vp, bt, sl, tok, cfg, active=active, with_stats=True
             )
             return jnp.concatenate([tok, stats, carried]), lg2, kp, vp
+
+        def _decode(tree, kp, vp, bt, sl, lg, key, temp, top_p, min_p, *counts):
+            tok = _sample(lg, key, temp, top_p, min_p)
+            return _advance(tree, kp, vp, bt, sl, tok, *counts)
+
+        def _decode_history(tree, kp, vp, bt, sl, lg, key, temp, top_p, min_p,
+                            top_k, penalty, seen, active, *carried):
+            """The step for a batch in which some slot asked for ``top_k``
+            or a repetition penalty: the penalty over what the slot has
+            ``seen [slots, vocab]`` (its prompt and its tokens so far), the
+            cut at its k, and the sampled token joins ``seen`` where the
+            slot decodes.  A slot that asked for neither carries 0 and 1.0
+            and samples what the plain step would."""
+            lg = dec.apply_repetition_penalty(lg, seen, penalty[:, None])
+            tok = _sample(lg, key, temp, top_p, min_p, top_k[:, None])
+            seen = seen | (
+                active[:, None] & jax.nn.one_hot(tok, lg.shape[-1], dtype=bool)
+            )
+            counts = (active, *carried) if counted else ()
+            return *_advance(tree, kp, vp, bt, sl, tok, *counts), seen
 
         def _prefill(tree, kp, vp, bt, ids, cl, st, old_lg, lanes, take, *carried):
             lg, kp, vp, *stats = dec.paged_prefill_chunk(
@@ -339,6 +388,8 @@ class GenerationScheduler:
             return out
 
         self._decode_fn = jax.jit(_decode)
+        self._decode_history_fn = jax.jit(_decode_history)
+        self._seed_seen_fn = jax.jit(lambda seen, i, row: seen.at[i].set(row))
         self._prefill_fn = jax.jit(_prefill)
 
         self._lock = threading.Condition()
@@ -450,6 +501,8 @@ class GenerationScheduler:
         temperature: float = 0.0,
         top_p: float | None = None,
         min_p: float | None = None,
+        top_k: int | None = None,
+        repetition_penalty: float | None = None,
         deadline=None,
         synthetic: bool = False,
     ) -> GenRequest:
@@ -457,6 +510,12 @@ class GenerationScheduler:
         the per-request telemetry (``ttft_s``, ``finished_at``) the
         serving benchmark reads; its ``.future`` resolves to the
         generated id list.
+
+        ``top_p`` / ``min_p`` / ``top_k`` truncate the sampling
+        distribution (only meaningful with ``temperature > 0``);
+        ``repetition_penalty`` (HF semantics, > 1 discourages repeats)
+        penalizes every token of the prompt or generated so far, greedy
+        rows included.
 
         Raises :class:`OverloadedError` when the bounded queue is full
         (the page pool's backpressure — never an OOM) and
@@ -469,6 +528,17 @@ class GenerationScheduler:
                 f"max_new_tokens={max_new_tokens} must be < "
                 f"max_cache={self.max_cache}"
             )
+        if top_k is not None and top_k < 1:
+            raise ValueError(f"top_k must be >= 1, got {top_k}")
+        if repetition_penalty is not None and repetition_penalty <= 0:
+            # HF semantics: penalty 0 would divide logits by zero (turning
+            # repeats into the unconditional winner) and negatives flip
+            # the sign branches — reject like RepetitionPenaltyLogitsProcessor
+            raise ValueError(
+                f"repetition_penalty must be > 0, got {repetition_penalty}"
+            )
+        if repetition_penalty == 1.0:
+            repetition_penalty = None  # no penalty: the plain step serves it
         if deadline is None:
             deadline = edge.current_deadline()
         if deadline is not None and deadline.expired():
@@ -482,8 +552,9 @@ class GenerationScheduler:
             prompt_ids = [0]
         req = GenRequest(
             prompt_ids, max_new_tokens, temperature=temperature,
-            top_p=top_p, min_p=min_p, deadline=deadline, synthetic=synthetic,
-            trace=tracing.current_trace(),
+            top_p=top_p, min_p=min_p, top_k=top_k,
+            repetition_penalty=repetition_penalty, deadline=deadline,
+            synthetic=synthetic, trace=tracing.current_trace(),
         )
         with self._lock:
             if len(self._queue) >= self.queue_limit:
@@ -742,7 +813,23 @@ class GenerationScheduler:
             self._temps[i] = req.temperature
             self._top_ps[i] = 1.0 if req.top_p is None else req.top_p
             self._min_ps[i] = 0.0 if req.min_p is None else req.min_p
+            if req.wants_history:
+                self._admit_history(i, req)
         self._queue[:] = remaining
+
+    def _admit_history(self, i: int, req: GenRequest) -> None:
+        """Slot ``i`` takes a request that asked for ``top_k`` or a
+        repetition penalty: its ``seen`` row starts from the prompt's
+        tokens (HF counts the prompt too), whatever the slot held before."""
+        jnp = self._jnp
+        self._history_slots += 1
+        self._top_ks[i] = req.top_k or 0
+        self._penalties[i] = req.repetition_penalty or 1.0
+        if self._seen is None:
+            self._seen = jnp.zeros((self.slots, self.cfg.vocab_size), bool)
+        row = np.zeros(self.cfg.vocab_size, bool)
+        row[req.prompt_ids] = True
+        self._seen = self._seed_seen_fn(self._seen, jnp.int32(i), jnp.asarray(row))
 
     def _maybe_inject_churn(self) -> None:
         """The ``request_churn`` fault: a burst of short synthetic
@@ -788,6 +875,10 @@ class GenerationScheduler:
         self._temps[i] = 0.0
         self._top_ps[i] = 1.0
         self._min_ps[i] = 0.0
+        if slot.req.wants_history:
+            self._history_slots -= 1
+            self._top_ks[i] = 0
+            self._penalties[i] = 1.0
 
     def _tables(self, block_tables: np.ndarray, lanes=slice(None)):
         """The tables a paged program takes for the slots ``lanes``: their
@@ -928,23 +1019,40 @@ class GenerationScheduler:
             temps = self._temps.copy()
             top_ps = self._top_ps.copy()
             min_ps = self._min_ps.copy()
+            history = self._history_slots > 0
+            if history:
+                top_ks = self._top_ks.copy()
+                penalties = self._penalties.copy()
             for i, slot in taken:
                 slot.seq_len += 1
                 self._seq_lens[i] = slot.seq_len
         counts = ()
-        if self._counted:
+        if self._counted or history:
             active = np.zeros(self.slots, bool)
             active[rows] = True
-            counts = (jnp.asarray(active), self._prefill_stats)
+            counts = (jnp.asarray(active),)
+        if self._counted:
+            counts += (self._prefill_stats,)
             self._prefill_stats = self._no_stats
         self._key, sub = jax.random.split(self._key)
         self._next_phase("tick.decode.enqueue")
         self._enqueued()
-        tok, self._logits, self._k_pool, self._v_pool = self._decode_fn(
+        args = (
             self.lm.params, self._k_pool, self._v_pool, self._tables(bt),
             jnp.asarray(sl), self._logits, sub, jnp.asarray(temps),
-            jnp.asarray(top_ps), jnp.asarray(min_ps), *counts,
+            jnp.asarray(top_ps), jnp.asarray(min_ps),
         )
+        if history:
+            tok, self._logits, self._k_pool, self._v_pool, self._seen = (
+                self._decode_history_fn(
+                    *args, jnp.asarray(top_ks), jnp.asarray(penalties),
+                    self._seen, *counts,
+                )
+            )
+        else:
+            tok, self._logits, self._k_pool, self._v_pool = self._decode_fn(
+                *args, *counts
+            )
         tok.copy_to_host_async()  # on its way before the host asks for it
         self._m_decode_steps.inc()
         return _Step(tok, [(i, slot.req) for i, slot in taken], self._programs)
@@ -1112,10 +1220,6 @@ class GenerationScheduler:
 
 _shared: dict[tuple, GenerationScheduler] = {}
 _shared_lock = threading.Lock()
-
-
-def continuous_enabled() -> bool:
-    return env_bool("PATHWAY_GENERATE_CONTINUOUS")
 
 
 def shared_scheduler(

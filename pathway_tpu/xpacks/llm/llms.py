@@ -191,18 +191,20 @@ class HFPipelineChat(BaseChat):
 
 
 class JaxChat(BaseChat):
-    """TPU-native local chat: jitted JAX decoder with a KV cache.
+    """TPU-native local chat: a jitted JAX decoder behind the continuous-
+    batching scheduler.
 
     The reference's local-serving story is a host-side torch pipeline
     (``xpacks/llm/llms.py:314`` HFPipelineChat; the Adaptive RAG template
-    runs Mistral-7B-Instruct through it).  Here generation runs as two
-    compiled XLA programs — bucketed-prompt prefill and a single-token
-    decode step reused for every generated token (``models/decoder.py``) —
-    so the serving path is device-resident end to end.  Concurrent rows of
-    an epoch are micro-batched into one padded ragged generation batch.  A
-    locally cached llama/mistral-family checkpoint is mapped in when
-    present; otherwise deterministic random weights keep shapes/FLOPs (and
-    thus serving latency) identical.
+    runs Mistral-7B-Instruct through it).  Here every row, whatever its
+    sampling options, is submitted to the process-wide
+    :func:`pathway_tpu.serving.generation.shared_scheduler` of its model:
+    rows of all routes and epochs share one running batch over a paged KV
+    cache, prefilled in chunks and decoded a token a step
+    (``models/decoder.py``), device-resident end to end.  A locally cached
+    llama/mistral-family checkpoint is mapped in when present; otherwise
+    deterministic random weights keep shapes/FLOPs (and thus serving
+    latency) identical.
     """
 
     def __init__(
@@ -211,7 +213,6 @@ class JaxChat(BaseChat):
         max_new_tokens: int = 128,
         temperature: float = 0.0,
         max_cache: int = 1024,
-        max_batch: int = 32,
         capacity: int | None = None,
         cache_strategy=None,
         quantize: str | None = None,
@@ -224,94 +225,49 @@ class JaxChat(BaseChat):
         self.max_new_tokens = max_new_tokens
         self.temperature = temperature
         self.max_cache = max_cache
-        self.max_batch = max_batch
         if quantize not in (None, "int8"):  # fail at config time, not first row
             raise ValueError(f"quantize must be None or 'int8', got {quantize!r}")
         self.quantize = quantize
-        self._model = None
+        self._built = False
         self._init_lock = None
-        self._batchers: dict[tuple, Any] = {}
 
         async def chat(messages: Any, **kwargs) -> str:
             import asyncio
 
-            from pathway_tpu.serving import generation
-
-            if self._model is None:
-                # first call compiles; keep the loop free while it does,
-                # and hold a lock so concurrent rows build it only once
+            if not self._built:
+                # the first call builds the model and its pools; keep the
+                # loop free while it does, and hold a lock so concurrent
+                # rows wait for one build
                 if self._init_lock is None:
                     self._init_lock = asyncio.Lock()
                 async with self._init_lock:
-                    if self._model is None:
-                        self._model = await asyncio.to_thread(self._build_model)
-            lm = self._model
-            mnt = int(kwargs.get("max_tokens", self.max_new_tokens))
-            temp = float(kwargs.get("temperature", self.temperature))
-            # coerce BEFORE keying: 5 and 5.0 must share one batcher (and
-            # one compiled program), and a malformed kwarg should fail
-            # here with a clear TypeError, not inside the batch worker
-            top_k = kwargs.get("top_k")
-            top_k = None if top_k is None else int(top_k)
-            top_p = kwargs.get("top_p")
-            top_p = None if top_p is None else float(top_p)
-            min_p = kwargs.get("min_p")
-            min_p = None if min_p is None else float(min_p)
-            rep = kwargs.get("repetition_penalty")
-            rep = None if rep is None else float(rep)
-            # continuous batching: every sampling config shares ONE
-            # scheduler batch (per-slot temp/top_p/min_p ride as data in
-            # the compiled step), so a new config never waits for a
-            # static batch to drain.  top_k / repetition_penalty need
-            # per-row history state the fixed-shape step doesn't carry —
-            # those configs fall back to the static batcher below.
-            if (
-                generation.continuous_enabled()
-                and top_k is None
-                and rep is None
-            ):
-                sched = generation.shared_scheduler(
-                    self.model, max_cache=self.max_cache,
-                    quantize=self.quantize,
-                )
-                fut = sched.submit(
-                    _messages_to_prompt(messages),
-                    max_new_tokens=mnt,
-                    temperature=temp,
-                    top_p=top_p,
-                    min_p=min_p,
-                )
-                return await asyncio.wrap_future(fut)
-            bkey = (mnt, temp, top_k, top_p, min_p, rep)
-            batcher = self._batchers.get(bkey)
-            if batcher is None:
-                from pathway_tpu.utils.batching import AsyncMicroBatcher
+                    if not self._built:
+                        await asyncio.to_thread(self._scheduler)
+                        self._built = True
 
-                # one batcher per sampling config; generation is seconds
-                # long, so batches run in a thread to keep the loop live
-                batcher = AsyncMicroBatcher(
-                    lambda prompts: lm.generate_many(
-                        prompts,
-                        max_new_tokens=mnt,
-                        temperature=temp,
-                        top_k=top_k,
-                        top_p=top_p,
-                        min_p=min_p,
-                        repetition_penalty=rep,
-                    ),
-                    max_batch_size=self.max_batch,
-                    flush_delay=0.01,
-                    run_in_thread=True,
-                )
-                self._batchers[bkey] = batcher
-            return await batcher.submit(_messages_to_prompt(messages))
+            def option(name, kind):
+                # coerce here: a malformed kwarg fails with a clear
+                # TypeError, not inside the scheduler's thread
+                value = kwargs.get(name)
+                return None if value is None else kind(value)
+
+            fut = self._scheduler().submit(
+                _messages_to_prompt(messages),
+                max_new_tokens=int(kwargs.get("max_tokens", self.max_new_tokens)),
+                temperature=float(kwargs.get("temperature", self.temperature)),
+                top_p=option("top_p", float),
+                min_p=option("min_p", float),
+                top_k=option("top_k", int),
+                repetition_penalty=option("repetition_penalty", float),
+            )
+            return await asyncio.wrap_future(fut)
 
         self.__wrapped__ = chat
 
-    def _build_model(self):
-        from pathway_tpu.models.decoder import shared_decoder
+    def _scheduler(self):
+        from pathway_tpu.serving import generation
 
-        return shared_decoder(
+        return generation.shared_scheduler(
             self.model, max_cache=self.max_cache, quantize=self.quantize
         )
 

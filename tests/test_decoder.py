@@ -1,9 +1,10 @@
-"""Decoder LLM (models/decoder.py): KV-cache correctness, causality,
+"""Decoder LLM (models/decoder.py): paged-cache correctness, causality,
 generation, tensor-parallel sharding, and the JaxChat serving UDF.
 
 Parity target: the reference's local chat serving
 (xpacks/llm/llms.py HFPipelineChat / the Mistral-7B Adaptive RAG
-template), re-designed as jitted prefill + cached single-token decode.
+template), re-designed as paged prefill + single-token decode behind the
+continuous-batching scheduler, held to the full causal forward.
 """
 
 import numpy as np
@@ -14,55 +15,39 @@ import jax.numpy as jnp
 
 from pathway_tpu.models.decoder import (
     DecoderLM,
-    decode_step,
+    causal_lm_logits,
     decoder_config_for,
     init_decoder_params,
-    prefill,
-    tp_cache_specs,
     tp_param_specs,
 )
+from tests.decoder_oracle import generate_ids, paged_logits, reference_greedy
 
 CFG = decoder_config_for("pw-tiny-decoder")
 TREE = init_decoder_params(CFG, seed=3)
 
 
-def _full_logits(tree, ids, lengths, cache_len):
-    """Reference: logits at every position via repeated prefill."""
-    outs = []
-    for t in range(1, int(lengths.max()) + 1):
-        lens = np.minimum(lengths, t).astype(np.int32)
-        logits, _, _ = prefill(tree, ids, jnp.asarray(lens), CFG, cache_len)
-        outs.append(np.asarray(logits))
-    return np.stack(outs, axis=1)  # [B, T, V]
+def _last_logits(tree, ids, lengths):
+    """The full forward's logits at each row's final real token."""
+    logits = np.asarray(
+        causal_lm_logits(tree, jnp.asarray(ids), jnp.asarray(lengths), CFG, serving=True)
+    )
+    return logits[np.arange(len(lengths)), np.asarray(lengths) - 1]
 
 
 def test_decode_step_matches_prefill():
-    """Incremental decode over the cache reproduces full-forward logits."""
+    """Incremental decode over the paged cache reproduces full-forward
+    logits."""
     rng = np.random.default_rng(0)
-    B, S, C = 2, 12, 32
+    B, S = 2, 12
     ids = rng.integers(1, CFG.vocab_size, size=(B, S)).astype(np.int32)
-    lengths = np.array([12, 7], np.int32)
 
     # prefill on a PREFIX, then feed the remaining real tokens one by one
     cut = 5
-    logits, kc, vc = prefill(
-        TREE, jnp.asarray(ids), jnp.asarray(np.full(B, cut, np.int32)), CFG, C
+    got = paged_logits(TREE, CFG, ids, cut)
+    full = np.asarray(
+        causal_lm_logits(TREE, jnp.asarray(ids), jnp.full((B,), S), CFG, serving=True)
     )
-    pos = jnp.asarray(np.full(B, cut, np.int32))
-    for t in range(cut, S):
-        token = jnp.asarray(ids[:, t])
-        logits, kc, vc = decode_step(TREE, kc, vc, token, pos, CFG)
-        full, _, _ = prefill(
-            TREE,
-            jnp.asarray(ids),
-            jnp.asarray(np.full(B, t + 1, np.int32)),
-            CFG,
-            C,
-        )
-        np.testing.assert_allclose(
-            np.asarray(logits), np.asarray(full), rtol=2e-4, atol=2e-4
-        )
-        pos = pos + 1
+    np.testing.assert_allclose(got, full[:, cut - 1:], rtol=2e-4, atol=2e-4)
 
 
 def test_prefill_is_causal():
@@ -70,12 +55,12 @@ def test_prefill_is_causal():
     logits read at earlier lengths."""
     rng = np.random.default_rng(1)
     ids = rng.integers(1, CFG.vocab_size, size=(1, 10)).astype(np.int32)
-    lens = jnp.asarray([6], jnp.int32)
-    base, _, _ = prefill(TREE, jnp.asarray(ids), lens, CFG, 16)
+    lens = np.asarray([6], np.int32)
+    base = _last_logits(TREE, ids, lens)
     ids2 = ids.copy()
     ids2[0, 6:] = rng.integers(1, CFG.vocab_size, size=4)
-    pert, _, _ = prefill(TREE, jnp.asarray(ids2), lens, CFG, 16)
-    np.testing.assert_allclose(np.asarray(base), np.asarray(pert), atol=1e-6)
+    pert = _last_logits(TREE, ids2, lens)
+    np.testing.assert_allclose(base, pert, atol=1e-6)
 
 
 def test_ragged_batch_rows_independent():
@@ -86,60 +71,51 @@ def test_ragged_batch_rows_independent():
     ids = np.zeros((2, 8), np.int32)
     ids[0] = a
     ids[1, :3] = b
-    lens = jnp.asarray([8, 3], jnp.int32)
-    both, _, _ = prefill(TREE, jnp.asarray(ids), lens, CFG, 16)
-    solo, _, _ = prefill(TREE, jnp.asarray(b[None, :]), jnp.asarray([3]), CFG, 16)
-    np.testing.assert_allclose(np.asarray(both)[1], np.asarray(solo)[0], atol=1e-5)
+    both = _last_logits(TREE, ids, np.asarray([8, 3], np.int32))
+    solo = _last_logits(TREE, b[None, :], np.asarray([3], np.int32))
+    np.testing.assert_allclose(both[1], solo[0], atol=1e-5)
 
 
 def test_generate_greedy_deterministic():
     lm = DecoderLM("pw-tiny-decoder", max_cache=64, eos_id=None)
-    out1 = lm.generate_ids([[5, 9, 17]], max_new_tokens=8)
-    out2 = lm.generate_ids([[5, 9, 17]], max_new_tokens=8)
+    out1 = generate_ids(lm, [[5, 9, 17]], max_new_tokens=8)
+    out2 = generate_ids(lm, [[5, 9, 17]], max_new_tokens=8)
     assert out1 == out2
     assert len(out1[0]) == 8
     assert all(0 <= t < CFG.vocab_size for t in out1[0])
 
 
 def test_generate_matches_token_by_token_prefill():
-    """Greedy generation through the cache equals greedy re-prefill argmax."""
+    """Greedy generation through the cache equals greedy re-forward argmax."""
     lm = DecoderLM("pw-tiny-decoder", max_cache=64, eos_id=None)
     prompt = [3, 7, 11, 2, 19]
-    got = lm.generate_ids([prompt], max_new_tokens=5)[0]
-    seq = list(prompt)
-    for _ in range(5):
-        ids = np.asarray([seq], np.int32)
-        logits, _, _ = prefill(
-            lm.params, jnp.asarray(ids), jnp.asarray([len(seq)]), CFG, 64
-        )
-        nxt = int(np.argmax(np.asarray(logits)[0]))
-        seq.append(nxt)
-    assert got == seq[len(prompt):]
+    got = generate_ids(lm, [prompt], max_new_tokens=5)[0]
+    assert got == reference_greedy(lm, prompt, 5)
 
 
 def test_generate_batch_ragged():
     lm = DecoderLM("pw-tiny-decoder", max_cache=64, eos_id=None)
-    outs = lm.generate_ids([[5, 9, 17, 4], [8]], max_new_tokens=4)
+    outs = generate_ids(lm, [[5, 9, 17, 4], [8]], max_new_tokens=4)
     assert len(outs) == 2 and all(len(o) == 4 for o in outs)
-    solo = lm.generate_ids([[8]], max_new_tokens=4)[0]
+    solo = generate_ids(lm, [[8]], max_new_tokens=4)[0]
     assert outs[1] == solo
 
 
 def test_eos_stops_row():
     lm = DecoderLM("pw-tiny-decoder", max_cache=64, eos_id=None)
-    forced = lm.generate_ids([[5, 9, 17]], max_new_tokens=3)[0]
+    forced = generate_ids(lm, [[5, 9, 17]], max_new_tokens=3)[0]
     eos = forced[1]
     lm2 = DecoderLM("pw-tiny-decoder", max_cache=64, eos_id=eos)
-    out = lm2.generate_ids([[5, 9, 17]], max_new_tokens=8)[0]
+    out = generate_ids(lm2, [[5, 9, 17]], max_new_tokens=8)[0]
     assert out == forced[: forced.index(eos)]
 
 
 def test_temperature_sampling_seeded():
     lm = DecoderLM("pw-tiny-decoder", max_cache=64, eos_id=None)
-    a = lm.generate_ids([[5, 9]], max_new_tokens=6, temperature=0.8, seed=1)
-    b = lm.generate_ids([[5, 9]], max_new_tokens=6, temperature=0.8, seed=1)
-    c = lm.generate_ids([[5, 9]], max_new_tokens=6, temperature=0.8, seed=2)
-    greedy = lm.generate_ids([[5, 9]], max_new_tokens=6)
+    a = generate_ids(lm, [[5, 9]], max_new_tokens=6, temperature=0.8, seed=1)
+    b = generate_ids(lm, [[5, 9]], max_new_tokens=6, temperature=0.8, seed=1)
+    c = generate_ids(lm, [[5, 9]], max_new_tokens=6, temperature=0.8, seed=2)
+    greedy = generate_ids(lm, [[5, 9]], max_new_tokens=6)
     assert a == b
     # sampling at T=0.8 over 512 random logits matching greedy argmax on
     # all 6 tokens for BOTH seeds has negligible probability
@@ -147,22 +123,22 @@ def test_temperature_sampling_seeded():
 
 
 def test_long_prompt_keeps_tail_and_runs():
-    """Prompts past the 512 shared bucket cap and past the cache budget
-    work: the tail is kept and prefill buckets up to the cache size."""
+    """Prompts past the cache budget work: the tail is kept and prefills
+    in chunks."""
     lm = DecoderLM("pw-tiny-decoder", max_cache=128, eos_id=None)
     rng = np.random.default_rng(7)
     long_prompt = rng.integers(1, CFG.vocab_size, size=600).tolist()
-    out = lm.generate_ids([long_prompt], max_new_tokens=4)[0]
+    out = generate_ids(lm, [long_prompt], max_new_tokens=4)[0]
     assert len(out) == 4
     # equivalent to generating from the kept tail directly
     tail = long_prompt[-(128 - 4):]
-    assert out == lm.generate_ids([tail], max_new_tokens=4)[0]
+    assert out == generate_ids(lm, [tail], max_new_tokens=4)[0]
 
 
 def test_max_new_tokens_budget_validated():
     lm = DecoderLM("pw-tiny-decoder", max_cache=32, eos_id=None)
     with pytest.raises(ValueError, match="max_new_tokens"):
-        lm.generate_ids([[1, 2, 3]], max_new_tokens=32)
+        generate_ids(lm, [[1, 2, 3]], max_new_tokens=32)
 
 
 def test_unknown_model_name_raises():
@@ -170,80 +146,49 @@ def test_unknown_model_name_raises():
         decoder_config_for("mistral-7b")  # typo'd preset name
 
 
-def test_jax_chat_microbatches_concurrent_rows(monkeypatch):
-    """Concurrent rows of one epoch run as a single generate_many batch.
-
-    Pins the STATIC fallback path (the one top_k / repetition_penalty
-    configs take) — the default continuous route is pinned below.
-    """
-    import asyncio
-
-    from pathway_tpu.xpacks.llm import llms
-
-    monkeypatch.setenv("PATHWAY_GENERATE_CONTINUOUS", "0")
-    chat = llms.JaxChat(model="pw-tiny-decoder", max_new_tokens=3, max_cache=64)
-    batch_sizes = []
-    lm = DecoderLM("pw-tiny-decoder", max_cache=64, eos_id=None)
-    real = lm.generate_many
-
-    def spy(prompts, **kw):
-        batch_sizes.append(len(prompts))
-        return real(prompts, **kw)
-
-    lm.generate_many = spy
-    chat._model = lm
-
-    async def run():
-        return await asyncio.gather(
-            *(chat.__wrapped__(f"question {i}") for i in range(5))
-        )
-
-    answers = asyncio.run(run())
-    assert len(answers) == 5 and all(isinstance(a, str) for a in answers)
-    assert max(batch_sizes) > 1  # rows actually coalesced
-    assert sum(batch_sizes) == 5
-
-
-def test_jax_chat_routes_through_continuous_scheduler(monkeypatch):
-    """Default config serves chat through the shared continuous scheduler;
-    the static per-config batcher is never touched."""
+@pytest.mark.parametrize("sampling", [
+    {}, {"temperature": 0.8, "top_k": 5, "repetition_penalty": 1.3},
+], ids=["plain", "top_k_and_penalty"])
+def test_jax_chat_routes_through_continuous_scheduler(monkeypatch, sampling):
+    """Every chat row, whatever its sampling, is submitted to the shared
+    continuous scheduler of its model, options passed through."""
     import asyncio
 
     from pathway_tpu.serving import generation
     from pathway_tpu.xpacks.llm import llms
 
     chat = llms.JaxChat(model="pw-tiny-decoder", max_new_tokens=3, max_cache=64)
-    static_calls = []
-    lm = DecoderLM("pw-tiny-decoder", max_cache=64, eos_id=None)
-    lm.generate_many = lambda *a, **kw: static_calls.append(a) or []
-    chat._model = lm
+    submitted = []
+    real_submit = generation.GenerationScheduler.submit
 
-    sched_calls = []
-    real_shared = generation.shared_scheduler
+    def spy_submit(self, prompt, **kw):
+        submitted.append((self, kw))
+        return real_submit(self, prompt, **kw)
 
-    def spy_shared(*a, **kw):
-        sched_calls.append(a)
-        return real_shared(*a, **kw)
-
-    monkeypatch.setattr(generation, "shared_scheduler", spy_shared)
+    monkeypatch.setattr(generation.GenerationScheduler, "submit", spy_submit)
 
     async def run():
         return await asyncio.gather(
-            *(chat.__wrapped__(f"question {i}") for i in range(3))
+            *(chat.__wrapped__(f"question {i}", **sampling) for i in range(3))
         )
 
     try:
         answers = asyncio.run(run())
+        shared = generation.shared_scheduler("pw-tiny-decoder", max_cache=64)
     finally:
         generation.reset_shared_schedulers()
     assert len(answers) == 3 and all(isinstance(a, str) for a in answers)
-    assert len(sched_calls) == 3
-    assert not static_calls  # static batcher bypassed entirely
+    assert [s for s, _kw in submitted] == [shared] * 3
+    for _s, kw in submitted:
+        assert kw["max_new_tokens"] == 3
+        assert kw["top_k"] == sampling.get("top_k")
+        assert kw["repetition_penalty"] == sampling.get("repetition_penalty")
+        assert kw["temperature"] == sampling.get("temperature", 0.0)
 
 
 def test_tensor_parallel_decode_matches_single_device():
-    """Params/cache sharded over an 8-way model axis produce the same
-    logits; XLA inserts the all-reduces from the shardings alone."""
+    """Params sharded over a model axis produce the same logits; XLA
+    inserts the all-reduces from the shardings alone."""
     from jax.sharding import Mesh, NamedSharding
 
     mesh = Mesh(np.array(jax.devices()[:8]).reshape(8), ("model",))
@@ -255,19 +200,11 @@ def test_tensor_parallel_decode_matches_single_device():
         place, TREE, specs, is_leaf=lambda x: isinstance(x, jnp.ndarray)
     )
     rng = np.random.default_rng(4)
-    ids = rng.integers(1, CFG.vocab_size, size=(1, 8)).astype(np.int32)
-    lens = jnp.asarray([8], jnp.int32)
-    ref_logits, ref_kc, ref_vc = prefill(TREE, jnp.asarray(ids), lens, CFG, 16)
-    logits, kc, vc = prefill(tree_sh, jnp.asarray(ids), lens, CFG, 16)
+    ids = jnp.asarray(rng.integers(1, CFG.vocab_size, size=(1, 9)).astype(np.int32))
+    lens = jnp.asarray([9], jnp.int32)
+    ref_logits = causal_lm_logits(TREE, ids, lens, CFG)
+    logits = causal_lm_logits(tree_sh, ids, lens, CFG)
     np.testing.assert_allclose(np.asarray(logits), np.asarray(ref_logits), atol=1e-5)
-
-    kc = jax.device_put(kc, NamedSharding(mesh2, tp_cache_specs()))
-    vc = jax.device_put(vc, NamedSharding(mesh2, tp_cache_specs()))
-    tok = jnp.asarray([7], jnp.int32)
-    pos = jnp.asarray([8], jnp.int32)
-    step_ref, _, _ = decode_step(TREE, ref_kc, ref_vc, tok, pos, CFG)
-    step_tp, _, _ = decode_step(tree_sh, kc, vc, tok, pos, CFG)
-    np.testing.assert_allclose(np.asarray(step_tp), np.asarray(step_ref), atol=1e-5)
     assert mesh.size == 8  # the 8-device mesh exists; 2 used for 4 heads
 
 
@@ -367,16 +304,29 @@ def test_causal_lm_loss_masks_padding():
 
 def test_generation_batch_invariance():
     """A row's greedy chain must not depend on what it is co-batched
-    with (padding rows are fully masked; the prefill bucket only changes
-    shapes, not math)."""
-    from pathway_tpu.models.decoder import DecoderLM
-
+    with (other slots are fully masked; the prefill program's shape
+    changes shapes, not math)."""
     lm = DecoderLM("pw-tiny-decoder", max_cache=64, eos_id=None)
-    solo = lm.generate_ids([[5, 9, 3]], max_new_tokens=10)
-    batched = lm.generate_ids(
-        [[5, 9, 3], [7, 11, 2, 8, 1], [4]], max_new_tokens=10
+    solo = generate_ids(lm, [[5, 9, 3]], max_new_tokens=10)
+    batched = generate_ids(
+        lm, [[5, 9, 3], [7, 11, 2, 8, 1], [4]], max_new_tokens=10
     )
     assert batched[0] == solo[0]
     # and independent of row order
-    shuffled = lm.generate_ids([[4], [5, 9, 3]], max_new_tokens=10)
+    shuffled = generate_ids(lm, [[4], [5, 9, 3]], max_new_tokens=10)
     assert shuffled[1] == solo[0]
+
+
+def test_models_import_nothing_of_serving():
+    """The scheduler drives the model, never the other way round: whoever
+    wants text calls ``GenerationScheduler(lm).generate`` or
+    ``shared_scheduler``."""
+    import pathlib
+    import re
+
+    import pathway_tpu.models as models
+
+    for path in pathlib.Path(models.__file__).parent.glob("*.py"):
+        assert not re.search(
+            r"^\s*(from|import)\s+pathway_tpu\.serving", path.read_text(), flags=re.M
+        ), path.name

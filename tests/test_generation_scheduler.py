@@ -5,7 +5,8 @@ Two kinds of test live here.  White-box tests drive ``_tick()`` by hand
 chunked-prefill fairness are deterministic — no sleeps, no timing
 assumptions.  End-to-end tests go through ``submit_ids`` and the worker
 thread and pin the output contract: greedy continuous batching must emit
-EXACTLY what the static batched path emits for the same prompts.
+EXACTLY the argmax chain of the full causal forward, which keeps no cache
+(``tests/decoder_oracle.py``), for the same prompts.
 """
 
 from __future__ import annotations
@@ -21,6 +22,7 @@ from pathway_tpu.engine import metrics as em  # noqa: E402
 from pathway_tpu.engine import serving as edge  # noqa: E402
 from pathway_tpu.models.decoder import PageExhaustedError, shared_decoder  # noqa: E402
 from pathway_tpu.serving import generation  # noqa: E402
+from tests.decoder_oracle import generate_ids, reference_greedy  # noqa: E402
 
 MODEL = "pw-tiny-decoder"
 MAX_CACHE = 64
@@ -72,13 +74,13 @@ def _enqueue(sched, req):
 def test_greedy_matches_static_batching():
     """THE determinism pin: continuous batching with churn (slots=2,
     5 requests of mixed length forcing queue + slot reuse) emits exactly
-    the static ``generate_ids`` greedy tokens for every prompt."""
+    the full forward's greedy tokens for every prompt."""
     lm = _lm()
     rng = np.random.default_rng(7)
     prompts = [_prompt(rng, n) for n in (3, 11, 1, 7, 20)]
     news = [6, 4, 8, 5, 3]
     ref = [
-        lm.generate_ids([p], max_new_tokens=mn)[0]
+        reference_greedy(lm, p, mn)
         for p, mn in zip(prompts, news)
     ]
     sched = generation.GenerationScheduler(
@@ -118,7 +120,7 @@ def test_pool_exhaustion_queues_instead_of_oom():
         futs = [sched.submit_ids(p, max_new_tokens=8) for p in prompts]
         got = [f.result(timeout=120) for f in futs]
         for p, out in zip(prompts, got):
-            assert out == lm.generate_ids([p], max_new_tokens=8)[0]
+            assert out == reference_greedy(lm, p, 8)
         assert sched.allocator.peak_pages <= 3
     finally:
         sched.shutdown()
@@ -266,12 +268,8 @@ def test_chunked_prefill_does_not_stall_short_prompts():
     assert short.first_token_at is not None
     assert long.first_token_at is None
     _drive(sched)
-    assert short.future.result(timeout=5) == lm.generate_ids(
-        [short.prompt_ids], max_new_tokens=4
-    )[0]
-    assert long.future.result(timeout=5) == lm.generate_ids(
-        [long.prompt_ids], max_new_tokens=4
-    )[0]
+    assert short.future.result(timeout=5) == reference_greedy(lm, short.prompt_ids, 4)
+    assert long.future.result(timeout=5) == reference_greedy(lm, long.prompt_ids, 4)
     sched.shutdown()
 
 
@@ -308,9 +306,7 @@ def test_request_churn_fault_no_head_of_line_blocking():
         sched._tick()
         if len(sched._churn_ttfts) >= 3 and not long.future.done():
             burst_served_while_long_ran = True
-    assert long.future.result(timeout=5) == lm.generate_ids(
-        [[3, 1, 4]], max_new_tokens=40
-    )[0]
+    assert long.future.result(timeout=5) == reference_greedy(lm, [3, 1, 4], 40)
     assert burst_served_while_long_ran, (
         "synthetic burst should reach first tokens before the long "
         "generation finishes"
@@ -352,11 +348,35 @@ def test_shared_scheduler_is_per_model_singleton():
         generation.reset_shared_schedulers()
 
 
-def test_continuous_enabled_env_gate(monkeypatch):
-    monkeypatch.delenv("PATHWAY_GENERATE_CONTINUOUS", raising=False)
-    assert generation.continuous_enabled()  # on by default
-    monkeypatch.setenv("PATHWAY_GENERATE_CONTINUOUS", "0")
-    assert not generation.continuous_enabled()
+def _sliding_window_lm():
+    import dataclasses
+
+    from pathway_tpu.models import decoder as dec
+
+    lm = dec.DecoderLM(MODEL, max_cache=MAX_CACHE)
+    # shorter than the sequences below, longer than a page
+    lm.config = dataclasses.replace(lm.config, sliding_window=12)
+    return lm
+
+
+@pytest.mark.parametrize("build", [
+    lambda: shared_decoder("pw-tiny-decoder", max_cache=MAX_CACHE),
+    lambda: shared_decoder("pw-tiny-moe-decoder", max_cache=MAX_CACHE),
+    _sliding_window_lm,
+    lambda: shared_decoder("pw-tiny-hybrid-decoder", max_cache=MAX_CACHE),
+], ids=["dense", "moe", "sliding_window", "hybrid"])
+def test_scheduler_greedy_is_the_full_forwards_argmax(build):
+    """Every kind of model the scheduler serves, two ragged rows side by
+    side through chunked prefill and paged decode: the answers are the
+    cache-free oracle's."""
+    lm = build()
+    rng = np.random.default_rng(17)
+    prompts = [_prompt(rng, 19), _prompt(rng, 5)]
+    got = generate_ids(
+        lm, prompts, max_new_tokens=14,
+        scheduler=dict(slots=2, page_size=8, prefill_chunk=8),
+    )
+    assert got == [reference_greedy(lm, p, 14) for p in prompts]
 
 
 def test_generation_snapshot_rides_flight_recorder(tmp_path):
@@ -482,14 +502,14 @@ def test_run_ahead_gives_the_parents_tokens(rows, sampling):
     assert got[0] == got[1] and [len(out) for out in got[0]] == list(news)
     if not sampling:
         assert got[0] == [
-            lm.generate_ids([p], max_new_tokens=n)[0] for p, n in zip(prompts, news)
+            reference_greedy(lm, p, n) for p, n in zip(prompts, news)
         ]
 
 
 def test_slot_reuse_under_run_ahead_matches_static_batching():
     """Greedy rows do not see each other: with two slots and five requests
     (a slot is taken again a tick later than before) every answer is the
-    static path's."""
+    full forward's."""
     lm = _lm()
     rng = np.random.default_rng(31)
     prompts = [_prompt(rng, n) for n in (3, 11, 1, 7, 20)]
@@ -502,7 +522,7 @@ def test_slot_reuse_under_run_ahead_matches_static_batching():
         _enqueue(sched, req)
     _drive(sched)
     assert [r.future.result(timeout=5) for r in reqs] == [
-        lm.generate_ids([p], max_new_tokens=n)[0] for p, n in zip(prompts, news)
+        reference_greedy(lm, p, n) for p, n in zip(prompts, news)
     ]
     assert sched.allocator.used_pages == 0 and sched.allocator.reserved == 0
     sched.shutdown()
@@ -514,7 +534,7 @@ def test_eos_read_a_step_late_drops_the_step_too_many():
     the order before gave, and the waste is counted."""
     lm = _lm()
     prompt = _prompt(np.random.default_rng(32), 6)
-    free_run = lm.generate_ids([prompt], max_new_tokens=12)[0]
+    free_run = reference_greedy(lm, prompt, 12)
     j = _first_repeat_free(free_run, start=3)
     lm.eos_id = free_run[j]
     outs = []
@@ -668,7 +688,7 @@ def test_raising_tick_with_a_step_in_flight_fails_requests_not_the_thread():
         assert _no_interval_left_open(sched)[-1]["attributes"].get("failed") is True
         assert sched.snapshot()["tick_failures"] == 1
         again = sched.submit_ids([3, 1, 4], max_new_tokens=5).result(timeout=120)
-        assert again == lm.generate_ids([[3, 1, 4]], max_new_tokens=5)[0]
+        assert again == reference_greedy(lm, [3, 1, 4], 5)
     finally:
         sched.shutdown()
     assert sched._step is None and sched._inflight is None

@@ -309,16 +309,45 @@ def test_mistral_programs_lower_as_before():
     assert sched._k_pool.shape == (2, 32, 8, 2, 16) and not sched.ring_pages
 
 
-@pytest.mark.parametrize("call", [
-    lambda lm: lm.generate_ids([[5, 6, 7]], max_new_tokens=2),
-    lambda lm: lm.generate_ids_speculative([[5, 6, 7]], max_new_tokens=2),
-    lambda lm: dec.quantize_decoder_tree(lm.params),
-    lambda lm: dec.decode_step(lm.params, None, None, jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32), CFG),
-    lambda lm: dec.verify_block(lm.params, None, None, jnp.zeros((1, 2), jnp.int32), jnp.zeros((1,), jnp.int32), CFG),
-], ids=["generate_ids", "speculative", "quantize", "decode_step", "verify_block"])
-def test_static_path_raises_for_layer_kinds(lm, call):
+def test_quantize_raises_for_layer_kinds(lm):
     with pytest.raises(NotImplementedError, match="scheduler's path"):
-        call(lm)
+        dec.quantize_decoder_tree(lm.params)
+
+
+def test_penalised_and_top_k_rows_beside_a_plain_row(lm):
+    """The history-carrying step of a model that counts its routing (the
+    ``active`` mask and the carried counts ride behind ``seen``): the plain
+    row is the full forward's argmax chain, the others what they get
+    alone, and the routing is counted as by the plain step."""
+    from pathway_tpu.engine.metrics import get_registry
+    from tests.decoder_oracle import generate_ids, reference_greedy
+
+    rng = np.random.default_rng(6)
+    rows = [
+        (_prompt(rng, 30), dict(repetition_penalty=1.6)),
+        (_prompt(rng, 9), {}),
+        (_prompt(rng, 12), dict(temperature=0.8, top_k=1)),
+    ]
+    new = 12
+    sched = _scheduler(lm)
+    pairs = "generate.moe.decode.pairs"
+    before = get_registry().scalar_metrics().get(pairs, 0.0)
+    try:
+        with sched._lock:
+            futures = [sched.submit_ids(p, max_new_tokens=new, **kw) for p, kw in rows]
+        outs = [f.result(timeout=300) for f in futures]
+        assert sched._decode_fn._cache_size() == 0
+        assert sched._decode_history_fn._cache_size() >= 1
+    finally:
+        sched.shutdown()
+    assert get_registry().scalar_metrics()[pairs] > before
+    assert outs[1] == reference_greedy(lm, rows[1][0], new)
+    assert outs[2] == reference_greedy(lm, rows[2][0], new)  # k = 1
+    small = dict(slots=SLOTS, page_size=PAGE, prefill_chunk=64)
+    assert [outs[0]] == generate_ids(
+        lm, [rows[0][0]], max_new_tokens=new, scheduler=small, **rows[0][1]
+    )
+    assert outs[0] != reference_greedy(lm, rows[0][0], new)
 
 
 def test_unknown_model_type_raises(tmp_path):

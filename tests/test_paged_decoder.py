@@ -1,11 +1,12 @@
-"""Paged KV cache vs the dense decoder path (ISSUE 18 tentpole pins).
+"""Paged KV cache vs the full causal forward (ISSUE 18 tentpole pins).
 
-The paged path must be the dense path rearranged through a block table:
-same math, same mask semantics, memory that scales with live tokens.
-These tests pin (a) the page allocator's reservation/accounting contract,
-(b) scatter/gather correctness including null-page routing for
+The paged path must be the full forward's attention rearranged through a
+block table: same math, same mask semantics, memory that scales with live
+tokens.  These tests pin (a) the page allocator's reservation/accounting
+contract, (b) scatter/gather correctness including null-page routing for
 out-of-table positions, and (c) logits equivalence of paged prefill +
-decode against ``prefill``/``decode_step`` on ragged batches.
+decode against ``causal_lm_logits``, which keeps no cache, on ragged
+batches.
 """
 
 from __future__ import annotations
@@ -148,8 +149,16 @@ def test_null_block_table_entries_gather_null_page():
 
 
 # ---------------------------------------------------------------------------
-# paged vs dense equivalence
+# paged vs full-forward equivalence
 # ---------------------------------------------------------------------------
+
+
+def _last_logits(tree, ids, lens):
+    """The full forward's logits at each row's last real token."""
+    logits = dec.causal_lm_logits(
+        tree, jnp.asarray(ids), jnp.asarray(lens, jnp.int32), CFG, serving=True
+    )
+    return np.asarray(logits)[np.arange(len(lens)), np.asarray(lens) - 1]
 
 
 def _alloc_tables(lens, max_tokens, page, num_pages):
@@ -167,9 +176,9 @@ def _alloc_tables(lens, max_tokens, page, num_pages):
 
 @pytest.mark.parametrize("chunk", [64, 5])
 def test_paged_prefill_matches_dense(chunk):
-    """Full-prompt and chunked paged prefill must match dense ``prefill``
-    logits on a ragged batch (chunked prefill is full prefill split along
-    the query axis)."""
+    """Full-prompt and chunked paged prefill must match the full
+    forward's logits at each row's last token on a ragged batch (chunked
+    prefill is full prefill split along the query axis)."""
     tree = dec.init_decoder_params(CFG, seed=3)
     lens = [7, 12, 1]
     S = len(lens)
@@ -178,9 +187,7 @@ def test_paged_prefill_matches_dense(chunk):
     for s, n in enumerate(lens):
         ids[s, :n] = rng.integers(1, CFG.vocab_size, n)
 
-    dense_logits, _, _ = dec.prefill(
-        tree, jnp.asarray(ids), jnp.asarray(lens), CFG, 32
-    )
+    dense_logits = _last_logits(tree, ids, lens)
 
     page = 4
     num_pages = 16
@@ -218,38 +225,35 @@ def test_paged_prefill_matches_dense(chunk):
 
 
 def test_paged_decode_matches_dense_greedy():
-    """Greedy continuation after prefill: the paged decode step and the
-    dense decode step must pick identical tokens for many steps."""
+    """Greedy continuation after prefill on a ragged batch: at every one
+    of many steps the paged decode step's logits are the full forward's
+    over the sequence so far, and it picks the same token."""
     tree = dec.init_decoder_params(CFG, seed=5)
     lens = [5, 9]
     S = len(lens)
     rng = np.random.default_rng(2)
-    ids = np.zeros((S, max(lens)), np.int32)
-    for s, n in enumerate(lens):
-        ids[s, :n] = rng.integers(1, CFG.vocab_size, n)
-
     cache_len = 32
-    d_logits, kc, vc = dec.prefill(
-        tree, jnp.asarray(ids), jnp.asarray(lens), CFG, cache_len
-    )
+    seqs = np.zeros((S, cache_len), np.int32)
+    for s, n in enumerate(lens):
+        seqs[s, :n] = rng.integers(1, CFG.vocab_size, n)
 
     page = 4
     k_pool, v_pool = dec.init_kv_pool(CFG, 24, page)
     bt = _alloc_tables([cache_len] * S, cache_len, page, 24)
     p_logits, k_pool, v_pool = dec.paged_prefill_chunk(
-        tree, k_pool, v_pool, bt, jnp.asarray(ids),
+        tree, k_pool, v_pool, bt, jnp.asarray(seqs[:, :max(lens)]),
         jnp.asarray(lens), jnp.zeros(S, jnp.int32), CFG,
     )
 
     pos = np.asarray(lens, np.int64)
     for step in range(10):
-        d_tok = np.asarray(jnp.argmax(d_logits, axis=-1))
-        p_tok = np.asarray(jnp.argmax(p_logits, axis=-1))
-        np.testing.assert_array_equal(p_tok, d_tok, err_msg=f"step {step}")
-        d_logits, kc, vc = dec.decode_step(
-            tree, kc, vc, jnp.asarray(d_tok, jnp.int32),
-            jnp.asarray(pos, jnp.int32), CFG,
+        d_logits = _last_logits(tree, seqs, pos)
+        np.testing.assert_allclose(
+            np.asarray(p_logits), d_logits, rtol=2e-4, atol=2e-4, err_msg=f"step {step}"
         )
+        p_tok = np.asarray(jnp.argmax(p_logits, axis=-1))
+        np.testing.assert_array_equal(p_tok, d_logits.argmax(-1), err_msg=f"step {step}")
+        seqs[np.arange(S), pos] = p_tok
         p_logits, k_pool, v_pool = dec.paged_decode_step(
             tree, k_pool, v_pool, bt, jnp.asarray(pos, jnp.int32),
             jnp.asarray(p_tok, jnp.int32), CFG,

@@ -25,6 +25,7 @@ from pathway_tpu.engine.profiler import install_jax_accounting  # noqa: E402
 from pathway_tpu.models import decoder as dec  # noqa: E402
 from pathway_tpu.serving import generation  # noqa: E402
 from pathway_tpu.serving.generation import prefill_ladder, prefill_shape  # noqa: E402
+from tests.decoder_oracle import reference_greedy  # noqa: E402
 
 MODEL = "pw-tiny-decoder-long"
 MAX_CACHE = 256
@@ -340,9 +341,9 @@ def test_one_row_prefill_leaves_a_decoding_slots_pages_alone(lm, ladder_sched):
     np.testing.assert_array_equal(np.asarray(sched._logits[row]), logits_before)
     assert sched._slots[row].seq_len == seq_len
     _drive(sched)
-    assert decoding.future.result(timeout=5) == lm.generate_ids(
-        [decoding.prompt_ids], max_new_tokens=40
-    )[0]
-    assert newcomer.future.result(timeout=5) == lm.generate_ids(
-        [newcomer.prompt_ids], max_new_tokens=4
-    )[0]
+    assert decoding.future.result(timeout=5) == reference_greedy(
+        lm, decoding.prompt_ids, 40
+    )
+    assert newcomer.future.result(timeout=5) == reference_greedy(
+        lm, newcomer.prompt_ids, 4
+    )

@@ -1,9 +1,9 @@
 """Sliding-window attention (DecoderConfig.sliding_window, Mistral v0.1).
 
 Pinned: window ≥ sequence degenerates to full causal attention, a tight
-window actually changes (and localizes) attention, prefill↔decode cache
-consistency holds under the window, and the pipelined trunk applies the
-same mask.
+window actually changes (and localizes) attention, the paged programs'
+cache holds the full forward's logits under the window, and the pipelined
+trunk applies the same mask.
 """
 
 import dataclasses
@@ -14,10 +14,9 @@ import numpy as np
 from pathway_tpu.models.decoder import (
     DecoderConfig,
     causal_lm_logits,
-    decode_step,
     init_decoder_params,
-    prefill,
 )
+from tests.decoder_oracle import paged_logits
 
 BASE = DecoderConfig(
     vocab_size=128, hidden=32, layers=2, heads=4, kv_heads=2,
@@ -66,16 +65,12 @@ def test_swa_prefill_decode_consistency():
     rng = np.random.default_rng(3)
     B, S = 2, 12
     full = rng.integers(1, cfg.vocab_size, size=(B, S + 1)).astype(np.int32)
-    want, _, _ = prefill(
-        tree, jnp.asarray(full), jnp.full((B,), S + 1, jnp.int32), cfg, 16
+    want = causal_lm_logits(
+        tree, jnp.asarray(full), jnp.full((B,), S + 1, jnp.int32), cfg, serving=True
     )
-    _, kc, vc = prefill(
-        tree, jnp.asarray(full[:, :S]), jnp.full((B,), S, jnp.int32), cfg, 16
-    )
-    got, _, _ = decode_step(
-        tree, kc, vc, jnp.asarray(full[:, S]), jnp.full((B,), S, jnp.int32), cfg
-    )
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
+    # a prefix longer than the window prefilled, the rest decoded
+    got = paged_logits(tree, cfg, full, 7)
+    np.testing.assert_allclose(got, np.asarray(want)[:, 6:], rtol=2e-4, atol=2e-4)
 
 
 def test_swa_pipelined_trunk_matches():

@@ -150,8 +150,15 @@ def test_long_prompt_tail_reaches_decoder():
     long_prompt = " ".join(f"word{i}" for i in range(300))
     ids_full = lm._encode_prompt(long_prompt)
     assert len(ids_full) > 64  # tokenized at the model limit, not cache
-    out = lm.generate(long_prompt, max_new_tokens=4)
+    from pathway_tpu.serving.generation import GenerationScheduler
+    from tests.decoder_oracle import generate_ids
+
+    sched = GenerationScheduler(lm)
+    try:
+        out = sched.generate(long_prompt, max_new_tokens=4)
+    finally:
+        sched.shutdown()
     # equals generating from the kept tail explicitly
     tail = ids_full[-(64 - 4):]
-    expect = lm.generate_ids([tail], max_new_tokens=4)[0]
+    expect = generate_ids(lm, [tail], max_new_tokens=4)[0]
     assert out == lm.tokenizer.decode(expect)
