@@ -622,20 +622,30 @@ def _qkv(lp, x, positions, cfg: DecoderConfig, kind: LayerKind):
     """Input norm, projections, rotary, value scale of one layer: ``q
     [..., NH, D]``, ``k [..., KH, D]``, ``v [..., KH, Dv]`` from the
     residual stream ``x [..., H]`` (a run of kinds holds the fused
-    ``wqkv``, a model of one kind ``wq`` / ``wk`` / ``wv``)."""
+    ``wqkv``, a model of one kind ``wq`` / ``wk`` / ``wv``).
+
+    The barrier keeps each product a plain ``[..., H] @ [H, N]``.  Without
+    it the TPU compiler folds the split into heads into the product (a
+    convolution over the heads) and wants the weight head-major: in a layer
+    scan that is the layer's slice of the stacked weight written out and
+    copied into the other layout every step, 2 of a Mistral-7B decode
+    step's 18.5 ms (PERF.md section 6, PR 32).  Behind it the slice fuses
+    into the product, as for ``wo`` and the MLP, and the split is a view of
+    the small output."""
     lead = x.shape[:-1]
     KH, D, Dv = kind.kv_heads, cfg.head_dim, cfg.v_dim
     h = _rms(x, lp["ln0"], cfg.norm_eps)
     if "wqkv" in lp:
         nq, nk = cfg.heads * D, KH * D
-        qkv = _mm(h, lp["wqkv"])
-        q = qkv[..., :nq].reshape(*lead, cfg.heads, D)
-        k = qkv[..., nq:nq + nk].reshape(*lead, KH, D)
-        v = qkv[..., nq + nk:].reshape(*lead, KH, Dv)
+        qkv = lax.optimization_barrier(_mm(h, lp["wqkv"]))
+        q, k, v = qkv[..., :nq], qkv[..., nq:nq + nk], qkv[..., nq + nk:]
     else:
-        q = _mm(h, lp["wq"]).reshape(*lead, cfg.heads, D)
-        k = _mm(h, lp["wk"]).reshape(*lead, KH, D)
-        v = _mm(h, lp["wv"]).reshape(*lead, KH, Dv)
+        q, k, v = lax.optimization_barrier(
+            (_mm(h, lp["wq"]), _mm(h, lp["wk"]), _mm(h, lp["wv"]))
+        )
+    q = q.reshape(*lead, cfg.heads, D)
+    k = k.reshape(*lead, KH, D)
+    v = v.reshape(*lead, KH, Dv)
     q = _rope_part(q, positions, kind.rope_theta, cfg.rotary_dim)
     k = _rope_part(k, positions, kind.rope_theta, cfg.rotary_dim)
     if cfg.value_scale != 1.0:
@@ -1004,6 +1014,11 @@ def _paged_trunk(tree, k_pool, v_pool, x, cfg: DecoderConfig, *, tables, rings,
     before this program (``starts [S]`` tokens) and to the program's own
     rows, by position, then writes the last of its ``lens [S]`` rows that
     the ring keeps.  ``valid [S, T]`` marks the rows that hold a token.
+    A run's pools are the scan's carry, not its ``xs`` and ``ys``: a layer
+    scatters into and gathers from the stack at its index, so no layer's
+    pool is sliced out of the stack or written back into one, and with the
+    pools donated (``serving/generation.py``) the caller's buffers are
+    updated in place.
     Returns ``(x, k_pool, v_pool, stats)``; ``stats`` is the routed
     layers' summed ``[pairs, experts_hit]`` (noughts without routed layers).
     """
@@ -1022,52 +1037,59 @@ def _paged_trunk(tree, k_pool, v_pool, x, cfg: DecoderConfig, *, tables, rings,
             )
             ring_at = attention_ops.ring_write_positions(positions, valid, lens, cap)
 
-        def layer(x, lp):
-            lp, kp, vp, *index = lp
+        def layer(carry, lp):
+            x, kp, vp = carry
+            lp, index = lp
             if experts:
-                lp = {**lp, **experts, "moe_layer": index[0]}
+                lp = {**lp, **experts, "moe_layer": index}
             with jax.named_scope("attn.qkv"):
                 q, k, v = _qkv(lp, x, positions, cfg, kind)
             if ring:
                 with jax.named_scope(scope):
                     ctx = attention_ops.ring_gqa_attention(
-                        q, k, v, kp, vp, rings, ring_sees, lp.get("sink")
+                        q, k, v, kp, vp, rings, ring_sees, lp.get("sink"), index
                     )
+                # read, then write: the rows that enter the ring replace
+                # entries the attention above still reads
                 with jax.named_scope("kv.write"):
-                    kp = attention_ops.scatter_kv_pages(kp, rings, ring_at, k)
-                    vp = attention_ops.scatter_kv_pages(vp, rings, ring_at, v)
+                    kp = attention_ops.scatter_kv_pages(kp, rings, ring_at, k, index)
+                    vp = attention_ops.scatter_kv_pages(vp, rings, ring_at, v, index)
             else:
                 with jax.named_scope("kv.write"):
-                    kp = attention_ops.scatter_kv_pages(kp, tables, write_positions, k)
-                    vp = attention_ops.scatter_kv_pages(vp, tables, write_positions, v)
+                    kp = attention_ops.scatter_kv_pages(
+                        kp, tables, write_positions, k, index
+                    )
+                    vp = attention_ops.scatter_kv_pages(
+                        vp, tables, write_positions, v, index
+                    )
                 with jax.named_scope(scope):
                     ctx = attention_ops.paged_gqa_attention(
-                        q, kp, vp, tables, mask, lp.get("sink")
+                        q, kp, vp, tables, mask, lp.get("sink"), index
                     )
             with jax.named_scope("attn.out"):
                 x = x + _mm(ctx, lp["wo"])
             h = _rms(x, lp["ln1"], cfg.norm_eps)
             mlp, stats = _ffn(lp, h, cfg, kind, serving=True, valid=valid)
-            if kind.routed:
-                return x + mlp, (kp, vp, stats)
-            return x + mlp, (kp, vp)
+            return (x + mlp, kp, vp), (stats if kind.routed else None)
 
         return layer
 
     k_out, v_out, stats = [], [], jnp.zeros((2,), jnp.int32)
     for kind, layers, kp, vp in run_stacks(cfg, tree["layers"], k_pool, v_pool):
-        xs, experts = (layers, kp, vp), {}
+        experts = {}
         if kind.routed:
             # the run's expert stacks stay whole and the body is told its
             # layer's index in them (``moe_serve`` says why)
             experts = {name: layers[name] for name in ("wg", "wu", "wd")}
-            rest = {k: v for k, v in layers.items() if k not in experts}
-            xs = (rest, kp, vp, jnp.arange(kp.shape[0], dtype=jnp.int32))
-        x, ys = lax.scan(body(kind, experts), x, xs)
-        k_out.append(ys[0])
-        v_out.append(ys[1])
+            layers = {k: v for k, v in layers.items() if k not in experts}
+        index = jnp.arange(kp.shape[0], dtype=jnp.int32)
+        (x, kp, vp), routed = lax.scan(
+            body(kind, experts), (x, kp, vp), (layers, index)
+        )
+        k_out.append(kp)
+        v_out.append(vp)
         if kind.routed:
-            stats = stats + ys[2].sum(0)
+            stats = stats + routed.sum(0)
     if cfg.runs is None:
         return x, k_out[0], v_out[0], stats
     return x, tuple(k_out), tuple(v_out), stats

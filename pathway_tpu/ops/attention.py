@@ -159,27 +159,44 @@ def _xla_attention(q, k, v, mask_bias, heads: int):
 # instead of one dense [B, max_cache] block: cache memory scales with live
 # tokens, and a per-slot block table maps logical positions onto pool
 # pages (the Ragged Paged Attention layout — PAPERS.md).  The gather below
-# is the XLA expression of that kernel: every compiled shape is static
-# (slot count fixed, page count bucketed by the scheduler), so a churning
-# request mix replays one warm program per bucket — `jax.cache.miss == 0`
-# in steady state.  On TPU the same layout drops into a Pallas kernel that
-# walks the block table with async HBM→VMEM copies per page; the gather
-# keeps the math and shapes identical everywhere else.
+# is that layout in plain XLA, on the TPU as everywhere else (there is no
+# hand-written kernel): every compiled shape is static (slot count fixed,
+# page count bucketed by the scheduler), so a churning request mix replays
+# one warm program per bucket — `jax.cache.miss == 0` in steady state.  It
+# reads every page of every slot's table at the bucketed width, live or
+# null; the scatter writes the new rows alone.  Both take the pool of ALL
+# layers and the layer's index, so that a layer scan carries the pool
+# whole and a layer's pages are read and written where they lie.
 
 
-def gather_kv_pages(pool, block_tables):
+def _layer_pages(pool, layer):
+    """``pool`` as pages ``[pages, page, KH, D]`` and the page at which the
+    cache of ``layer`` starts in it.  ``layer`` None: ``pool`` is one
+    layer's ``[P, page, KH, D]``.  Otherwise it is the whole stack ``[L, P,
+    page, KH, D]``, flattened over layers and pages (a view, no copy), so
+    that a layer scan which carries the stack reads and writes a layer's
+    pages where they lie and never slices the layer's pool out of it."""
+    if layer is None:
+        return pool, 0
+    L, P = pool.shape[:2]
+    return pool.reshape((L * P,) + pool.shape[2:]), layer * P
+
+
+def gather_kv_pages(pool, block_tables, layer=None):
     """Gather a slot-major KV view out of the page pool.
 
-    ``pool`` is ``[P, page, KH, D]`` (one layer's pages), ``block_tables``
-    ``[S, G]`` int32 page indices (entry 0 = the reserved null page for
-    unallocated tail entries).  Returns ``[S, G*page, KH, D]`` — each
-    slot's logical cache, contiguous again.  Garbage gathered through
-    null-page entries sits at positions >= the slot's length and is
-    masked out by the caller.
+    ``pool`` is ``[P, page, KH, D]`` (one layer's pages), or the whole
+    stack ``[L, P, page, KH, D]`` with ``layer`` the index (it may be
+    traced) of the layer to read; ``block_tables`` ``[S, G]`` int32 page
+    indices (entry 0 = the reserved null page for unallocated tail
+    entries).  Returns ``[S, G*page, KH, D]`` — each slot's logical cache,
+    contiguous again.  Garbage gathered through null-page entries sits at
+    positions >= the slot's length and is masked out by the caller.
     """
     S, G = block_tables.shape
-    g = pool[block_tables]  # [S, G, page, KH, D]
-    return g.reshape(S, G * pool.shape[1], pool.shape[2], pool.shape[3])
+    pages, first = _layer_pages(pool, layer)
+    g = pages[first + block_tables]  # [S, G, page, KH, D]
+    return g.reshape(S, G * pages.shape[1], pages.shape[2], pages.shape[3])
 
 
 def gqa_attention(q, k, v, mask, sink=None):
@@ -210,14 +227,15 @@ def gqa_attention(q, k, v, mask, sink=None):
     return ctx.reshape(S, T, NH * v.shape[-1])
 
 
-def paged_gqa_attention(q, k_pool, v_pool, block_tables, mask, sink=None):
+def paged_gqa_attention(q, k_pool, v_pool, block_tables, mask, sink=None, layer=None):
     """GQA attention against paged KV: q ``[S, T, NH, D]``, pools
-    ``[P, page, KH, D]``, block_tables ``[S, G]``, mask ``[S, T, G*page]``
-    boolean (True = attend).  Same math as the dense decode path
-    (``models/decoder.py::_attend``) over the gathered context, so paged
-    and dense generations agree token-for-token."""
-    k = gather_kv_pages(k_pool, block_tables)  # [S, C, KH, D]
-    v = gather_kv_pages(v_pool, block_tables)
+    ``[P, page, KH, D]`` (or the whole stacks and ``layer``, as
+    :func:`gather_kv_pages` takes them), block_tables ``[S, G]``, mask
+    ``[S, T, G*page]`` boolean (True = attend).  Same math as the full
+    forward (``models/decoder.py::_attend``) over the gathered context, so
+    paged and dense generations agree token-for-token."""
+    k = gather_kv_pages(k_pool, block_tables, layer)  # [S, C, KH, D]
+    v = gather_kv_pages(v_pool, block_tables, layer)
     return gqa_attention(q, k, v, mask, sink)
 
 
@@ -249,15 +267,16 @@ def ring_mask(starts, positions, valid, window: int, cap: int):
     return (key_pos <= q_pos) & (key_pos > q_pos - window) & valid[:, :, None]
 
 
-def ring_gqa_attention(q, k_new, v_new, k_pool, v_pool, rings, mask, sink=None):
+def ring_gqa_attention(q, k_new, v_new, k_pool, v_pool, rings, mask, sink=None,
+                       layer=None):
     """Sliding-window attention of a program's rows ``q`` / ``k_new`` /
     ``v_new`` ``[S, T, ...]`` against each slot's ring (the pages ``rings
     [S, R]`` of this layer's pools, as they were before this program) and
     the rows themselves, under ``mask`` (:func:`ring_mask`).  The caller
     writes the rows the ring keeps afterwards (:func:`ring_write_positions`),
     so nothing a row still needs has been overwritten."""
-    k_old = gather_kv_pages(k_pool, rings)  # [S, cap, KH, D]
-    v_old = gather_kv_pages(v_pool, rings)
+    k_old = gather_kv_pages(k_pool, rings, layer)  # [S, cap, KH, D]
+    v_old = gather_kv_pages(v_pool, rings, layer)
     return gqa_attention(
         q, jnp.concatenate([k_old, k_new], axis=1),
         jnp.concatenate([v_old, v_new], axis=1), mask, sink,
@@ -275,17 +294,21 @@ def ring_write_positions(positions, valid, lens, cap: int):
     return jnp.where(keep, positions % cap, jnp.int32(2**30))
 
 
-def scatter_kv_pages(pool, block_tables, positions, values):
+def scatter_kv_pages(pool, block_tables, positions, values, layer=None):
     """Write per-slot K or V rows into the page pool.
 
-    ``pool`` ``[P, page, KH, D]``; ``positions`` ``[S, T]`` logical token
-    positions per slot (page = pos // page_size via the slot's block
-    table); ``values`` ``[S, T, KH, D]``.  Returns the updated pool.
+    ``pool`` ``[P, page, KH, D]``, or the whole stack ``[L, P, page, KH,
+    D]`` with ``layer`` the index of the layer written (one scatter into
+    the stack: in place where the caller's buffer may be reused);
+    ``positions`` ``[S, T]`` logical token positions per slot (page = pos
+    // page_size via the slot's block table); ``values`` ``[S, T, KH, D]``.
+    Returns the updated pool.
     Positions whose block-table entry is 0 land in the reserved null page
     — by construction those are only padding rows (inactive slots, tail
     of a ragged prefill chunk), so null-page collisions are harmless: the
     null page is never unmasked by any slot's attention."""
-    P, page = pool.shape[0], pool.shape[1]
+    pages, first = _layer_pages(pool, layer)
+    page = pages.shape[1]
     S, T = positions.shape
     G = block_tables.shape[1]
     slot_of = positions // page  # [S, T] block-table column per write
@@ -295,13 +318,13 @@ def scatter_kv_pages(pool, block_tables, positions, values):
     # positions past the table's width (ragged padding rows) must land in
     # the null page, NOT clip into the slot's last live page
     page_idx = jnp.where(slot_of >= G, 0, page_idx)
-    flat = page_idx * page + positions % page  # [S, T] rows into [P*page]
-    pool_flat = pool.reshape(P * page, pool.shape[2], pool.shape[3])
-    pool_flat = pool_flat.at[flat.reshape(-1)].set(
+    flat = (first + page_idx) * page + positions % page  # [S, T] token rows
+    rows = pages.reshape((pages.shape[0] * page,) + pages.shape[2:])
+    rows = rows.at[flat.reshape(-1)].set(
         values.reshape(S * T, values.shape[2], values.shape[3]),
         mode="drop",
     )
-    return pool_flat.reshape(pool.shape)
+    return rows.reshape(pool.shape)
 
 
 @functools.partial(

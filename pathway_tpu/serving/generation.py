@@ -281,9 +281,7 @@ class GenerationScheduler:
             self.num_pages, self.page_size,
             dec.kv_bytes_per_token(self.cfg, growing_only=True),
         )
-        self._k_pool, self._v_pool = dec.init_kv_pool(
-            self.cfg, self.num_pages, self.page_size, self.slots
-        )
+        self._init_pools()
         self._hybrid = self.cfg.runs is not None
         windows = {
             k.window for k, _n in self.cfg.layer_runs if dec.uses_ring(self.cfg, k)
@@ -387,10 +385,14 @@ class GenerationScheduler:
                 out += (carried[0] + stats[0],)
             return out
 
-        self._decode_fn = jax.jit(_decode)
-        self._decode_history_fn = jax.jit(_decode_history)
+        # a program consumes the pools it is given and hands back their
+        # buffers, updated in place: the scheduler holds the one copy, and
+        # chains it from each program to the next
+        pools = (1, 2)
+        self._decode_fn = jax.jit(_decode, donate_argnums=pools)
+        self._decode_history_fn = jax.jit(_decode_history, donate_argnums=pools)
         self._seed_seen_fn = jax.jit(lambda seen, i, row: seen.at[i].set(row))
-        self._prefill_fn = jax.jit(_prefill)
+        self._prefill_fn = jax.jit(_prefill, donate_argnums=pools)
 
         self._lock = threading.Condition()
         self._queue: list[GenRequest] = []
@@ -648,12 +650,24 @@ class GenerationScheduler:
         self._fail_all(RequestFailedError("generation scheduler shut down"))
         _blackbox.get_recorder().set_generation_supplier(None)
 
+    def _init_pools(self) -> None:
+        from pathway_tpu.models import decoder as dec
+
+        self._k_pool, self._v_pool = dec.init_kv_pool(
+            self.cfg, self.num_pages, self.page_size, self.slots
+        )
+
     def _fail_all(self, exc: BaseException) -> None:
         """Fail every queued and active request.  A step in flight has no
         one left to deliver to: it is let go unread, and nothing is waited
-        for any more."""
+        for any more.  A program that failed after it consumed its pools
+        left none behind: they are made anew, since no request that held
+        a page of them lives on."""
         self._step = None
         self._drained(failed=True)
+        pools = self._jax.tree_util.tree_leaves((self._k_pool, self._v_pool))
+        if any(leaf.is_deleted() for leaf in pools):
+            self._init_pools()
         with self._lock:
             victims = [r for r in self._queue]
             self._queue.clear()

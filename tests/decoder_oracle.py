@@ -6,6 +6,7 @@ itself is reached the way a caller reaches it, through a
 from __future__ import annotations
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -100,3 +101,52 @@ def paged_logits(tree, cfg, ids, cut: int, *, page: int = 4) -> np.ndarray:
         )
         outs.append(np.asarray(logits))
     return np.stack(outs, axis=1)
+
+
+def lower_program(sched, which: str, tree, k_pool, v_pool, *, width: int, rows=None,
+                  table_width: int = 2, array=None):
+    """The scheduler's ``"decode"`` step, or its ``"prefill"`` program over
+    ``rows`` rows (every slot by default) of ``width`` tokens, lowered over
+    ``tree`` and the pools: the arguments a tick passes, noughts, each made
+    by ``array(shape, dtype)`` (``jnp.zeros`` by default; a shape with a
+    sharding where nothing is to be allocated)."""
+    array = array or jnp.zeros
+    S = sched.slots
+
+    def tables(n):
+        bt = array((n, table_width), jnp.int32)
+        return (bt, array((n, sched.ring_pages), jnp.int32)) if sched._hybrid else bt
+
+    logits = array((S, sched.cfg.vocab_size), jnp.float32)
+    carried = (array((2,), jnp.int32),) if sched._counted else ()
+    if which == "decode":
+        f32 = array((S,), jnp.float32)
+        active = (array((S,), jnp.bool_),) if sched._counted else ()
+        return sched._decode_fn.lower(
+            tree, k_pool, v_pool, tables(S), array((S,), jnp.int32), logits,
+            array((2,), jnp.uint32), f32, f32, f32, *active, *carried,
+        )
+    R = S if rows is None else rows
+    lens = array((R,), jnp.int32)
+    return sched._prefill_fn.lower(
+        tree, k_pool, v_pool, tables(R), array((R, width), jnp.int32), lens, lens,
+        logits, lens, array((R,), jnp.bool_), *carried,
+    )
+
+
+# an instruction of a compiled module's text: name, result dims, opcode
+HLO_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(", flags=re.M
+)
+
+
+def elements(dims: str) -> int:
+    return int(np.prod([int(d) for d in dims.split(",")])) if dims else 1
+
+
+def aliased_parameters(compiled_text: str) -> set[int]:
+    """The parameters a compiled module's header says come out again in
+    the buffer they went in (``input_output_alias``)."""
+    header = compiled_text.splitlines()[0]
+    assert "input_output_alias" in header, header[:300]
+    return {int(p) for p in re.findall(r"\{\d+\}: \((\d+), \{\}", header)}

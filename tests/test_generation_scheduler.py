@@ -694,6 +694,70 @@ def test_raising_tick_with_a_step_in_flight_fails_requests_not_the_thread():
     assert sched._step is None and sched._inflight is None
 
 
+def _pool_leaves(sched):
+    import jax
+
+    return jax.tree_util.tree_leaves((sched._k_pool, sched._v_pool))
+
+
+@pytest.mark.parametrize("model", [MODEL, "pw-tiny-hybrid-decoder"])
+def test_a_program_consumes_the_pools_it_is_given(model):
+    """Every program of a tick (the prefill, then the decode step) takes
+    the pools it is handed and leaves them deleted; the scheduler holds the
+    ones that came back, the only copy there is."""
+    lm = shared_decoder(model, max_cache=128)
+    sched = generation.GenerationScheduler(
+        lm, slots=2, page_size=8, prefill_chunk=16, queue_limit=4
+    )
+    _enqueue(sched, generation.GenRequest([5, 6, 7], 6))
+    for _ in range(3):  # prefill + first step, then a step a tick
+        given = _pool_leaves(sched)
+        sched._tick()
+        assert all(leaf.is_deleted() for leaf in given)
+        held = _pool_leaves(sched)
+        assert not any(leaf.is_deleted() for leaf in held)
+        assert [leaf.shape for leaf in held] == [leaf.shape for leaf in given]
+    sched.shutdown()
+    assert not any(leaf.is_deleted() for leaf in _pool_leaves(sched))
+
+
+def test_a_step_that_fails_with_the_pools_taken_leaves_the_scheduler_serving():
+    """The fourth decode step runs, consuming the pools it was given, and
+    only then fails: both requests fail, the failure is counted once, the
+    pools are made anew, and the next request is answered as it would be
+    by a scheduler that never failed."""
+    lm = _lm()
+    sched = generation.GenerationScheduler(
+        lm, slots=2, page_size=16, prefill_chunk=8, queue_limit=4
+    )
+    step, calls, taken = sched._decode_fn, [0], []
+
+    def failing(*args):
+        calls[0] += 1
+        out = step(*args)
+        if calls[0] == 4:
+            taken.extend([args[1], args[2]])
+            raise RuntimeError("device fell over after the step")
+        return out
+
+    sched._decode_fn = failing
+    try:
+        futures = [sched.submit_ids([3, 1, 4], max_new_tokens=30) for _ in range(2)]
+        for future in futures:
+            with pytest.raises(RuntimeError, match="fell over after the step"):
+                future.result(timeout=120)
+        assert taken and all(pool.is_deleted() for pool in taken)
+        assert sched.snapshot()["tick_failures"] == 1
+        assert not any(leaf.is_deleted() for leaf in _pool_leaves(sched))
+        assert _no_interval_left_open(sched)[-1]["attributes"].get("failed") is True
+        again = sched.submit_ids([3, 1, 4], max_new_tokens=5).result(timeout=120)
+        assert again == reference_greedy(lm, [3, 1, 4], 5)
+        assert sched.snapshot()["tick_failures"] == 1
+    finally:
+        sched.shutdown()
+    assert sched.allocator.used_pages == 0 and sched.allocator.reserved == 0
+
+
 def test_a_second_answer_compiles_nothing_the_first_did_not():
     from pathway_tpu.engine.profiler import install_jax_accounting
 

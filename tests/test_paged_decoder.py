@@ -321,3 +321,63 @@ def test_scope_names_are_metadata_only(which, monkeypatch):
     bare = _serving_program(which)
     assert "attn.qkv" not in bare.as_text(debug_info=True)
     assert with_scopes == instructions(bare) and with_scopes > 50
+
+
+# ---------------------------------------------------------------------------
+# the compiled programs touch of the pools what a slot holds
+# ---------------------------------------------------------------------------
+
+
+def _compiled_program(model: str, which: str, pages: int):
+    """The scheduler's compiled decode or prefill program for ``model`` over
+    pools of ``pages`` pages, with the pools' shapes and the position of
+    the first of them among the program's parameters."""
+    from pathway_tpu.serving.generation import GenerationScheduler
+    from tests.decoder_oracle import lower_program
+
+    lm = dec.shared_decoder(model, max_cache=64)
+    sched = GenerationScheduler(lm, slots=3, page_size=8, pages=pages, prefill_chunk=16)
+    try:
+        lowered = lower_program(
+            sched, which, lm.params, sched._k_pool, sched._v_pool, width=16
+        )
+        pools = jax.tree_util.tree_leaves((sched._k_pool, sched._v_pool))
+        first = len(jax.tree_util.tree_leaves(lm.params))
+        return lowered.compile().as_text(), [p.shape for p in pools], first
+    finally:
+        sched.shutdown()
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+@pytest.mark.parametrize("model", ["pw-tiny-decoder", "pw-tiny-hybrid-decoder"])
+def test_a_program_neither_copies_nor_slices_a_pool(model, which):
+    """A step's cost must not follow the pool: at two pool sizes, no
+    instruction of the compiled program that moves data whole (a slice, an
+    update of a slice, a copy, a broadcast) yields a layer's pool or a
+    whole pool, and every pool goes in and comes out in one buffer.
+
+    One thing is let through: a ``copy`` of a window layer's ring pool.
+    The ring is read before it is written, and the CPU backend, which
+    drops the order between a gather and a scatter that share no value,
+    keeps it by copying; the TPU's compiler orders the two and copies
+    nothing (``tests/test_tpu_compiled_step.py`` holds the step compiled
+    for the chip).  A ring's pool is as large as the slots' windows, not
+    as the pages the scheduler was given."""
+    from tests.decoder_oracle import HLO_INSTRUCTION, aliased_parameters, elements
+
+    movers = ("dynamic-slice", "dynamic-update-slice", "copy", "broadcast")
+
+    def sizes(shapes):
+        whole = {int(np.prod(shape)) for shape in shapes}
+        return whole | {int(np.prod(shape[1:])) for shape in shapes}
+
+    for pages in (37, 61):  # primes: no other array of the program has such a size
+        text, pools, first = _compiled_program(model, which, pages)
+        rings = sizes([shape for shape in pools if shape[1] != pages])
+        moved = [
+            (op, dims) for _name, dims, op in HLO_INSTRUCTION.findall(text)
+            if op in movers and (n := elements(dims)) in sizes(pools)
+            and not (op == "copy" and n in rings)
+        ]
+        assert not moved, moved
+        assert set(range(first, first + len(pools))) <= aliased_parameters(text)

@@ -1,0 +1,141 @@
+"""The scheduler's two programs compiled for the chip, without the chip.
+
+The TPU's compiler is installed here and compiles for a v5e that is
+described, not attached: nothing runs, no weight is allocated, and what
+comes back is the optimized program the chip would run.  These tests read
+it at the sizes of the benchmark's ``mistral7b-bge-rag`` configuration
+(24 layers at published widths, 8 slots, 257 pages of 16 tokens) and hold
+what no CPU compile can show: that a step reads each weight where it lies
+and touches of the KV pools only what its tables name.  The layouts that
+matter (which product wants which operand tiled how) exist on this
+backend alone.
+
+All of them live in this one file, and the topology is described inside a
+fixture: one process at a time may load the TPU's library, so every
+worker collects the same tests and only the one given this file loads it.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import re
+import types
+
+import jax
+import numpy as np
+import pytest
+
+from pathway_tpu.models import decoder as dec
+from pathway_tpu.serving.generation import GenerationScheduler
+from tests.decoder_oracle import (
+    HLO_INSTRUCTION, aliased_parameters, elements, lower_program,
+)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PAGES, TABLE_WIDTH, PREFILL_WIDTH = 257, 32, 512
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps the compiler from loading
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def programs(one_chip):
+    """``{"decode": text, "prefill": text}`` and the shapes the assertions
+    are about, for the Mistral configuration the benchmark serves."""
+    with open(os.path.join(ROOT, "chipbench", "configs", "mistral7b-bge-rag.json")) as f:
+        hf = {k: v for k, v in json.load(f).items() if k != "chipbench"}
+    cfg = dec.decoder_config_from_hf(hf)
+
+    def array(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(lambda x: array(x.shape, x.dtype), tree)
+
+    # the scheduler as the server builds it, but for its pools (two pages
+    # here: the programs are lowered over shapes, not over these arrays)
+    lm = types.SimpleNamespace(config=cfg, params=None, max_cache=1024, eos_id=None)
+    sched = GenerationScheduler(lm, pages=2)
+    cache_was = jax.config.jax_enable_compilation_cache
+    # an entry compiled for a described chip cannot be read back without one
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        tree = on_chip(jax.eval_shape(lambda: dec.init_decoder_params(cfg, 0)))
+        kp, vp = on_chip(jax.eval_shape(
+            lambda: dec.init_kv_pool(cfg, PAGES, sched.page_size, sched.slots)
+        ))
+        decode, prefill = (
+            lower_program(
+                sched, which, tree, kp, vp, width=PREFILL_WIDTH, rows=1,
+                table_width=TABLE_WIDTH, array=array,
+            ).compile().as_text()
+            for which in ("decode", "prefill")
+        )
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        sched.shutdown()
+    D, H = cfg.head_dim, cfg.hidden
+    return {
+        "decode": decode, "prefill": prefill,
+        "pool": kp.shape,
+        "projections": {H * cfg.heads * D, H * cfg.kv_heads * D},
+        "first_pool": len(jax.tree_util.tree_leaves(tree)),
+    }
+
+
+def _scheduled(text: str):
+    """``(name, elements, opcode)`` of the instructions the device runs one
+    by one: those of the entry and of the loops' bodies, not those inside
+    a fusion (a slice there is a read fused into its consumer, which is
+    what is wanted)."""
+    fused = set(re.findall(r"(?:calls|to_apply)=%([\w.\-]+)", text))
+    out = []
+    for block in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text):
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", block)
+        if head is None or head.group(1) in fused:
+            continue
+        out += [
+            (name, elements(dims), op)
+            for name, dims, op in HLO_INSTRUCTION.findall(block)
+        ]
+    return out
+
+
+def _moves_data_only(name: str, op: str) -> bool:
+    """A copy, or a fusion that computes nothing: the compiler names those
+    after what they do (``constant_dynamic-slice_fusion.10``,
+    ``copy_bitcast_fusion``)."""
+    if op in ("copy", "copy-start", "dynamic-slice", "dynamic-update-slice", "broadcast"):
+        return True
+    return op == "fusion" and re.search(r"slice|copy|broadcast", name) is not None
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_mistral_step_on_the_chip_reads_weights_and_pools_in_place(programs, which):
+    text = programs[which]
+    assert text.startswith(f"HloModule jit__{which}")
+    scheduled = _scheduled(text)
+    assert len(scheduled) > 100  # the parse found the loop's body
+    pool = programs["pool"]
+    whole = int(np.prod(pool))
+    sizes = {whole, whole // pool[0]} | programs["projections"]
+    moved = [
+        (name, op, n) for name, n, op in scheduled
+        if n in sizes and _moves_data_only(name, op)
+    ]
+    assert not moved, moved
+    # the pools go in and come out in one buffer, and flow through the
+    # layers' loop as they are
+    first = programs["first_pool"]
+    assert {first, first + 1} <= aliased_parameters(text)
