@@ -365,6 +365,26 @@ METRICS: dict[str, tuple[str, str]] = {
         "counter", "held experts that met at least one token, summed over "
         "the routed layers of every prefill program (divisor: generate."
         "prefill.chunks x routed layers)"),
+    "generate.ssm.state.resets": (
+        "counter", "slots whose recurrent state (a model with Mamba-2 "
+        "layers) a prompt's first chunk started from noughts: over "
+        "generate.requests, 1 where every admission zeroes exactly one "
+        "slot's state"),
+    "generate.ssm.prefill.tokens": (
+        "counter", "real tokens a prefill program's Mamba-2 scan advanced "
+        "a slot's state by, summed over rows, counted on the device from "
+        "the time steps that were not nought: equals generate.prefill."
+        "tokens where no padding leaks into a state"),
+    "generate.ssm.decode.tokens": (
+        "counter", "rows whose recurrent state a decode step advanced "
+        "(the rows that decoded; a padding row's time step is nought)"),
+    "generate.ssm.state.slots": (
+        "gauge", "slots that hold a recurrent state: the taken slots of a "
+        "model with Mamba-2 layers, 0 for any other"),
+    "generate.ssm.state.bytes": (
+        "gauge", "bytes of recurrent state the taken slots hold: a slot's "
+        "convolution tails and float32 scan states over the Mamba-2 "
+        "layers, fixed whatever the sequence's length"),
     "generate.kv.pages.global": (
         "gauge", "pages of the allocator's pool in use: the cache of the "
         "layers that keep every token (all layers of a model of one kind)"),

@@ -55,16 +55,32 @@ class LayerKind:
     run a stack scanned by one layer body that its kind parameterises."""
 
     kv_heads: int
+    # which parts a layer is made of: "block" is attention followed by an
+    # FFN, each behind its own norm; "attention", "mamba" (a Mamba-2
+    # mixer, ``ops/ssm.py``) and "ffn" are layers of that one part alone,
+    # behind the layer's one norm
+    part: str = "block"
     # sliding-window attention over the last ``window`` positions (None =
     # every earlier position); a window layer's paged cache is a ring
     window: int | None = None
     rope_theta: float = 10000.0
+    # rotary embedding on queries and keys (False: none; the model takes
+    # its order from elsewhere, as ``nemotron_h`` does from its Mamba layers)
+    rope: bool = True
     # a learned logit per query head that joins the softmax and carries no
     # value (the run's ``sink`` leaf)
     sink: bool = False
     # routed experts (width ``intermediate`` each) or one dense SwiGLU
     routed: bool = False
     intermediate: int = 14336
+
+    @property
+    def attends(self) -> bool:
+        return self.part in ("block", "attention")
+
+    @property
+    def has_ffn(self) -> bool:
+        return self.part in ("block", "ffn")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -94,6 +110,12 @@ class DecoderConfig:
     # "softmax" (Mixtral) or "sigmoid" with a per-expert correction bias
     # on the choice (``noaux_tc``)
     experts_scoring: str = "softmax"
+    # an expert without a gate is ``down(relu(up x) ** 2)``; the chosen
+    # experts' weights times ``experts_route_scale``; a shared expert of
+    # width ``experts_shared`` (0: none) that every token takes
+    experts_gated: bool = True
+    experts_route_scale: float = 1.0
+    experts_shared: int = 0
     # Mistral-v0.1-style sliding-window attention: each query attends to
     # at most the last `sliding_window` positions (None = full causal)
     sliding_window: int | None = None
@@ -111,6 +133,19 @@ class DecoderConfig:
     # layers of different kinds in one model: ``((kind, count), ...)`` in
     # layer order.  None: every layer is ``self.kind`` (the fields above)
     runs: tuple[tuple[LayerKind, int], ...] | None = None
+    # a "mamba" layer's sizes: heads x head width is the mixer's inner
+    # width, ``ssm_groups`` groups of heads share B and C of ``ssm_state``
+    # entries, the convolution before the scan has ``ssm_conv`` taps, the
+    # prefill program scans in chunks of ``ssm_chunk`` tokens; ``dt_bias``
+    # is seeded so that the time step starts in ``ssm_dt_init`` (min, max,
+    # floor)
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    ssm_dt_init: tuple[float, float, float] = (0.001, 0.1, 1e-4)
 
     @property
     def head_dim(self) -> int:
@@ -136,6 +171,19 @@ class DecoderConfig:
     @property
     def routed_layers(self) -> int:
         return sum(n for kind, n in self.layer_runs if kind.routed)
+
+    @property
+    def ssm_layers(self) -> int:
+        return sum(n for kind, n in self.layer_runs if kind.part == "mamba")
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """Columns the convolution runs over: x, then B and C of every group."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
 
 def run_stacks(cfg: DecoderConfig, layers, *pools):
@@ -198,10 +246,12 @@ def decoder_config_from_hf(hf: dict) -> DecoderConfig:
     model_type = hf.get("model_type")
     if model_type == "mimo_v2":
         return _mimo_v2_config(hf)
+    if model_type == "nemotron_h":
+        return _nemotron_h_config(hf)
     if model_type not in _LLAMA_TYPES:
         raise ValueError(
             f"config.json has model_type {model_type!r}; decoder_config_for "
-            f"reads {', '.join(_LLAMA_TYPES)} and mimo_v2"
+            f"reads {', '.join(_LLAMA_TYPES)}, mimo_v2 and nemotron_h"
         )
     return DecoderConfig(
         vocab_size=hf.get("vocab_size", 32000),
@@ -278,13 +328,7 @@ def _mimo_v2_config(hf: dict) -> DecoderConfig:
             runs[-1] = (kind, runs[-1][1] + 1)
         else:
             runs.append((kind, 1))
-    held = hf["n_routed_experts"]
-    published = hf.get("n_routed_experts_published", held)
-    first = hf.get("expert_shard_index", 0) * held
-    if first + held > published:
-        raise ValueError(
-            f"mimo_v2 config: experts [{first}, {first + held}) of {published}"
-        )
+    held, published, first = _expert_share(hf, "mimo_v2")
     return DecoderConfig(
         vocab_size=hf["vocab_size"], hidden=hf["hidden_size"], layers=L,
         heads=heads, kv_heads=hf["num_key_value_heads"],
@@ -301,6 +345,101 @@ def _mimo_v2_config(hf: dict) -> DecoderConfig:
         rotary_dim=int(hf.get("partial_rotary_factor", 1.0) * D) // 2 * 2,
         value_scale=float(hf.get("attention_value_scale") or 1.0),
         runs=tuple(runs),
+    )
+
+
+def _expert_share(hf: dict, model_type: str) -> tuple[int, int, int]:
+    """``(held, published, first)``: ``n_routed_experts`` counts the experts
+    held here; a file that is one chip's share of an expert-parallel layer
+    states the router's published width as ``n_routed_experts_published``
+    and which share this is as ``expert_shard_index``."""
+    held = hf["n_routed_experts"]
+    published = hf.get("n_routed_experts_published", held)
+    first = hf.get("expert_shard_index", 0) * held
+    if first + held > published:
+        raise ValueError(
+            f"{model_type} config: experts [{first}, {first + held}) of {published}"
+        )
+    return held, published, first
+
+
+# the one part each letter of ``hybrid_override_pattern`` makes a layer
+_NEMOTRON_H_PARTS = {"M": "mamba", "*": "attention", "E": "ffn"}
+
+
+def _nemotron_h_config(hf: dict) -> DecoderConfig:
+    """``model_type: nemotron_h`` (Nemotron-H / Nemotron 3 Nano, language
+    model): every layer is ONE part behind one norm, by its letter of
+    ``hybrid_override_pattern``: ``M`` a Mamba-2 mixer, ``*`` grouped-query
+    attention with no rotary embedding (the family takes its order from
+    the Mamba layers), ``E`` sigmoid-routed experts without a gate
+    (``down(relu(up x) ** 2)``) beside a shared expert, the chosen weights
+    renormalised and scaled.  The share of an expert-parallel layer is
+    stated as in ``mimo_v2``.  A setting this forward does not implement
+    raises, as does an ``-`` (dense MLP) layer."""
+    unread = {
+        "n_group": (1, None), "topk_group": (1, None), "n_shared_experts": (1,),
+        "norm_topk_prob": (True,), "attention_bias": (False, None),
+        "mamba_proj_bias": (False, None), "mlp_bias": (False, None),
+        "use_bias": (False, None), "use_conv_bias": (True,),
+        "mamba_hidden_act": ("silu",), "mlp_hidden_act": ("relu2",),
+        "tie_word_embeddings": (False, None), "sliding_window": (None,),
+        "residual_in_fp32": (False, None), "moe_latent_size": (None, 0),
+        "num_nextn_predict_layers": (None, 0), "time_step_limit": (None,),
+        "norm_eps": (hf.get("layer_norm_epsilon", 1e-5), None),
+    }
+    for key, allowed in unread.items():
+        if hf.get(key) not in allowed:
+            raise NotImplementedError(
+                f"nemotron_h config: {key}={hf.get(key)!r} is not implemented "
+                f"(this forward takes {allowed})"
+            )
+    L = hf["num_hidden_layers"]
+    pattern = hf["hybrid_override_pattern"][:L]
+    if len(pattern) != L:
+        raise ValueError(
+            f"nemotron_h config: {L} layers but hybrid_override_pattern "
+            f"describes {len(pattern)}"
+        )
+    other = sorted(set(pattern) - set(_NEMOTRON_H_PARTS))
+    if other:
+        raise NotImplementedError(
+            f"nemotron_h config: layers {other} of hybrid_override_pattern are "
+            f"not implemented (this forward takes {sorted(_NEMOTRON_H_PARTS)})"
+        )
+    runs: list[tuple[LayerKind, int]] = []
+    for letter in pattern:
+        part = _NEMOTRON_H_PARTS[letter]
+        kind = LayerKind(
+            kv_heads=hf["num_key_value_heads"] if part == "attention" else 0,
+            part=part, rope=False, routed=part == "ffn",
+            intermediate=hf["moe_intermediate_size"] if part == "ffn" else 0,
+        )
+        if runs and runs[-1][0] == kind:
+            runs[-1] = (kind, runs[-1][1] + 1)
+        else:
+            runs.append((kind, 1))
+    held, published, first = _expert_share(hf, "nemotron_h")
+    return DecoderConfig(
+        vocab_size=hf["vocab_size"], hidden=hf["hidden_size"], layers=L,
+        heads=hf["num_attention_heads"], kv_heads=hf["num_key_value_heads"],
+        intermediate=hf["moe_intermediate_size"],
+        max_len=min(hf.get("max_position_embeddings", 4096), 8192),
+        norm_eps=float(hf.get("layer_norm_epsilon", 1e-5)),
+        dtype=jnp.dtype(hf.get("torch_dtype", "bfloat16")),
+        experts=held, experts_top_k=hf["num_experts_per_tok"],
+        experts_published=published, experts_first=first,
+        experts_scoring="sigmoid", experts_gated=False,
+        experts_route_scale=float(hf.get("routed_scaling_factor") or 1.0),
+        experts_shared=hf["moe_shared_expert_intermediate_size"],
+        qk_head_dim=hf["head_dim"], runs=tuple(runs),
+        ssm_heads=hf["mamba_num_heads"], ssm_head_dim=hf["mamba_head_dim"],
+        ssm_groups=hf["n_groups"], ssm_state=hf["ssm_state_size"],
+        ssm_conv=hf["conv_kernel"], ssm_chunk=hf["chunk_size"],
+        ssm_dt_init=(
+            float(hf.get("time_step_min", 0.001)), float(hf.get("time_step_max", 0.1)),
+            float(hf.get("time_step_floor", 1e-4)),
+        ),
     )
 
 
@@ -325,6 +464,31 @@ TINY_HYBRID_HF = {
     "torch_dtype": "float32",
 }
 PRESETS["pw-tiny-hybrid-decoder"] = decoder_config_from_hf(TINY_HYBRID_HF)
+
+# every kind of layer Nemotron 3 Nano has, tiny, in its order (one period
+# and the two layers that follow, as the benchmark's cut ends): Mamba-2
+# heads in groups, a chunk shorter than the tests' prompts, attention heads
+# whose width times their number is not the hidden size, one of two shares
+# of 8 ungated experts beside a shared expert.
+# ``chipbench/configs/nemotron-3-nano-bge-rag.json``'s ``tiny.decoder`` block
+TINY_MAMBA_HF = {
+    "model_type": "nemotron_h", "vocab_size": 512, "hidden_size": 48,
+    "num_hidden_layers": 9, "hybrid_override_pattern": "MEMEM*EME",
+    "mamba_num_heads": 8, "mamba_head_dim": 8, "n_groups": 2,
+    "ssm_state_size": 16, "conv_kernel": 4, "chunk_size": 8,
+    "time_step_min": 0.001, "time_step_max": 0.1, "time_step_floor": 0.0001,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "intermediate_size": 40, "moe_intermediate_size": 40,
+    "moe_shared_expert_intermediate_size": 80,
+    "n_routed_experts": 4, "n_routed_experts_published": 8,
+    "expert_shard_index": 0, "num_experts_per_tok": 3, "n_shared_experts": 1,
+    "n_group": 1, "topk_group": 1, "norm_topk_prob": True,
+    "routed_scaling_factor": 2.5, "mamba_hidden_act": "silu",
+    "mlp_hidden_act": "relu2", "use_conv_bias": True,
+    "layer_norm_epsilon": 1e-05, "max_position_embeddings": 128,
+    "torch_dtype": "float32",
+}
+PRESETS["pw-tiny-mamba-decoder"] = decoder_config_from_hf(TINY_MAMBA_HF)
 
 
 def decoder_config_for(model_name: str) -> DecoderConfig:
@@ -424,25 +588,33 @@ def init_decoder_params(cfg: DecoderConfig, seed: int = 0):
 
 
 def _init_run(cfg: DecoderConfig, kind: LayerKind, n: int, key, norm_init):
-    """One run's stacked leaves: the fused ``wqkv`` (queries, then keys,
-    then values, as ``attention_projection_layout: fused_qkv`` lays them),
-    ``wo`` over the value heads, a sink logit per query head where the
-    kind has one (normal x 0.5: not nought, or a test would not see it),
-    and the dense SwiGLU or the router (f32, at its published width, with
-    the ``noaux_tc`` correction bias, normal x 0.02) and the experts HELD
+    """One run's stacked leaves, by the parts its kind has.
+
+    Attention: the fused ``wqkv`` (queries, then keys, then values, as
+    ``attention_projection_layout: fused_qkv`` lays them), ``wo`` over the
+    value heads, a sink logit per query head where the kind has one
+    (normal x 0.5: not nought, or a test would not see it).  FFN: the
+    dense SwiGLU, or the router (f32, at its published width, with the
+    ``noaux_tc`` correction bias, normal x 0.02) and the experts HELD
     (their draw keyed by the first expert's index, so that another share
-    of the layer draws other experts)."""
+    of the layer draws other experts; no ``wg`` where experts have no
+    gate) and the shared expert, which every share draws alike.  A layer
+    of one part has the one norm ``ln0``; ``_init_mamba`` draws a mixer."""
     H, NH, D, Dv = cfg.hidden, cfg.heads, cfg.head_dim, cfg.v_dim
     keys = jax.random.split(key, 8)
-    qkv = NH * D + kind.kv_heads * (D + Dv)
-    run = {
-        "ln0": jnp.ones((n, H), cfg.dtype),
-        "ln1": jnp.ones((n, H), cfg.dtype),
-        "wqkv": norm_init(keys[0], (n, H, qkv), H),
-        "wo": norm_init(keys[1], (n, NH * Dv, H), NH * Dv),
-    }
-    if kind.sink:
-        run["sink"] = 0.5 * jax.random.normal(keys[2], (n, NH), jnp.float32)
+    run = {"ln0": jnp.ones((n, H), cfg.dtype)}
+    if kind.part == "block":
+        run["ln1"] = jnp.ones((n, H), cfg.dtype)
+    if kind.part == "mamba":
+        run.update(_init_mamba(cfg, n, keys, norm_init))
+    if kind.attends:
+        qkv = NH * D + kind.kv_heads * (D + Dv)
+        run["wqkv"] = norm_init(keys[0], (n, H, qkv), H)
+        run["wo"] = norm_init(keys[1], (n, NH * Dv, H), NH * Dv)
+        if kind.sink:
+            run["sink"] = 0.5 * jax.random.normal(keys[2], (n, NH), jnp.float32)
+    if not kind.has_ffn:
+        return run
     F = kind.intermediate
     if not kind.routed:
         run.update({
@@ -453,16 +625,71 @@ def _init_run(cfg: DecoderConfig, kind: LayerKind, n: int, key, norm_init):
         return run
     E, width = cfg.experts, cfg.experts_published or cfg.experts
     held = [jax.random.fold_in(k, cfg.experts_first) for k in keys[3:6]]
-    run.update({
-        "moe_router": jax.random.normal(keys[6], (n, H, width), jnp.float32)
-        / np.sqrt(H),
-        "wg": norm_init(held[0], (n, E, H, F), H),
-        "wu": norm_init(held[1], (n, E, H, F), H),
-        "wd": norm_init(held[2], (n, E, F, H), F),
-    })
+    run["moe_router"] = jax.random.normal(
+        keys[6], (n, H, width), jnp.float32
+    ) / np.sqrt(H)
+    def stored(w, axis):
+        # an expert's hidden width is stored as whole lanes (``_lanes``):
+        # the added columns of ``wg`` / ``wu`` and rows of ``wd`` are
+        # noughts and add nought, whichever form the expert has
+        extra = [(0, _lanes(F) - F if a == axis else 0) for a in range(w.ndim)]
+        return jnp.pad(w, extra) if _lanes(F) > F else w
+
+    if cfg.experts_gated:
+        run["wg"] = stored(norm_init(held[0], (n, E, H, F), H), 3)
+    run["wu"] = stored(norm_init(held[1], (n, E, H, F), H), 3)
+    run["wd"] = stored(norm_init(held[2], (n, E, F, H), F), 2)
     if cfg.experts_scoring == "sigmoid":
         run["moe_bias"] = 0.02 * jax.random.normal(keys[7], (n, width), jnp.float32)
+    if cfg.experts_shared:
+        Fs = cfg.experts_shared
+        up, down = jax.random.split(jax.random.fold_in(keys[6], 1))
+        run["shared_up"] = norm_init(up, (n, H, Fs), H)
+        run["shared_down"] = norm_init(down, (n, Fs, H), Fs)
     return run
+
+
+def _lanes(width: int) -> int:
+    """An expert's hidden width as it is stored: the next multiple of the
+    chip's 128 lanes where it is wider than one and not a multiple.  The
+    grouped product (``parallel/moe.py::moe_serve``) is a kernel that
+    takes whole lanes: given 1,856 columns (14.5 x 128) the compiler pads
+    a copy of ALL of a layer's experts for it, hit or not, every step (638
+    MB a layer at Nemotron 3 Nano's widths).  In memory the columns are
+    tiled by 128 anyway, so the stored padding costs ``wg`` / ``wu``
+    nothing and ``wd`` its added rows."""
+    return width if width <= 128 else -(-width // 128) * 128
+
+
+def _init_mamba(cfg: DecoderConfig, n: int, keys, norm_init):
+    """A Mamba-2 mixer's leaves, seeded away from nought so that a
+    comparison sees each: ``in_proj`` to ``[z | x B C | dt]`` and
+    ``out_proj`` (normal / sqrt(fan_in)); the convolution's taps ``[K,
+    columns]`` (normal / sqrt(K)) and bias (normal x 0.1); ``A_log`` = log
+    of uniform [1, 16) a head; ``dt_bias`` the inverse softplus of a time
+    step drawn log-uniform in ``ssm_dt_init``'s range (the Mamba-2 recipe);
+    ``D`` = 1 + normal x 0.5; the gated norm's weight ones.  The last four
+    stay float32, as the scan reads them."""
+    H, NH, K = cfg.hidden, cfg.ssm_heads, cfg.ssm_conv
+    inner, columns = cfg.ssm_inner, cfg.ssm_conv_width
+    lo, hi, floor = cfg.ssm_dt_init
+    step = jnp.maximum(
+        jnp.exp(
+            jax.random.uniform(keys[4], (n, NH), jnp.float32)
+            * (np.log(hi) - np.log(lo)) + np.log(lo)
+        ),
+        floor,
+    )
+    return {
+        "in_proj": norm_init(keys[0], (n, H, inner + columns + NH), H),
+        "out_proj": norm_init(keys[1], (n, inner, H), inner),
+        "conv_w": norm_init(keys[2], (n, K, columns), K),
+        "conv_b": (0.1 * jax.random.normal(keys[3], (n, columns), jnp.float32)).astype(cfg.dtype),
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "A_log": jnp.log(jax.random.uniform(keys[5], (n, NH), jnp.float32, 1.0, 16.0)),
+        "D": 1.0 + 0.5 * jax.random.normal(keys[6], (n, NH), jnp.float32),
+        "gate_norm": jnp.ones((n, inner), cfg.dtype),
+    }
 
 
 def tp_param_specs(cfg: DecoderConfig, axis: str = "model"):
@@ -646,8 +873,9 @@ def _qkv(lp, x, positions, cfg: DecoderConfig, kind: LayerKind):
     q = q.reshape(*lead, cfg.heads, D)
     k = k.reshape(*lead, KH, D)
     v = v.reshape(*lead, KH, Dv)
-    q = _rope_part(q, positions, kind.rope_theta, cfg.rotary_dim)
-    k = _rope_part(k, positions, kind.rope_theta, cfg.rotary_dim)
+    if kind.rope:
+        q = _rope_part(q, positions, kind.rope_theta, cfg.rotary_dim)
+        k = _rope_part(k, positions, kind.rope_theta, cfg.rotary_dim)
     if cfg.value_scale != 1.0:
         v = v * jnp.asarray(cfg.value_scale, v.dtype)
     return q, k, v
@@ -687,17 +915,15 @@ def _ffn(lp, h, cfg: DecoderConfig, kind: LayerKind | None = None, *,
             scoring=cfg.experts_scoring,
             router_width=cfg.experts_published,
             first_expert=cfg.experts_first,
+            gated=cfg.experts_gated,
+            route_scale=cfg.experts_route_scale,
         )
-        params = {
-            "router": lp["moe_router"],
-            "wg": lp["wg"],
-            "wu": lp["wu"],
-            "wd": lp["wd"],
-        }
-        if "moe_bias" in lp:
-            params["bias"] = lp["moe_bias"]
-        if "moe_layer" in lp:
-            params["layer"] = lp["moe_layer"]
+        params = {"router": lp["moe_router"], "wu": lp["wu"], "wd": lp["wd"]}
+        # what a layer has of: a gate, a correction bias, its index in the
+        # run's expert stacks, a shared expert
+        optional = {"wg": "wg", "bias": "moe_bias", "layer": "moe_layer",
+                    "shared_up": "shared_up", "shared_down": "shared_down"}
+        params.update({name: lp[leaf] for name, leaf in optional.items() if leaf in lp})
         if serving:
             out, pairs, hit = moe_serve(params, h, mcfg, valid)
             return out, jnp.stack([pairs, hit])
@@ -730,6 +956,12 @@ def decoder_layer(lp, x, positions, mask, cfg: DecoderConfig,
     capacity-drop policy (training).
     """
     kind = kind or cfg.kind
+    if kind.part != "block":
+        raise NotImplementedError(
+            f"the full forward takes layers of attention followed by an FFN; "
+            f"a {kind.part!r} layer is served by the scheduler's paged "
+            "programs (_paged_trunk), not trained"
+        )
     q, k, v = _qkv(lp, x, positions, cfg, kind)
     x = x + _mm(_attend(q, k, v, mask, cfg, lp.get("sink")), lp["wo"])
     h = _rms(x, lp["ln1"], cfg.norm_eps)
@@ -886,8 +1118,26 @@ def init_kv_pool(cfg: DecoderConfig, num_pages: int, page_size: int, slots: int 
     num_pages, page_size, KH, D]`` (values ``Dv`` wide).  Page 0 is the
     null page.  A model of runs gets a tuple of pools, one a run: a global
     run's like the above, a window run's ``[L, 1 + slots * ring, ...]``
-    with slot ``i``'s ring at pages ``1 + i * ring`` onwards, fixed."""
+    with slot ``i``'s ring at pages ``1 + i * ring`` onwards, fixed.
+
+    A run keeps whatever state its layers carry from one program to the
+    next in its pair of the two tuples, and the programs hand every pair
+    on alike.  A Mamba-2 run's pair is the recurrent state, a slot: the
+    convolution's tail ``[L, slots, K - 1, columns]`` (the last columns
+    before the next token) and the scan's state ``[L, slots, heads, head
+    width, state size]`` in float32, of fixed size whatever the sequence's
+    length.  A run of FFN layers carries nothing: an empty pair."""
     def pool(n, kind):
+        if kind.part == "mamba":
+            return (
+                jnp.zeros((n, slots, cfg.ssm_conv - 1, cfg.ssm_conv_width), cfg.dtype),
+                jnp.zeros(
+                    (n, slots, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
+                    jnp.float32,
+                ),
+            )
+        if not kind.attends:
+            return jnp.zeros((n, 0), cfg.dtype), jnp.zeros((n, 0), cfg.dtype)
         pages = num_pages
         if uses_ring(cfg, kind):
             pages = 1 + slots * ring_pages(kind.window, page_size)
@@ -900,6 +1150,22 @@ def init_kv_pool(cfg: DecoderConfig, num_pages: int, page_size: int, slots: int 
     if cfg.runs is None:
         return pool(cfg.layers, cfg.kind)
     return tuple(zip(*(pool(n, kind) for kind, n in cfg.runs)))
+
+
+def _page_size(cfg: DecoderConfig, k_pool) -> int:
+    """Tokens a page holds, read off the first run that keeps pages."""
+    for kind, kp in run_stacks(cfg, k_pool):
+        if kind.attends:
+            return kp.shape[2]
+    raise ValueError("no layer of this model keeps a paged cache")
+
+
+def ssm_state_bytes_per_slot(cfg: DecoderConfig) -> int:
+    """Bytes of recurrent state one slot holds across the Mamba-2 layers:
+    the convolution's tail and the scan's float32 state."""
+    tail = (cfg.ssm_conv - 1) * cfg.ssm_conv_width * jnp.dtype(cfg.dtype).itemsize
+    state = cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state * 4
+    return cfg.ssm_layers * (tail + state)
 
 
 class PageExhaustedError(RuntimeError):
@@ -1002,10 +1268,76 @@ def kv_ring_bytes_per_slot(cfg: DecoderConfig, page_size: int) -> int:
     )
 
 
+def _mamba_mixer(lp, h, tails, states, cfg: DecoderConfig, *, index, rows,
+                 valid, fresh):
+    """A Mamba-2 mixer over the normed rows ``h [S, T, H]``, continued
+    from and handed back into the run's recurrent state: ``tails [n,
+    slots, K-1, columns]`` and ``states [n, slots, heads, P, N]`` (float32)
+    at layer ``index``, row ``r`` of the program being slot ``rows[r]``
+    (``rows`` None: slot ``r``).  ``valid [S, T]`` marks the tokens (a
+    row's first), ``fresh [S]`` the rows whose sequence starts with this
+    program: they start from a state of noughts whatever the slot held.
+    A padding token moves nothing (its time step is nought), a row
+    without a token is written back as it was read.  One token a row (the
+    decode step) takes the recurrence as written, more the chunked scan.  Returns ``(out [S, T, H], tails, states,
+    tokens the scan advanced a state by)``."""
+    from pathway_tpu.ops import ssm
+
+    S, T, _H = h.shape
+    NH, P, G, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
+    inner, columns = cfg.ssm_inner, cfg.ssm_conv_width
+    at = (index,) if rows is None else (index, rows)
+    lens = jnp.sum(valid, axis=1, dtype=jnp.int32)
+    tail_was, state_was = tails[at], states[at]
+    tail = jnp.where(fresh[:, None, None], 0, tail_was)
+    state = jnp.where(fresh[:, None, None, None], 0, state_was)
+    with jax.named_scope("ssm.in_proj"):
+        # the barrier keeps the split into z, x B C and dt a view of the
+        # product's output (``_qkv`` says why)
+        proj = lax.optimization_barrier(_mm(h, lp["in_proj"]))
+        z, xbc = proj[..., :inner], proj[..., inner:inner + columns]
+        dt = proj[..., inner + columns:]
+    with jax.named_scope("ssm.conv"):
+        xbc, tail = ssm.causal_conv(xbc, tail, lp["conv_w"], lp["conv_b"], lens)
+    x = xbc[..., :inner].reshape(S, T, NH, P)
+    B = xbc[..., inner:inner + G * N].reshape(S, T, G, N)
+    C = xbc[..., inner + G * N:].reshape(S, T, G, N)
+    with jax.named_scope("ssm.scan"):
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
+        dt = jnp.where(valid[..., None], dt, 0.0)
+        A = -jnp.exp(lp["A_log"])
+        if T == 1:
+            y, state = ssm.ssm_step(x[:, 0], dt[:, 0], A, B[:, 0], C[:, 0], state)
+            y = y[:, None]
+        else:
+            y, state = ssm.ssd_chunked(x, dt, A, B, C, state, cfg.ssm_chunk)
+        y = y + lp["D"][:, None] * x.astype(jnp.float32)
+        advanced = jnp.sum(dt[..., 0] > 0, dtype=jnp.int32)
+    with jax.named_scope("ssm.gate_norm"):
+        # gate, then RMS norm over each group of heads with its weight
+        y = y.reshape(S, T, G, inner // G) * jax.nn.silu(
+            z.astype(jnp.float32)
+        ).reshape(S, T, G, inner // G)
+        y = y * lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True) + cfg.norm_eps)
+        y = y.reshape(S, T, inner).astype(h.dtype) * lp["gate_norm"]
+    with jax.named_scope("ssm.out_proj"):
+        out = _mm(y, lp["out_proj"])
+    with jax.named_scope("ssm.state.write"):
+        live = lens > 0
+        tails = tails.at[at].set(jnp.where(live[:, None, None], tail, tail_was))
+        states = states.at[at].set(
+            jnp.where(live[:, None, None, None], state, state_was)
+        )
+    return out, tails, states, advanced
+
+
 def _paged_trunk(tree, k_pool, v_pool, x, cfg: DecoderConfig, *, tables, rings,
-                 positions, write_positions, mask, valid, starts, lens):
+                 positions, write_positions, mask, valid, starts, lens,
+                 state_rows=None, fresh=None):
     """The layers of a paged program over the rows ``x [S, T, H]``: every
-    run scanned by the one layer body, which its kind parameterises.
+    run scanned by the one layer body, which its kind parameterises: a
+    mixer (attention or Mamba-2) where the kind has one, then an FFN where
+    it has one, each added to the residual stream behind its norm.
 
     A layer whose cache is the slot's block table (``tables [S, G]``)
     scatters its K/V at ``write_positions`` and attends through the gather
@@ -1013,14 +1345,19 @@ def _paged_trunk(tree, k_pool, v_pool, x, cfg: DecoderConfig, *, tables, rings,
     (``rings [S, R]``; :func:`uses_ring`) attends to what the ring held
     before this program (``starts [S]`` tokens) and to the program's own
     rows, by position, then writes the last of its ``lens [S]`` rows that
-    the ring keeps.  ``valid [S, T]`` marks the rows that hold a token.
+    the ring keeps.  A Mamba-2 layer continues the recurrent state of the
+    slots ``state_rows [S]`` (:func:`_mamba_mixer`; ``fresh [S]`` marks the
+    rows that start from noughts).  ``valid [S, T]`` marks the rows that
+    hold a token.
     A run's pools are the scan's carry, not its ``xs`` and ``ys``: a layer
     scatters into and gathers from the stack at its index, so no layer's
     pool is sliced out of the stack or written back into one, and with the
     pools donated (``serving/generation.py``) the caller's buffers are
     updated in place.
     Returns ``(x, k_pool, v_pool, stats)``; ``stats`` is the routed
-    layers' summed ``[pairs, experts_hit]`` (noughts without routed layers).
+    layers' summed ``[pairs, experts_hit]`` (noughts without routed
+    layers), and for a model with Mamba-2 layers a third entry: the tokens
+    the scan advanced a state by (of one layer: they all meet the same).
     """
     from pathway_tpu.ops import attention as attention_ops
 
@@ -1031,7 +1368,7 @@ def _paged_trunk(tree, k_pool, v_pool, x, cfg: DecoderConfig, *, tables, rings,
         )
         if ring:
             # by position, and the same for every layer of the run
-            cap = rings.shape[1] * jax.tree_util.tree_leaves(k_pool)[0].shape[2]
+            cap = rings.shape[1] * _page_size(cfg, k_pool)
             ring_sees = attention_ops.ring_mask(
                 starts, positions, valid, kind.window, cap
             )
@@ -1040,65 +1377,91 @@ def _paged_trunk(tree, k_pool, v_pool, x, cfg: DecoderConfig, *, tables, rings,
         def layer(carry, lp):
             x, kp, vp = carry
             lp, index = lp
+            counts = None
             if experts:
                 lp = {**lp, **experts, "moe_layer": index}
-            with jax.named_scope("attn.qkv"):
-                q, k, v = _qkv(lp, x, positions, cfg, kind)
-            if ring:
-                with jax.named_scope(scope):
-                    ctx = attention_ops.ring_gqa_attention(
-                        q, k, v, kp, vp, rings, ring_sees, lp.get("sink"), index
-                    )
-                # read, then write: the rows that enter the ring replace
-                # entries the attention above still reads
-                with jax.named_scope("kv.write"):
-                    kp = attention_ops.scatter_kv_pages(kp, rings, ring_at, k, index)
-                    vp = attention_ops.scatter_kv_pages(vp, rings, ring_at, v, index)
-            else:
-                with jax.named_scope("kv.write"):
-                    kp = attention_ops.scatter_kv_pages(
-                        kp, tables, write_positions, k, index
-                    )
-                    vp = attention_ops.scatter_kv_pages(
-                        vp, tables, write_positions, v, index
-                    )
-                with jax.named_scope(scope):
-                    ctx = attention_ops.paged_gqa_attention(
-                        q, kp, vp, tables, mask, lp.get("sink"), index
-                    )
-            with jax.named_scope("attn.out"):
-                x = x + _mm(ctx, lp["wo"])
-            h = _rms(x, lp["ln1"], cfg.norm_eps)
-            mlp, stats = _ffn(lp, h, cfg, kind, serving=True, valid=valid)
-            return (x + mlp, kp, vp), (stats if kind.routed else None)
+            if kind.part == "mamba":
+                mixed, kp, vp, counts = _mamba_mixer(
+                    lp, _rms(x, lp["ln0"], cfg.norm_eps), kp, vp, cfg, index=index,
+                    rows=state_rows, valid=valid, fresh=fresh,
+                )
+                x = x + mixed
+            if kind.attends:
+                with jax.named_scope("attn.qkv"):
+                    q, k, v = _qkv(lp, x, positions, cfg, kind)
+                if ring:
+                    with jax.named_scope(scope):
+                        ctx = attention_ops.ring_gqa_attention(
+                            q, k, v, kp, vp, rings, ring_sees, lp.get("sink"), index
+                        )
+                    # read, then write: the rows that enter the ring replace
+                    # entries the attention above still reads
+                    with jax.named_scope("kv.write"):
+                        kp = attention_ops.scatter_kv_pages(kp, rings, ring_at, k, index)
+                        vp = attention_ops.scatter_kv_pages(vp, rings, ring_at, v, index)
+                else:
+                    with jax.named_scope("kv.write"):
+                        kp = attention_ops.scatter_kv_pages(
+                            kp, tables, write_positions, k, index
+                        )
+                        vp = attention_ops.scatter_kv_pages(
+                            vp, tables, write_positions, v, index
+                        )
+                    with jax.named_scope(scope):
+                        ctx = attention_ops.paged_gqa_attention(
+                            q, kp, vp, tables, mask, lp.get("sink"), index
+                        )
+                with jax.named_scope("attn.out"):
+                    x = x + _mm(ctx, lp["wo"])
+            if kind.has_ffn:
+                # a block's FFN has a norm of its own, a lone FFN the layer's
+                h = _rms(x, lp["ln1" if kind.part == "block" else "ln0"], cfg.norm_eps)
+                mlp, stats = _ffn(lp, h, cfg, kind, serving=True, valid=valid)
+                x = x + mlp
+                if kind.routed:
+                    counts = stats
+            return (x, kp, vp), counts
 
         return layer
 
-    k_out, v_out, stats = [], [], jnp.zeros((2,), jnp.int32)
+    k_out, v_out = [], []
+    stats, advanced = jnp.zeros((2,), jnp.int32), jnp.int32(0)
     for kind, layers, kp, vp in run_stacks(cfg, tree["layers"], k_pool, v_pool):
         experts = {}
         if kind.routed:
             # the run's expert stacks stay whole and the body is told its
             # layer's index in them (``moe_serve`` says why)
-            experts = {name: layers[name] for name in ("wg", "wu", "wd")}
+            experts = {
+                name: layers[name] for name in ("wg", "wu", "wd") if name in layers
+            }
             layers = {k: v for k, v in layers.items() if k not in experts}
         index = jnp.arange(kp.shape[0], dtype=jnp.int32)
-        (x, kp, vp), routed = lax.scan(
+        (x, kp, vp), counts = lax.scan(
             body(kind, experts), (x, kp, vp), (layers, index)
         )
         k_out.append(kp)
         v_out.append(vp)
         if kind.routed:
-            stats = stats + routed.sum(0)
+            stats = stats + counts.sum(0)
+        elif kind.part == "mamba":
+            advanced = advanced + counts.sum()
+    if cfg.ssm_layers:
+        # every Mamba-2 layer meets the same tokens: one layer's count
+        stats = jnp.concatenate([stats, (advanced // cfg.ssm_layers)[None]])
     if cfg.runs is None:
         return x, k_out[0], v_out[0], stats
     return x, tuple(k_out), tuple(v_out), stats
 
 
 def _split_tables(cfg: DecoderConfig, block_tables):
-    """``(tables, rings)``: a model of runs is handed both, a model of one
-    kind its block tables alone."""
-    return block_tables if cfg.runs is not None else (block_tables, None)
+    """``(tables, rings, state_rows)``: a model of runs is handed its block
+    tables and rings, and a prefill program of one with Mamba-2 layers the
+    slot whose recurrent state each row continues as well (None: row ``r``
+    is slot ``r``); a model of one kind its block tables alone."""
+    if cfg.runs is None:
+        return block_tables, None, None
+    tables, rings, *state_rows = block_tables
+    return tables, rings, (state_rows[0] if state_rows else None)
 
 
 def paged_decode_step(tree, k_pool, v_pool, block_tables, seq_lens, token,
@@ -1121,10 +1484,9 @@ def paged_decode_step(tree, k_pool, v_pool, block_tables, seq_lens, token,
     that does not must not write, and the routed layers count and compute
     the active rows only.
     """
-    tables, rings = _split_tables(cfg, block_tables)
+    tables, rings, _rows = _split_tables(cfg, block_tables)
     S = token.shape[0]
-    page = jax.tree_util.tree_leaves(k_pool)[0].shape[2]
-    C = tables.shape[1] * page
+    C = tables.shape[1] * _page_size(cfg, k_pool)
     x = tree["embed"][token][:, None, :]  # [S, 1, H]
     positions = seq_lens[:, None]  # [S, 1]
     idx = jnp.arange(C)[None, None, :]
@@ -1141,6 +1503,8 @@ def paged_decode_step(tree, k_pool, v_pool, block_tables, seq_lens, token,
         tree, k_pool, v_pool, x, cfg, tables=tables, rings=rings,
         positions=positions, write_positions=write_positions, mask=mask,
         valid=valid, starts=seq_lens, lens=jnp.ones((S,), jnp.int32),
+        # row r of a step is slot r, and no sequence starts with a step
+        fresh=jnp.zeros((S,), bool),
     )
     with jax.named_scope("lm_head"):
         x = _rms(x, tree["final_norm"], cfg.norm_eps)
@@ -1174,10 +1538,9 @@ def paged_prefill_chunk(tree, k_pool, v_pool, block_tables, chunk_ids,
     inside the row and to what the ring held, and only the row's last
     tokens, those the ring keeps, are written (``_paged_trunk``).
     """
-    tables, rings = _split_tables(cfg, block_tables)
+    tables, rings, state_rows = _split_tables(cfg, block_tables)
     T = chunk_ids.shape[1]
-    page = jax.tree_util.tree_leaves(k_pool)[0].shape[2]
-    C = tables.shape[1] * page
+    C = tables.shape[1] * _page_size(cfg, k_pool)
     x = tree["embed"][chunk_ids]  # [S, T, H]
     positions = start[:, None] + jnp.arange(T)[None, :]  # [S, T]
     valid_q = jnp.arange(T)[None, :] < chunk_lens[:, None]  # [S, T]
@@ -1193,7 +1556,9 @@ def paged_prefill_chunk(tree, k_pool, v_pool, block_tables, chunk_ids,
     x, k_pool, v_pool, stats = _paged_trunk(
         tree, k_pool, v_pool, x, cfg, tables=tables, rings=rings,
         positions=positions, write_positions=write_positions, mask=mask,
-        valid=valid_q, starts=start, lens=chunk_lens,
+        valid=valid_q, starts=start, lens=chunk_lens, state_rows=state_rows,
+        # a prompt's first chunk: its recurrent state starts from noughts
+        fresh=(start == 0) & (chunk_lens > 0),
     )
     with jax.named_scope("lm_head"):
         x = _rms(x, tree["final_norm"], cfg.norm_eps)

@@ -68,6 +68,11 @@ class MoEConfig:
     # chip holds experts ``[first_expert, first_expert + experts)`` of them
     router_width: int = 0
     first_expert: int = 0
+    # serving: a gated expert is ``down(silu(gate x) * up x)`` (three
+    # matrices), one that is not ``down(relu(up x)**2)`` (two, no ``wg``)
+    gated: bool = True
+    # the chosen experts' renormalised weights are multiplied by this
+    route_scale: float = 1.0
 
     def capacity(self, n_tokens: int) -> int:
         """Static per-expert token slots for an ``n_tokens`` group."""
@@ -263,6 +268,14 @@ def _grouped(x, w, rows_expert, group_sizes):
     return jax.lax.ragged_dot(x, w, group_sizes)
 
 
+def _activate(gate, up):
+    """An expert's hidden activation: ``silu(gate) * up`` where it has a
+    gate, ``relu(up) ** 2`` where it has none."""
+    if gate is None:
+        return jnp.square(jax.nn.relu(up))
+    return jax.nn.silu(gate) * up
+
+
 def _one_stack(w):
     """``[n, E, ...]`` (every layer's experts) as ``[n * E, ...]``: no copy."""
     return jax.tree_util.tree_map(lambda t: t.reshape((-1,) + t.shape[2:]), w)
@@ -273,7 +286,12 @@ def moe_serve(params, x: jnp.ndarray, cfg: MoEConfig, valid=None):
     pairs, experts_hit)``.
 
     Every token is routed over the whole router width (``params["router"]``
-    ``[H, router_width]`` f32, ``params["bias"]`` optional); of its
+    ``[H, router_width]`` f32, ``params["bias"]`` optional; the chosen
+    weights times ``cfg.route_scale``).  An expert is gated (``wg``, ``wu``,
+    ``wd``) or, with ``cfg.gated`` false, ``wd(relu(wu x) ** 2)``.  Where
+    ``params`` holds ``shared_up`` / ``shared_down``, that shared expert
+    (of the same ungated form; none is implemented beside gated experts)
+    is added for every token, real or padding.  Of a token's
     ``top_k`` (token, expert) pairs those that fall on the experts held
     here (``cfg.first_expert`` and the ``cfg.experts`` after it, the
     leading axis of ``wg`` / ``wu`` / ``wd``) are sorted by expert and go
@@ -307,6 +325,8 @@ def moe_serve(params, x: jnp.ndarray, cfg: MoEConfig, valid=None):
             precision=jax.lax.Precision.HIGHEST,
         )
         idx, weights = route(logits, cfg, params.get("bias"))
+        if cfg.route_scale != 1.0:
+            weights = weights * cfg.route_scale
         local = idx - cfg.first_expert
         here = (local >= 0) & (local < E)
         if valid is not None:
@@ -321,18 +341,17 @@ def moe_serve(params, x: jnp.ndarray, cfg: MoEConfig, valid=None):
         )
         xs = xt[order // K]  # [T*K, H], a token's row once per pair
     with jax.named_scope("moe.experts"):
-        wg, wu, wd = params["wg"], params["wu"], params["wd"]
+        wg, wu, wd = params.get("wg"), params["wu"], params["wd"]
         rows, sizes = rows_expert, group_sizes
         if "layer" in params:
-            layers = jax.tree_util.tree_leaves(wg)[0].shape[0]
+            layers = jax.tree_util.tree_leaves(wu)[0].shape[0]
             wg, wu, wd = _one_stack(wg), _one_stack(wu), _one_stack(wd)
             rows = params["layer"] * E + rows_expert
             sizes = (
                 jnp.zeros((layers, E), jnp.int32).at[params["layer"]].set(group_sizes)
             ).reshape(-1)
-        gate = _grouped(xs, wg, rows, sizes)
-        up = _grouped(xs, wu, rows, sizes)
-        out = _grouped(jax.nn.silu(gate) * up, wd, rows, sizes)
+        gate = _grouped(xs, wg, rows, sizes) if cfg.gated else None
+        out = _grouped(_activate(gate, _grouped(xs, wu, rows, sizes)), wd, rows, sizes)
         # rows past the groups are pairs held elsewhere: whatever the
         # grouped product left there is not read
         out = jnp.where((rows_expert < E)[:, None], out, 0)
@@ -342,6 +361,14 @@ def moe_serve(params, x: jnp.ndarray, cfg: MoEConfig, valid=None):
             "tkh,tk->th", out[back].reshape(T, K, H).astype(jnp.float32),
             jnp.where(here, weights, 0.0),
         )
+    if "shared_up" in params:
+        # the shared expert: every token takes it, whatever its routing,
+        # and every share of the layer computes it alike
+        if cfg.gated:
+            raise NotImplementedError("a shared expert beside gated experts")
+        with jax.named_scope("moe.shared"):
+            hidden = _activate(None, xt @ params["shared_up"])
+            y = y + (hidden @ params["shared_down"]).astype(jnp.float32)
     pairs = jnp.sum(here, dtype=jnp.int32)
     experts_hit = jnp.sum(group_sizes > 0, dtype=jnp.int32)
     return y.reshape(orig_shape).astype(x.dtype), pairs, experts_hit

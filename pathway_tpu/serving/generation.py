@@ -15,6 +15,15 @@ vLLM/Ragged-Paged-Attention serving shape instead (PAPERS.md):
   with live tokens.  Admission reserves a request's worst case up front:
   the pool can never OOM mid-generation; requests queue (bounded) at
   the edge instead.
+* **Recurrent state** — a Mamba-2 layer keeps no cache that grows: a slot
+  holds, a layer, the convolution's last columns and the scan's float32
+  state, of fixed size (``models/decoder.py::init_kv_pool``).  It is the
+  third kind of state in the one manager, beside the pages and the rings:
+  it lives in the same pools the programs carry and donate, belongs to the
+  slot from admission to release, and starts from noughts with the
+  prompt's first chunk, inside the program, whatever the slot's last
+  request or a step that ran ahead left there.  Padding rows and padding
+  tokens do not move it.
 * **Chunked prefill** — a prompt prefills in programs shaped by what is
   left of it (:func:`prefill_shape`): alone, as one row of the smallest
   rung of a short ladder of widths that covers it, so the weights are
@@ -270,7 +279,7 @@ class GenerationScheduler:
                 self.slots * self.pages_per_seq // 2, self.pages_per_seq
             ) + 1
         self.num_pages = n_pages
-        # two kinds of cache in one manager: the layers whose cache grows
+        # the kinds of cache in one manager: the layers whose cache grows
         # with the sequence take pages from the allocator and are counted
         # by the token; a window layer of a model of runs keeps a ring a
         # slot (``dec.uses_ring``), fixed here and counted by the slot
@@ -295,6 +304,9 @@ class GenerationScheduler:
             dec.ring_pages(windows.pop(), self.page_size) if windows else 0
         )
         self.ring_bytes_per_slot = dec.kv_ring_bytes_per_slot(self.cfg, self.page_size)
+        # the third kind: a Mamba-2 layer's recurrent state, a slot, fixed
+        self._ssm = self.cfg.ssm_layers > 0
+        self.ssm_bytes_per_slot = dec.ssm_state_bytes_per_slot(self.cfg)
         # slot i's ring: pages 1 + i * ring onwards of every window run's
         # pool, for as long as the scheduler lives
         self._ring_tables = 1 + np.arange(
@@ -331,7 +343,9 @@ class GenerationScheduler:
         # programs since the last step carried forward ride behind the
         # tokens, in the one array the tick syncs anyway
         self._counted = counted = self._hybrid or self.cfg.routed_layers > 0
-        self._no_stats = jnp.zeros((2,), jnp.int32)
+        # a program's counts: the routing's [pairs, experts_hit], and with
+        # Mamba-2 layers the tokens the scan advanced a state by
+        self._no_stats = jnp.zeros((2 + self._ssm,), jnp.int32)
         self._prefill_stats = self._no_stats
 
         def _sample(lg, key, temp, top_p, min_p, top_k=None):
@@ -458,13 +472,27 @@ class GenerationScheduler:
         hit_help = (
             "held experts that met a token, summed over routed layers and programs"
         )
-        # in the order they ride behind a decode step's tokens
-        self._m_moe = [
+        # in the order they ride behind a decode step's tokens: the step's
+        # counts, then the carried prefill programs'
+        decode_counts = [
             reg.counter("generate.moe.decode.pairs", pairs_help),
             reg.counter("generate.moe.decode.experts_hit", hit_help),
+        ]
+        prefill_counts = [
             reg.counter("generate.moe.prefill.pairs", pairs_help),
             reg.counter("generate.moe.prefill.experts_hit", hit_help),
         ]
+        if self._ssm:
+            ssm_help = (
+                "real tokens a Mamba-2 scan advanced a slot's state by, summed over rows"
+            )
+            decode_counts.append(reg.counter("generate.ssm.decode.tokens", ssm_help))
+            prefill_counts.append(reg.counter("generate.ssm.prefill.tokens", ssm_help))
+            self._m_ssm_resets = reg.counter(
+                "generate.ssm.state.resets",
+                "slots whose recurrent state a prompt's first chunk started from noughts",
+            )
+        self._m_counts = decode_counts + prefill_counts
         self._m_window_pages_released = reg.counter(
             "generate.kv.window.pages_released",
             "ring pages that held a token, a window layer, when their slot was released",
@@ -894,13 +922,19 @@ class GenerationScheduler:
             self._top_ks[i] = 0
             self._penalties[i] = 1.0
 
-    def _tables(self, block_tables: np.ndarray, lanes=slice(None)):
-        """The tables a paged program takes for the slots ``lanes``: their
-        block tables, and for a model of runs their rings beside them."""
+    def _tables(self, block_tables: np.ndarray, lanes=None):
+        """The tables a paged program takes for the slots ``lanes`` (a
+        decode step: every slot, row ``r`` slot ``r``): their block tables,
+        for a model of runs their rings beside them, and for a prefill
+        program of a model with recurrent state the slot each row is."""
         jnp = self._jnp
         if not self._hybrid:
             return jnp.asarray(block_tables)
-        return jnp.asarray(block_tables), jnp.asarray(self._ring_tables[lanes])
+        rings = self._ring_tables if lanes is None else self._ring_tables[lanes]
+        tables = jnp.asarray(block_tables), jnp.asarray(rings)
+        if self._ssm and lanes is not None:
+            tables += (jnp.asarray(lanes, jnp.int32),)
+        return tables
 
     def _ring_pages_in_use(self) -> int:
         """Ring pages that hold a token, a window layer: a slot's ring
@@ -980,6 +1014,8 @@ class GenerationScheduler:
                 ids[r, :n] = slot.req.prompt_ids[done:done + n]
                 chunk_lens[r] = n
                 starts[r] = done
+                if self._ssm and done == 0:
+                    self._m_ssm_resets.inc()  # the program starts it from noughts
                 chunked.append(slot)
                 slot.seq_len = done + n
                 self._seq_lens[i] = slot.seq_len
@@ -1092,9 +1128,9 @@ class GenerationScheduler:
             # behind the slots' tokens: this step's and the carried prefill
             # programs' [pairs, experts_hit]
             counts = [int(n) for n in htok[self.slots:]]
-            for counter, n in zip(self._m_moe, counts):
+            for counter, n in zip(self._m_counts, counts):
                 counter.inc(n)
-            prefill_pairs = counts[2]
+            prefill_pairs = counts[len(counts) // 2]
         eos = self.lm.eos_id
         produced = 0
         wasted = 0
@@ -1195,6 +1231,9 @@ class GenerationScheduler:
                 ),
                 # what the dense slots x max_cache layout would hold resident
                 "generate.kv.bytes.dense": float(self.dense_kv_bytes),
+                # recurrent state: a taken slot's is whole, whatever its length
+                "generate.ssm.state.slots": float(active if self._ssm else 0),
+                "generate.ssm.state.bytes": float(active * self.ssm_bytes_per_slot),
                 # sustained decode throughput over the last 5 s
                 "generate.tokens_per_s": (
                     sum(n for _, n in window) / span if span > 0 else 0.0
@@ -1222,6 +1261,8 @@ class GenerationScheduler:
                 "kv_bytes_peak": self.allocator.peak_bytes
                 + self._peak_active * self.ring_bytes_per_slot,
                 "kv_bytes_dense": self.dense_kv_bytes,
+                "ssm_state_bytes_per_slot": self.ssm_bytes_per_slot,
+                "ssm_state_bytes_live": active * self.ssm_bytes_per_slot,
                 "tokens_total": self._tokens_total,
                 "tick_failures": self._tick_failures,
                 "last_tick_error": self._last_tick_error,

@@ -113,12 +113,15 @@ def lower_program(sched, which: str, tree, k_pool, v_pool, *, width: int, rows=N
     array = array or jnp.zeros
     S = sched.slots
 
-    def tables(n):
+    def tables(n, prefill=False):
         bt = array((n, table_width), jnp.int32)
-        return (bt, array((n, sched.ring_pages), jnp.int32)) if sched._hybrid else bt
+        if not sched._hybrid:
+            return bt
+        slot_of_row = (array((n,), jnp.int32),) if prefill and sched._ssm else ()
+        return (bt, array((n, sched.ring_pages), jnp.int32), *slot_of_row)
 
     logits = array((S, sched.cfg.vocab_size), jnp.float32)
-    carried = (array((2,), jnp.int32),) if sched._counted else ()
+    carried = (array(sched._no_stats.shape, jnp.int32),) if sched._counted else ()
     if which == "decode":
         f32 = array((S,), jnp.float32)
         active = (array((S,), jnp.bool_),) if sched._counted else ()
@@ -129,7 +132,7 @@ def lower_program(sched, which: str, tree, k_pool, v_pool, *, width: int, rows=N
     R = S if rows is None else rows
     lens = array((R,), jnp.int32)
     return sched._prefill_fn.lower(
-        tree, k_pool, v_pool, tables(R), array((R, width), jnp.int32), lens, lens,
+        tree, k_pool, v_pool, tables(R, prefill=True), array((R, width), jnp.int32), lens, lens,
         logits, lens, array((R,), jnp.bool_), *carried,
     )
 

@@ -49,11 +49,11 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture(scope="module")
-def programs(one_chip):
-    """``{"decode": text, "prefill": text}`` and the shapes the assertions
-    are about, for the Mistral configuration the benchmark serves."""
-    with open(os.path.join(ROOT, "chipbench", "configs", "mistral7b-bge-rag.json")) as f:
+def _compiled(one_chip, config: str):
+    """``(cfg, tree shapes, k_pool shapes, {"decode": text, "prefill":
+    text})`` of the scheduler's two programs for a configuration the
+    benchmark serves, compiled for the described chip."""
+    with open(os.path.join(ROOT, "chipbench", "configs", f"{config}.json")) as f:
         hf = {k: v for k, v in json.load(f).items() if k != "chipbench"}
     cfg = dec.decoder_config_from_hf(hf)
 
@@ -85,9 +85,17 @@ def programs(one_chip):
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
         sched.shutdown()
+    return cfg, tree, kp, {"decode": decode, "prefill": prefill}
+
+
+@pytest.fixture(scope="module")
+def programs(one_chip):
+    """The two programs' texts and the shapes the assertions are about,
+    for the Mistral configuration the benchmark serves."""
+    cfg, tree, kp, texts = _compiled(one_chip, "mistral7b-bge-rag")
     D, H = cfg.head_dim, cfg.hidden
     return {
-        "decode": decode, "prefill": prefill,
+        **texts,
         "pool": kp.shape,
         "projections": {H * cfg.heads * D, H * cfg.kv_heads * D},
         "first_pool": len(jax.tree_util.tree_leaves(tree)),
@@ -139,3 +147,40 @@ def test_mistral_step_on_the_chip_reads_weights_and_pools_in_place(programs, whi
     # layers' loop as they are
     first = programs["first_pool"]
     assert {first, first + 1} <= aliased_parameters(text)
+
+
+@pytest.fixture(scope="module")
+def nemotron_programs(one_chip):
+    cfg, tree, kp, texts = _compiled(one_chip, "nemotron-3-nano-bge-rag")
+    return {**texts, "cfg": cfg, "tree": tree, "pools": kp}
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_nemotron_step_on_the_chip_copies_no_expert_stack_and_keeps_state_in_place(
+        nemotron_programs, which):
+    """Nemotron 3 Nano's share at published widths (16 runs of one layer;
+    64 experts of 1,856 columns a routed layer, stored as 1,920): the
+    grouped product reads a layer's experts where they lie (given 1,856
+    columns the compiler padded a copy of all 64, 638 MB a layer a step),
+    every run's pair of the pools, the Mamba-2 layers' recurrent state
+    among them, goes in and comes out in one buffer, and no loop is left
+    of runs that are one layer long (a scan of one step is inlined)."""
+    text, cfg = nemotron_programs[which], nemotron_programs["cfg"]
+    assert text.startswith(f"HloModule jit__{which}")
+    scheduled = _scheduled(text)
+    assert len(scheduled) > 100
+    stack = cfg.experts * cfg.hidden * dec._lanes(1856)
+    state = int(np.prod(nemotron_programs["pools"][0].shape))  # a run's convolution tails
+    moved = [
+        (name, op, n) for name, n, op in scheduled
+        if n >= stack and _moves_data_only(name, op)
+    ]
+    assert not moved, moved
+    assert state == 8 * 3 * 6144
+    # the only loops left carry the scan's state from chunk to chunk
+    assert len(re.findall(r" while\(", text)) == (0 if which == "decode" else cfg.ssm_layers)
+    first = len(jax.tree_util.tree_leaves(nemotron_programs["tree"]))
+    carrying = [r for r, (kind, _n) in enumerate(cfg.runs) if kind.part != "ffn"]
+    assert len(carrying) == 9  # 7 Mamba-2 runs' state, 2 attention runs' pages
+    pairs = {first + r for r in carrying} | {first + len(cfg.runs) + r for r in carrying}
+    assert pairs <= aliased_parameters(text)
