@@ -365,6 +365,12 @@ METRICS: dict[str, tuple[str, str]] = {
         "counter", "held experts that met at least one token, summed over "
         "the routed layers of every prefill program (divisor: generate."
         "prefill.chunks x routed layers)"),
+    "generate.moe.decode.steps_in_place": (
+        "counter", "decode steps enqueued whose routed layers loop over the "
+        "held experts that met a token and read each where it lies (a "
+        "program of few rows, parallel/moe.py::serves_in_place; a static "
+        "fact of the step program): equal to generate.decode.steps on a "
+        "model with routed layers, 0 on one without"),
     "generate.ssm.state.resets": (
         "counter", "slots whose recurrent state (a model with Mamba-2 "
         "layers) a prompt's first chunk started from noughts: over "

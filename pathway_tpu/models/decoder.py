@@ -97,8 +97,9 @@ class DecoderConfig:
     dtype: Any = jnp.bfloat16
     # experts > 0 switches the MLP to sparse MoE: per-layer router +
     # stacked expert SwiGLU weights (parallel/moe.py: the capacity-based
-    # GShard dispatch for training, the sorted grouped product for
-    # serving; the expert axis is shardable over the mesh).  It counts the
+    # GShard dispatch for training; for serving the sorted grouped product
+    # where a program has many rows and a loop over the experts its rows
+    # met where it has few; the expert axis is shardable over the mesh).  It counts the
     # experts HELD here: the router is ``experts_published`` wide where
     # that is set (one chip's share of an expert-parallel layer) and this
     # chip's experts start at ``experts_first``
@@ -210,7 +211,8 @@ PRESETS: dict[str, DecoderConfig] = {
     # the MoE sibling of the Mistral family the reference's Adaptive RAG
     # template serves (block-sparse FFN, 8 experts, top-2 routing), served
     # through parallel/moe.py::moe_serve (softmax scores, all 8 experts
-    # held): sorted pairs, one grouped product
+    # held): prefill sorts its pairs for one grouped product, a decode
+    # step loops over the experts its rows met
     "mixtral-8x7b-instruct": DecoderConfig(
         rope_theta=1e6, experts=8, experts_top_k=2, max_len=8192,
     ),

@@ -15,10 +15,12 @@ Design points, all MXU/XLA-motivated:
   fixed ``capacity`` of token slots per batch; overflow tokens are dropped
   from that expert (their residual stream passes through unchanged).
   Static shapes keep the whole layer one compiled program.
-* **No capacity (serving, ``moe_serve``).**  The (token, expert) pairs
-  that fall on the experts this chip holds are sorted by expert and go
-  through one grouped product (``jax.lax.ragged_dot``): no pair is
-  dropped and no expert is multiplied by a token it was not given.
+* **No capacity (serving, ``moe_serve``).**  No (token, expert) pair that
+  falls on the experts this chip holds is dropped.  A program of many
+  rows (prefill) sorts them by expert for one grouped product
+  (``jax.lax.ragged_dot``): no expert is multiplied by a token it was not
+  given.  A program of few rows (a decode step) loops over the experts
+  its rows met and reads each once, where it lies.
 * **Top-k routing with renormalised gates** (k=2 default, the
   Mixtral/GShard setting): the combine weights of the selected experts
   are renormalised to sum to 1, so with identical experts the layer
@@ -281,6 +283,105 @@ def _one_stack(w):
     return jax.tree_util.tree_map(lambda t: t.reshape((-1,) + t.shape[2:]), w)
 
 
+# A ``[T, H] @ [H, F]`` product in bfloat16 reads H * F * 2 bytes and
+# makes 2 * T * H * F operations: on a v5e (197 TFLOP/s, 819 GB/s) the
+# read is the longer of the two up to T = 197e12 / 819e9 = 240 rows.  Well
+# under that, multiplying EVERY row by an expert costs that expert's read
+# and nothing more, so a program of at most this many rows loops over the
+# experts it met; one of more rows sorts its pairs for the grouped product.
+IN_PLACE_ROWS = 128
+
+
+def serves_in_place(rows: int) -> bool:
+    """Whether a serving program over ``rows`` tokens (a static fact of
+    the program: slots x width) multiplies its held pairs by a loop over
+    the experts they met (:func:`moe_serve`), and not by the grouped
+    product."""
+    return rows <= IN_PLACE_ROWS
+
+
+def _expert(w, e):
+    """Expert ``e``'s matrix of the stack ``w [E, A, B]`` (a float weight
+    or an int8 weight-only pair), read where it lies."""
+    return jax.tree_util.tree_map(
+        lambda t: jax.lax.dynamic_index_in_dim(t, e, 0, keepdims=False), w
+    )
+
+
+def _times(x, w, out_dtype=None):
+    """``x [T, A] @ w [A, B]``, ``w`` one expert's float weight or int8
+    pair (``s [1, B]``), accumulated in float32."""
+    if isinstance(w, dict) and "q" in w:
+        out = jnp.matmul(x, w["q"].astype(x.dtype), preferred_element_type=out_dtype)
+        return out * w["s"].astype(out.dtype)
+    return jnp.matmul(x, w, preferred_element_type=out_dtype)
+
+
+def _experts_in_place(xt, wg, wu, wd, layer, here, local, weights, group_sizes):
+    """The held pairs' weighted sum ``[T, H]`` float32 by a loop whose
+    trip count is the number of held experts that met a token: an
+    iteration reads one such expert where it lies in the stacks (``[E,
+    ...]``, or ``[n * E, ...]`` and this layer's from ``layer * E``),
+    multiplies all ``T`` rows by it and adds the result under each row's
+    weight for it, nought where the row did not choose it.  Each hit
+    expert is read once whatever the rows that chose it; no sort, no
+    kernel call."""
+    T, H = xt.shape
+    E = group_sizes.shape[0]
+    first = 0 if layer is None else layer * E
+    hit = group_sizes > 0
+    nth = jnp.cumsum(hit) - 1  # a hit expert's place among the hit
+
+    def one(i, acc):
+        e = jnp.argmax(hit & (nth == i)).astype(jnp.int32)
+        at = first + e
+        gate = None if wg is None else _times(xt, _expert(wg, at))
+        hidden = _activate(gate, _times(xt, _expert(wu, at)))
+        out = _times(hidden, _expert(wd, at), jnp.float32)
+        mine = here & (local == e)  # [T, K]
+        weight = jnp.sum(jnp.where(mine, weights, 0.0), axis=-1, keepdims=True)
+        return acc + jnp.where(mine.any(-1, keepdims=True), out * weight, 0.0)
+
+    return jax.lax.fori_loop(
+        0, jnp.sum(hit, dtype=jnp.int32), one, jnp.zeros((T, H), jnp.float32)
+    )
+
+
+def _sorted_pairs(xt, flat, top_k: int):
+    """The ``T x top_k`` pairs sorted by expert (``flat``: a pair's held
+    expert, or E where it is held elsewhere: those sort behind every held
+    expert's rows): ``(order, the sorted rows' experts, xs [T * top_k, H]``
+    a token's row once per pair)."""
+    order = jnp.argsort(flat)
+    return order, flat[order], xt[order // top_k]
+
+
+def _experts_grouped(sorted_pairs, wg, wu, wd, layer, here, weights, group_sizes):
+    """The same sum of the pairs sorted by expert through one grouped
+    product a matrix, over the whole stacks with every other layer's
+    groups empty."""
+    order, rows_expert, xs = sorted_pairs
+    (T, K), E, H = here.shape, group_sizes.shape[0], xs.shape[-1]
+    rows, sizes = rows_expert, group_sizes
+    if layer is not None:
+        layers = jax.tree_util.tree_leaves(wu)[0].shape[0] // E
+        rows = layer * E + rows_expert
+        sizes = (
+            jnp.zeros((layers, E), jnp.int32).at[layer].set(group_sizes)
+        ).reshape(-1)
+    gate = None if wg is None else _grouped(xs, wg, rows, sizes)
+    out = _grouped(_activate(gate, _grouped(xs, wu, rows, sizes)), wd, rows, sizes)
+    # rows past the groups are pairs held elsewhere: whatever the
+    # grouped product left there is not read
+    out = jnp.where((rows_expert < E)[:, None], out, 0)
+    back = jnp.argsort(order)  # undo the sort: row t*K + k again
+    # the weighted sum in float32, as the weights are
+    return jnp.einsum(
+        "tkh,tk->th", out[back].reshape(T, K, H).astype(jnp.float32),
+        jnp.where(here, weights, 0.0),
+    )
+
+
 def moe_serve(params, x: jnp.ndarray, cfg: MoEConfig, valid=None):
     """MoE feed-forward for serving: ``x [..., H]`` → ``(y [..., H],
     pairs, experts_hit)``.
@@ -294,9 +395,9 @@ def moe_serve(params, x: jnp.ndarray, cfg: MoEConfig, valid=None):
     is added for every token, real or padding.  Of a token's
     ``top_k`` (token, expert) pairs those that fall on the experts held
     here (``cfg.first_expert`` and the ``cfg.experts`` after it, the
-    leading axis of ``wg`` / ``wu`` / ``wd``) are sorted by expert and go
-    through one grouped product, then are summed back per token with the
-    router's weights.  No capacity, no dropped pair; what the experts held
+    leading axis of ``wg`` / ``wu`` / ``wd``) are multiplied by their
+    experts and summed back per token, in float32, with the router's
+    weights.  No capacity, no dropped pair; what the experts held
     elsewhere would add is left out (on one chip of an expert-parallel
     layer that partial sum is the layer's result here, and with every
     expert held it is the whole).  ``valid [...]`` marks real tokens:
@@ -304,18 +405,39 @@ def moe_serve(params, x: jnp.ndarray, cfg: MoEConfig, valid=None):
     ``experts_hit`` the held experts that met at least one token (int32
     scalars, for the scheduler's counters).
 
+    Two paths share the router, the choice, the counts, the shared expert
+    and the activation, and nothing else, because few rows and many want
+    opposite things.  Which one a program takes is a fact of its shape
+    (:func:`serves_in_place`: the rows ``T`` = the product of ``x``'s
+    leading axes, at most ``IN_PLACE_ROWS``), no option and no model's name:
+
+    * a decode step (``T`` = the scheduler's slots, 8) and any other
+      program of few rows loops over the held experts that met a token
+      (a dynamic trip count, ``experts_hit``), reads each where it lies in
+      the stack and multiplies all ``T`` rows by it: the products are
+      bound by the expert's read while ``T`` is small, so a step pays for
+      the ≈ 1–3 experts it met and for no sort and no kernel call (the
+      grouped product costs 0.1–0.3 ms a call on a v5e whatever the rows);
+    * a prefill program (``[1, 512]``, ``[1, 256]``, ``[8, 32]``: ``T`` ≥
+      256, thousands of pairs over every held expert) sorts its pairs by
+      expert and sends them through one grouped product a matrix
+      (``jax.lax.ragged_dot``): no expert is multiplied by a token it was
+      not given.
+
     Inside a scan over stacked layers ``wg`` / ``wu`` / ``wd`` may be the
     whole stacks ``[n, E, ...]`` with ``params["layer"]`` the layer's index
-    in them: the grouped product then runs over all ``n * E`` experts with
-    every other layer's groups empty.  It cannot read a layer's experts
-    through the scan's own slice of the stack: the product is a custom
-    call on the chip, and XLA copies all of a layer's experts for it, hit
-    or not, every step (three 537 MB copies a layer at MiMo-V2.5's widths).
+    in them: the loop reads expert ``layer * E + e``, and the grouped
+    product runs over all ``n * E`` experts with every other layer's
+    groups empty.  Neither reads a layer's experts through the scan's own
+    slice of the stack: the grouped product is a custom call on the chip,
+    and XLA copies all of a layer's experts for it, hit or not, every step
+    (three 537 MB copies a layer at MiMo-V2.5's widths).
     """
     orig_shape = x.shape
     H = orig_shape[-1]
     xt = x.reshape(-1, H)
     T, K, E = xt.shape[0], cfg.top_k, cfg.experts
+    in_place = serves_in_place(T)
     with jax.named_scope("moe.route"):
         # float32 all the way: at the default precision the chip would
         # multiply in bfloat16, and a choice between two experts whose
@@ -331,36 +453,25 @@ def moe_serve(params, x: jnp.ndarray, cfg: MoEConfig, valid=None):
         here = (local >= 0) & (local < E)
         if valid is not None:
             here = here & valid.reshape(-1)[:, None]
-        # pairs held elsewhere sort behind every held expert's rows
         flat = jnp.where(here, local, E).reshape(-1)
-        order = jnp.argsort(flat)
-        rows_expert = flat[order]
         group_sizes = jnp.sum(
             flat[:, None] == jnp.arange(E, dtype=flat.dtype)[None, :], axis=0,
             dtype=jnp.int32,
         )
-        xs = xt[order // K]  # [T*K, H], a token's row once per pair
+        sorted_pairs = None if in_place else _sorted_pairs(xt, flat, K)
     with jax.named_scope("moe.experts"):
-        wg, wu, wd = params.get("wg"), params["wu"], params["wd"]
-        rows, sizes = rows_expert, group_sizes
-        if "layer" in params:
-            layers = jax.tree_util.tree_leaves(wu)[0].shape[0]
+        wg, wu, wd = params.get("wg") if cfg.gated else None, params["wu"], params["wd"]
+        layer = params.get("layer")
+        if layer is not None:
             wg, wu, wd = _one_stack(wg), _one_stack(wu), _one_stack(wd)
-            rows = params["layer"] * E + rows_expert
-            sizes = (
-                jnp.zeros((layers, E), jnp.int32).at[params["layer"]].set(group_sizes)
-            ).reshape(-1)
-        gate = _grouped(xs, wg, rows, sizes) if cfg.gated else None
-        out = _grouped(_activate(gate, _grouped(xs, wu, rows, sizes)), wd, rows, sizes)
-        # rows past the groups are pairs held elsewhere: whatever the
-        # grouped product left there is not read
-        out = jnp.where((rows_expert < E)[:, None], out, 0)
-        back = jnp.argsort(order)  # undo the sort: row t*K + k again
-        # the weighted sum in float32, as the weights are
-        y = jnp.einsum(
-            "tkh,tk->th", out[back].reshape(T, K, H).astype(jnp.float32),
-            jnp.where(here, weights, 0.0),
-        )
+        if in_place:
+            y = _experts_in_place(
+                xt, wg, wu, wd, layer, here, local, weights, group_sizes
+            )
+        else:
+            y = _experts_grouped(
+                sorted_pairs, wg, wu, wd, layer, here, weights, group_sizes
+            )
     if "shared_up" in params:
         # the shared expert: every token takes it, whatever its routing,
         # and every share of the layer computes it alike

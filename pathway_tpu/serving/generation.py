@@ -482,6 +482,16 @@ class GenerationScheduler:
             reg.counter("generate.moe.prefill.pairs", pairs_help),
             reg.counter("generate.moe.prefill.experts_hit", hit_help),
         ]
+        self._m_steps_in_place = reg.counter(
+            "generate.moe.decode.steps_in_place",
+            "decode steps whose routed layers loop over the experts they met",
+        )
+        # a static fact of the step program: its rows are the slots
+        self._moe_in_place = False
+        if self.cfg.routed_layers > 0:
+            from pathway_tpu.parallel.moe import serves_in_place
+
+            self._moe_in_place = serves_in_place(self.slots)
         if self._ssm:
             ssm_help = (
                 "real tokens a Mamba-2 scan advanced a slot's state by, summed over rows"
@@ -1105,6 +1115,8 @@ class GenerationScheduler:
             )
         tok.copy_to_host_async()  # on its way before the host asks for it
         self._m_decode_steps.inc()
+        if self._moe_in_place:
+            self._m_steps_in_place.inc()
         return _Step(tok, [(i, slot.req) for i, slot in taken], self._programs)
 
     def _deliver(self, step: _Step) -> None:

@@ -182,3 +182,165 @@ def test_serving_padding_takes_no_expert():
     assert np.all(np.asarray(y)[1, 2:] == 0.0)
     y_all, _, _ = moe_serve(params, x, cfg)
     np.testing.assert_allclose(np.asarray(y)[0], np.asarray(y_all)[0], atol=1e-6)
+
+
+# --- the two paths of ``moe_serve``: a loop over the hit experts where the
+# program has few rows, the grouped product where it has many ---------------
+
+def _serve_params(cfg, *, seed, dtype=jnp.float32, shared=0, bias=False, layers=0,
+                  int8=False):
+    """``moe_serve``'s params for ``cfg``: a router over the whole width,
+    the held experts' matrices (stacked ``[layers, E, ...]`` where
+    ``layers``; int8 pairs where ``int8``), a correction bias, a shared
+    expert."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 7)
+    E, H, F = cfg.experts, cfg.hidden, cfg.intermediate
+    lead = (layers, E) if layers else (E,)
+
+    def normal(key, shape, fan_in):
+        return (jax.random.normal(key, shape, jnp.float32) / np.sqrt(fan_in)).astype(dtype)
+
+    def stored(w):
+        if not int8:
+            return w
+        s = jnp.max(jnp.abs(w), axis=-2, keepdims=True) / 127.0
+        return {"q": jnp.round(w / s).astype(jnp.int8), "s": s.astype(jnp.float32)}
+
+    params = {
+        "router": jax.random.normal(keys[0], (H, cfg.router_width or E), jnp.float32),
+        "wu": stored(normal(keys[2], lead + (H, F), H)),
+        "wd": stored(normal(keys[3], lead + (F, H), F)),
+    }
+    if cfg.gated:
+        params["wg"] = stored(normal(keys[1], lead + (H, F), H))
+    if bias:
+        params["bias"] = 0.3 * jax.random.normal(keys[4], (cfg.router_width or E,))
+    if shared:
+        params["shared_up"] = normal(keys[5], (H, shared), H)
+        params["shared_down"] = normal(keys[6], (shared, H), shared)
+    if layers:
+        params["layer"] = jnp.int32(layers - 2)
+    return params
+
+
+def _served(monkeypatch, rows_in_place, params, x, cfg, valid=None):
+    """``moe_serve`` jitted with the rule's constant set for the test, and
+    the path its program took, read from the program."""
+    from pathway_tpu.parallel import moe
+
+    monkeypatch.setattr(moe, "IN_PLACE_ROWS", rows_in_place)
+    fn = jax.jit(lambda p, x: moe_serve(p, x, cfg, valid))
+    text = str(jax.make_jaxpr(fn)(params, x))
+    assert ("ragged_dot" in text) != ("while[" in text)
+    y, pairs, hit = fn(params, x)
+    return np.asarray(y, np.float32), int(pairs), int(hit), "ragged_dot" not in text
+
+
+_WIDE = dict(hidden=16, experts=4, intermediate=24, router_width=16, first_expert=8)
+
+
+def _elsewhere_only(params, cfg):
+    # every token chooses among experts 0-3, none of those held here (8-11)
+    bias = jnp.where(jnp.arange(cfg.router_width) < 4, 100.0, 0.0)
+    return {**params, "bias": bias}
+
+
+def _one_expert(params, cfg):
+    # both rows choose held expert 2 (the router's column 10), whatever they hold
+    return {**params, "bias": jnp.where(jnp.arange(cfg.router_width) == 10, 100.0, 0.0)}
+
+
+SERVE_CASES = {
+    # name: (cfg, params' keywords, rows, valid, what is done to params, (pairs, hit) where known)
+    "gated": (MoEConfig(hidden=16, experts=4, intermediate=24, top_k=2), {}, 24, None, None, (48, None)),
+    "ungated-shared-sigmoid-bias-scaled": (
+        MoEConfig(**_WIDE, top_k=6, scoring="sigmoid", gated=False, route_scale=2.5),
+        dict(shared=32, bias=True), 8, None, None, None,
+    ),
+    "held-elsewhere-only-shared": (
+        MoEConfig(**_WIDE, top_k=3, scoring="sigmoid", gated=False),
+        dict(shared=32), 8, None, _elsewhere_only, (0, 0),
+    ),
+    "held-elsewhere-only-noughts": (
+        MoEConfig(**_WIDE, top_k=3, scoring="sigmoid"), {}, 8, None, _elsewhere_only, (0, 0),
+    ),
+    "every-row-invalid": (
+        MoEConfig(hidden=16, experts=4, intermediate=24, top_k=2), {}, 8,
+        np.zeros(8, bool), None, (0, 0),
+    ),
+    "some-rows-invalid": (
+        MoEConfig(**_WIDE, top_k=6, gated=False), dict(shared=32), 8,
+        np.arange(8) % 3 == 0, None, None,
+    ),
+    "two-rows-one-expert": (
+        MoEConfig(**_WIDE, top_k=1, scoring="sigmoid"), {}, 2, None, _one_expert, (2, 1),
+    ),
+    "stacked-with-layer": (
+        MoEConfig(**_WIDE, top_k=6, gated=False), dict(layers=3, shared=32), 8, None, None, None,
+    ),
+    "stacked-gated": (
+        MoEConfig(hidden=16, experts=4, intermediate=24, top_k=2), dict(layers=3), 8, None, None, (16, None),
+    ),
+    "int8-pair": (
+        MoEConfig(hidden=16, experts=4, intermediate=24, top_k=2), dict(int8=True), 8, None, None, (16, None),
+    ),
+    "int8-pair-stacked-ungated": (
+        MoEConfig(**_WIDE, top_k=6, gated=False), dict(int8=True, layers=3), 8, None, None, None,
+    ),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(SERVE_CASES))
+def test_serving_loop_over_hit_experts_equals_grouped_product(monkeypatch, case, dtype):
+    """The same inputs through both paths: equal results (float32 to 1e-5;
+    bfloat16 within the rounding of the hidden activation and the output),
+    ``pairs`` and ``experts_hit`` equal exactly."""
+    cfg, keywords, rows, valid, change, counts = SERVE_CASES[case]
+    dtype = jnp.dtype(dtype)
+    params = _serve_params(cfg, seed=len(case), dtype=dtype, **keywords)
+    if change is not None:
+        params = change(params, cfg)
+    x = jax.random.normal(jax.random.PRNGKey(7), (rows, cfg.hidden), jnp.float32).astype(dtype)
+    valid = None if valid is None else jnp.asarray(valid)
+    loop = _served(monkeypatch, 10**9, params, x, cfg, valid)
+    grouped = _served(monkeypatch, 0, params, x, cfg, valid)
+    assert loop[3] and not grouped[3]  # each took its path
+    assert loop[1:3] == grouped[1:3]
+    tol = 1e-5 if dtype == jnp.float32 else 0.05
+    np.testing.assert_allclose(loop[0], grouped[0], rtol=tol, atol=tol)
+    if counts is not None:
+        pairs, hit = counts
+        assert loop[1] == pairs and (hit is None or loop[2] == hit)
+    if counts == (0, 0):
+        # a trip count of nought: the shared expert's alone, or noughts
+        if "shared_up" in params:
+            h = jnp.square(jax.nn.relu(x @ params["shared_up"]))
+            want = np.asarray((h @ params["shared_down"]).astype(jnp.float32))
+            np.testing.assert_allclose(loop[0], want, rtol=tol, atol=tol)
+            assert np.abs(want).max() > 0.1
+        else:
+            assert np.all(loop[0] == 0.0)
+    elif valid is not None:
+        assert np.abs(loop[0][np.asarray(valid)]).max() > 0.01
+
+
+def test_serving_path_is_chosen_by_the_rows_alone(monkeypatch):
+    """128 rows loop, 129 sort: the rule's two sides give equal results on
+    the rows they share, and a decode step's 8 rows are far inside it."""
+    from pathway_tpu.parallel import moe
+
+    assert moe.IN_PLACE_ROWS == 128
+    assert moe.serves_in_place(8) and moe.serves_in_place(128)
+    assert not moe.serves_in_place(129) and not moe.serves_in_place(256)
+    cfg = MoEConfig(**_WIDE, top_k=6, gated=False, route_scale=2.5)
+    params = _serve_params(cfg, seed=3, shared=32)
+    x = jax.random.normal(jax.random.PRNGKey(5), (129, 16), jnp.float32)
+    few = _served(monkeypatch, moe.IN_PLACE_ROWS, params, x[:128], cfg)
+    many = _served(monkeypatch, moe.IN_PLACE_ROWS, params, x, cfg)
+    assert few[3] and not many[3]
+    np.testing.assert_allclose(few[0], many[0][:128], rtol=1e-5, atol=1e-5)
+    assert 0 < few[1] <= many[1] <= few[1] + 6 and few[2] == many[2] == 4
+    # leading axes multiply: [8, 16] rows take the loop, [8, 32] the sort
+    for shape, in_place in (((8, 16, 16), True), ((8, 32, 16), False), ((8, 1, 16), True)):
+        assert _served(monkeypatch, moe.IN_PLACE_ROWS, params, jnp.ones(shape), cfg)[3] is in_place

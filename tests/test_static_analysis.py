@@ -118,11 +118,13 @@ def test_corpus_determinism():
 
 
 def test_package_tree_is_clean_within_budget():
-    t0 = time.monotonic()
+    # the process's own CPU time: beside five other xdist workers the wall
+    # clock measures the machine, not the analyzer
+    t0 = time.process_time()
     report = run_lint(
         [os.path.join(REPO, "pathway_tpu"), os.path.join(REPO, "tests")]
     )
-    elapsed = time.monotonic() - t0
+    elapsed = time.process_time() - t0
     assert not report.findings, (
         "unsuppressed lint findings in the package tree:\n"
         + report_to_text(report)
@@ -141,7 +143,7 @@ def test_package_tree_is_clean_within_budget():
     assert len(report.suppressed) >= len(report.suppressions)
     # the tier-1 budget: the analyzer must never dominate the gate
     assert elapsed < 20.0, (
-        f"lint over the full tree took {elapsed:.1f}s (budget 20s) — "
+        f"lint over the full tree took {elapsed:.1f}s of CPU (budget 20s) — "
         "profile the call-graph passes before landing this"
     )
 

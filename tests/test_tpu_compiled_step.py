@@ -3,12 +3,13 @@
 The TPU's compiler is installed here and compiles for a v5e that is
 described, not attached: nothing runs, no weight is allocated, and what
 comes back is the optimized program the chip would run.  These tests read
-it at the sizes of the benchmark's ``mistral7b-bge-rag`` configuration
-(24 layers at published widths, 8 slots, 257 pages of 16 tokens) and hold
-what no CPU compile can show: that a step reads each weight where it lies
-and touches of the KV pools only what its tables name.  The layouts that
-matter (which product wants which operand tiled how) exist on this
-backend alone.
+it at the sizes of the benchmark's three configurations
+(``mistral7b-bge-rag``: 24 layers at published widths; ``mimo-v2.5-bge-rag``
+and ``nemotron-3-nano-bge-rag``: one chip's share; 8 slots, 257 pages of 16
+tokens) and hold what no CPU compile can show: that a step reads each
+weight where it lies, an expert among them, and touches of the KV pools
+only what its tables name.  The layouts that matter (which product wants
+which operand tiled how) exist on this backend alone.
 
 All of them live in this one file, and the topology is described inside a
 fixture: one process at a time may load the TPU's library, so every
@@ -155,32 +156,90 @@ def nemotron_programs(one_chip):
     return {**texts, "cfg": cfg, "tree": tree, "pools": kp}
 
 
+def _grouped_products(text: str) -> int:
+    """The grouped products (``jax.lax.ragged_dot``: a custom call of the
+    chip's compiler, ``%ragged-dot-none.N``) a compiled program holds."""
+    return len(re.findall(r"^\s*%ragged-dot-none[\w.\-]* = ", text, flags=re.M))
+
+
+def _loops(text: str) -> int:
+    return len(re.findall(r" while\(", text))
+
+
+def _moved_whole(scheduled, least: int):
+    """The scheduled instructions of ``least`` elements or more that only
+    move data."""
+    return [
+        (name, op, n) for name, n, op in scheduled
+        if n >= least and _moves_data_only(name, op)
+    ]
+
+
 @pytest.mark.parametrize("which", ["decode", "prefill"])
-def test_nemotron_step_on_the_chip_copies_no_expert_stack_and_keeps_state_in_place(
+def test_nemotron_step_on_the_chip_copies_no_expert_and_keeps_state_in_place(
         nemotron_programs, which):
     """Nemotron 3 Nano's share at published widths (16 runs of one layer;
-    64 experts of 1,856 columns a routed layer, stored as 1,920): the
-    grouped product reads a layer's experts where they lie (given 1,856
-    columns the compiler padded a copy of all 64, 638 MB a layer a step),
-    every run's pair of the pools, the Mamba-2 layers' recurrent state
-    among them, goes in and comes out in one buffer, and no loop is left
-    of runs that are one layer long (a scan of one step is inlined)."""
+    64 experts of 1,856 columns a routed layer, stored as 1,920).  The
+    decode step (8 rows) holds no grouped product: a routed layer loops
+    over the experts it met and reads each where it lies, so nothing the
+    size of ONE expert is copied and the only loops left are those seven
+    (a scan of one step, a run that is one layer long, is inlined).  The
+    prefill program (512 rows) keeps its fourteen grouped products, which
+    read a layer's experts where they lie (given 1,856 columns the
+    compiler padded a copy of all 64, 638 MB a layer a step), and the
+    loops that carry the scan's state from chunk to chunk.  In both every
+    run's pair of the pools, the Mamba-2 layers' recurrent state among
+    them, goes in and comes out in one buffer."""
     text, cfg = nemotron_programs[which], nemotron_programs["cfg"]
     assert text.startswith(f"HloModule jit__{which}")
     scheduled = _scheduled(text)
     assert len(scheduled) > 100
-    stack = cfg.experts * cfg.hidden * dec._lanes(1856)
+    expert = cfg.hidden * dec._lanes(1856)
     state = int(np.prod(nemotron_programs["pools"][0].shape))  # a run's convolution tails
-    moved = [
-        (name, op, n) for name, n, op in scheduled
-        if n >= stack and _moves_data_only(name, op)
-    ]
-    assert not moved, moved
+    if which == "decode":
+        assert _grouped_products(text) == 0
+        assert _loops(text) == cfg.routed_layers == 7
+        least = expert
+    else:
+        assert _grouped_products(text) == 2 * cfg.routed_layers
+        assert _loops(text) == cfg.ssm_layers
+        least = cfg.experts * expert
+    assert not _moved_whole(scheduled, least)
     assert state == 8 * 3 * 6144
-    # the only loops left carry the scan's state from chunk to chunk
-    assert len(re.findall(r" while\(", text)) == (0 if which == "decode" else cfg.ssm_layers)
     first = len(jax.tree_util.tree_leaves(nemotron_programs["tree"]))
     carrying = [r for r, (kind, _n) in enumerate(cfg.runs) if kind.part != "ffn"]
     assert len(carrying) == 9  # 7 Mamba-2 runs' state, 2 attention runs' pages
     pairs = {first + r for r in carrying} | {first + len(cfg.runs) + r for r in carrying}
     assert pairs <= aliased_parameters(text)
+
+
+@pytest.fixture(scope="module")
+def mimo_programs(one_chip):
+    cfg, _tree, _kp, texts = _compiled(one_chip, "mimo-v2.5-bge-rag")
+    return {**texts, "cfg": cfg}
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_mimo_step_on_the_chip_loops_over_hit_experts_and_prefill_groups(
+        mimo_programs, which):
+    """MiMo-V2.5's share at published widths (32 gated experts of 4,096 x
+    2,048 a routed layer; runs G-dense, W x 4, G, W): the decode step
+    holds no grouped product, one loop a routed body (the scanned run's,
+    inside the scan's own loop, and the two runs of one layer) and no
+    copy the size of one expert; the prefill program keeps its three
+    grouped products a routed body and no loop but the layer scan."""
+    text, cfg = mimo_programs[which], mimo_programs["cfg"]
+    assert text.startswith(f"HloModule jit__{which}")
+    scheduled = _scheduled(text)
+    assert len(scheduled) > 100
+    scans = sum(1 for _kind, n in cfg.runs if n > 1)
+    routed_bodies = sum(1 for kind, _n in cfg.runs if kind.routed)
+    assert (scans, routed_bodies, cfg.routed_layers) == (1, 3, 6)
+    expert = cfg.hidden * dec._lanes(2048)
+    if which == "decode":
+        assert _grouped_products(text) == 0
+        assert _loops(text) == scans + routed_bodies
+        assert not _moved_whole(scheduled, expert)
+    else:
+        assert _grouped_products(text) == 3 * routed_bodies
+        assert _loops(text) == scans
