@@ -334,6 +334,11 @@ METRICS: dict[str, tuple[str, str]] = {
         "counter", "token rows of prefill programs that held no prompt "
         "token (rows x width dispatched less generate.prefill.tokens): "
         "arithmetic spent on padding"),
+    "generate.prefill.context_tokens": (
+        "counter", "summed over a prefill program's rows, the tokens of the "
+        "row's slot already in the cache when the program runs: the earlier "
+        "context its chunk's attention reads (nought for a prompt's first "
+        "chunk; over generate.prefill.chunks, the context a chunk reads)"),
     "generate.decode.steps": (
         "counter", "continuous decode steps dispatched (one token per "
         "decoding slot per step)"),

@@ -49,6 +49,22 @@ from pathway_tpu.models.tokenizer import load_tokenizer, may_have_local_checkpoi
 
 
 @dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN rope scaling (``rope_type: yarn``): the rotated dims whose
+    wavelength fits between ``beta_fast`` and ``beta_slow`` rotations over
+    ``original_max_position_embeddings`` are ramped from their own
+    frequency (extrapolated) to it divided by ``factor`` (interpolated),
+    and cos and sin are multiplied by ``attention_factor``
+    (:func:`rope_inv_frequencies`)."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerKind:
     """What may differ between the layers of one model: a model is a
     sequence of runs of like layers (``DecoderConfig.layer_runs``), each
@@ -73,6 +89,15 @@ class LayerKind:
     # routed experts (width ``intermediate`` each) or one dense SwiGLU
     routed: bool = False
     intermediate: int = 14336
+    # query heads of the kind's attention (None: the model's ``heads``);
+    # rotary on the first ``rotary_dim`` dims of a query / key head (None:
+    # the model's ``rotary_dim``), scaled by ``yarn`` where it is set
+    heads: int | None = None
+    rotary_dim: int | None = None
+    yarn: YaRN | None = None
+    # a learned sigmoid gate per query head multiplies that head's share
+    # of the attention's output (the run's ``attn_gate`` leaf)
+    gated: bool = False
 
     @property
     def attends(self) -> bool:
@@ -250,10 +275,12 @@ def decoder_config_from_hf(hf: dict) -> DecoderConfig:
         return _mimo_v2_config(hf)
     if model_type == "nemotron_h":
         return _nemotron_h_config(hf)
+    if model_type == "laguna":
+        return _laguna_config(hf)
     if model_type not in _LLAMA_TYPES:
         raise ValueError(
             f"config.json has model_type {model_type!r}; decoder_config_for "
-            f"reads {', '.join(_LLAMA_TYPES)}, mimo_v2 and nemotron_h"
+            f"reads {', '.join(_LLAMA_TYPES)}, mimo_v2, nemotron_h and laguna"
         )
     return DecoderConfig(
         vocab_size=hf.get("vocab_size", 32000),
@@ -350,13 +377,14 @@ def _mimo_v2_config(hf: dict) -> DecoderConfig:
     )
 
 
-def _expert_share(hf: dict, model_type: str) -> tuple[int, int, int]:
-    """``(held, published, first)``: ``n_routed_experts`` counts the experts
-    held here; a file that is one chip's share of an expert-parallel layer
-    states the router's published width as ``n_routed_experts_published``
-    and which share this is as ``expert_shard_index``."""
-    held = hf["n_routed_experts"]
-    published = hf.get("n_routed_experts_published", held)
+def _expert_share(hf: dict, model_type: str,
+                  key: str = "n_routed_experts") -> tuple[int, int, int]:
+    """``(held, published, first)``: ``key`` (``n_routed_experts``) counts
+    the experts held here; a file that is one chip's share of an
+    expert-parallel layer states the router's published width as
+    ``<key>_published`` and which share this is as ``expert_shard_index``."""
+    held = hf[key]
+    published = hf.get(f"{key}_published", held)
     first = hf.get("expert_shard_index", 0) * held
     if first + held > published:
         raise ValueError(
@@ -445,6 +473,120 @@ def _nemotron_h_config(hf: dict) -> DecoderConfig:
     )
 
 
+# the attention of each ``layer_types`` entry a ``laguna`` file may name
+_LAGUNA_WINDOWED = {"full_attention": False, "sliding_attention": True}
+
+
+def _laguna_config(hf: dict) -> DecoderConfig:
+    """``model_type: laguna`` (poolside's Laguna, language model): full and
+    sliding-window attention layers by ``layer_types``, each with query
+    heads of its own (``num_attention_heads_per_layer``), rotary of its
+    own (``rope_parameters`` by layer type: on part or all of a head, with
+    YaRN's scaling or without) and a sigmoid gate per query head on the
+    attention's output; dense or routed FFNs by ``mlp_layer_types``: a
+    dense SwiGLU, or softmax-routed SwiGLU experts beside a SwiGLU shared
+    expert, the chosen weights renormalised and scaled.  ``num_experts``
+    counts the experts held here; one chip's share of an expert-parallel
+    layer is stated as ``num_experts_published`` and
+    ``expert_shard_index``.  A setting this forward does not implement
+    raises."""
+    unread = {
+        "gating": ("per-head",), "moe_router_logit_softcapping": (0, 0.0, None),
+        "moe_apply_router_weight_on_input": (False, None),
+        "attention_bias": (False, None), "tie_word_embeddings": (False, None),
+        "norm_topk_prob": (True,), "decoder_sparse_step": (1, None),
+        "hidden_act": ("silu", None),
+    }
+    for key, allowed in unread.items():
+        if hf.get(key) not in allowed:
+            raise NotImplementedError(
+                f"laguna config: {key}={hf.get(key)!r} is not implemented "
+                f"(this forward takes {allowed})"
+            )
+    L, D = hf["num_hidden_layers"], hf["head_dim"]
+    per_layer = {
+        name: hf[name][:L] for name in (
+            "layer_types", "mlp_layer_types", "num_attention_heads_per_layer",
+            "gating_types",
+        )
+    }
+    short = {name: len(v) for name, v in per_layer.items() if len(v) != L}
+    if short:
+        raise ValueError(f"laguna config: {L} layers but {short} describe fewer")
+    for key, values, allowed in (
+        ("layer_types", per_layer["layer_types"], set(_LAGUNA_WINDOWED)),
+        ("mlp_layer_types", per_layer["mlp_layer_types"], {"dense", "sparse"}),
+        ("gating_types", per_layer["gating_types"], {"per_head"}),
+    ):
+        other = sorted(set(values) - allowed)
+        if other:
+            raise NotImplementedError(
+                f"laguna config: {key} {other} are not implemented "
+                f"(this forward takes {sorted(allowed)})"
+            )
+    dense = {l for l, m in enumerate(per_layer["mlp_layer_types"]) if m == "dense"}
+    if {l for l in hf.get("mlp_only_layers", []) if l < L} != dense:
+        raise ValueError(
+            f"laguna config: mlp_only_layers {hf.get('mlp_only_layers')} disagree "
+            f"with the dense layers of mlp_layer_types {sorted(dense)}"
+        )
+    kinds = {}
+    for layer_type, windowed in _LAGUNA_WINDOWED.items():
+        rope = hf["rope_parameters"].get(layer_type, {})
+        rope_type = rope.get("rope_type", "default")
+        if rope_type not in ("default", "yarn"):
+            raise NotImplementedError(f"laguna config: {layer_type} rope_type {rope_type!r}")
+        yarn = None
+        if rope_type == "yarn":
+            factor = float(rope["factor"])
+            yarn = YaRN(
+                factor=factor,
+                original_max_position_embeddings=rope["original_max_position_embeddings"],
+                beta_fast=float(rope.get("beta_fast", 32.0)),
+                beta_slow=float(rope.get("beta_slow", 1.0)),
+                # transformers' default where the file states none
+                attention_factor=float(
+                    rope.get("attention_factor") or 0.1 * np.log(factor) + 1.0
+                ),
+            )
+        kinds[layer_type] = dict(
+            window=hf["sliding_window"] if windowed else None,
+            rope_theta=float(rope.get("rope_theta", 10000.0)),
+            rotary_dim=int(rope.get("partial_rotary_factor", 1.0) * D) // 2 * 2,
+            yarn=yarn,
+        )
+    runs: list[tuple[LayerKind, int]] = []
+    for layer_type, mlp, heads in zip(
+        per_layer["layer_types"], per_layer["mlp_layer_types"],
+        per_layer["num_attention_heads_per_layer"],
+    ):
+        routed = mlp == "sparse"
+        kind = LayerKind(
+            kv_heads=hf["num_key_value_heads"], heads=heads, gated=True,
+            routed=routed,
+            intermediate=hf["moe_intermediate_size" if routed else "intermediate_size"],
+            **kinds[layer_type],
+        )
+        if runs and runs[-1][0] == kind:
+            runs[-1] = (kind, runs[-1][1] + 1)
+        else:
+            runs.append((kind, 1))
+    held, published, first = _expert_share(hf, "laguna", "num_experts")
+    return DecoderConfig(
+        vocab_size=hf["vocab_size"], hidden=hf["hidden_size"], layers=L,
+        heads=hf["num_attention_heads"], kv_heads=hf["num_key_value_heads"],
+        intermediate=hf["intermediate_size"],
+        max_len=min(hf.get("max_position_embeddings", 4096), 8192),
+        norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
+        dtype=jnp.dtype(hf.get("torch_dtype", "bfloat16")),
+        experts=held, experts_top_k=hf["num_experts_per_tok"],
+        experts_published=published, experts_first=first,
+        experts_route_scale=float(hf.get("moe_routed_scaling_factor") or 1.0),
+        experts_shared=hf["shared_expert_intermediate_size"],
+        qk_head_dim=D, runs=tuple(runs),
+    )
+
+
 # ``chipbench/configs/mimo-v2.5-bge-rag.json``'s ``tiny.decoder`` block is
 # this dictionary (a test holds them equal)
 TINY_HYBRID_HF = {
@@ -491,6 +633,47 @@ TINY_MAMBA_HF = {
     "torch_dtype": "float32",
 }
 PRESETS["pw-tiny-mamba-decoder"] = decoder_config_from_hf(TINY_MAMBA_HF)
+
+# every kind of layer Laguna has, tiny, in the benchmark's cut's order
+# (full and dense, three window layers, full and routed): 4 query heads on
+# full layers and 6 on window layers over 2 KV heads, a per-head gate,
+# YaRN on half of a full layer's head, a window of 24 (longer than the
+# tests' 8-token pages, shorter than their prompts), one of two shares of
+# 8 softmax-routed SwiGLU experts beside a SwiGLU shared expert; a context
+# of 1,024, so that the benchmark's rehearsal sends prompts longer than
+# one prefill program.
+# ``chipbench/configs/laguna-s-2.1-bge-rag.json``'s ``tiny.decoder`` block
+TINY_LAGUNA_HF = {
+    "model_type": "laguna", "vocab_size": 512, "hidden_size": 64,
+    "intermediate_size": 128, "num_hidden_layers": 5,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "max_position_embeddings": 1024, "attention_bias": False, "rms_norm_eps": 1e-06,
+    "num_experts": 4, "num_experts_published": 8, "expert_shard_index": 0,
+    "num_experts_per_tok": 3, "moe_intermediate_size": 32,
+    "shared_expert_intermediate_size": 32, "norm_topk_prob": True,
+    "decoder_sparse_step": 1, "mlp_only_layers": [0], "tie_word_embeddings": False,
+    "gating": "per-head", "sliding_window": 24,
+    "rope_parameters": {
+        "full_attention": {
+            "rope_theta": 500000, "rope_type": "yarn", "factor": 4,
+            "original_max_position_embeddings": 32, "beta_slow": 1, "beta_fast": 32,
+            "attention_factor": 1.1386294361119891, "partial_rotary_factor": 0.5,
+        },
+        "sliding_attention": {
+            "rope_type": "default", "rope_theta": 10000, "partial_rotary_factor": 1,
+        },
+    },
+    "layer_types": [
+        "full_attention", "sliding_attention", "sliding_attention",
+        "sliding_attention", "full_attention",
+    ],
+    "mlp_layer_types": ["dense", "sparse", "sparse", "sparse", "sparse"],
+    "gating_types": ["per_head"] * 5,
+    "num_attention_heads_per_layer": [4, 6, 6, 6, 4],
+    "moe_apply_router_weight_on_input": False, "moe_routed_scaling_factor": 2.5,
+    "moe_router_logit_softcapping": 0, "torch_dtype": "float32",
+}
+PRESETS["pw-tiny-laguna-decoder"] = decoder_config_from_hf(TINY_LAGUNA_HF)
 
 
 def decoder_config_for(model_name: str) -> DecoderConfig:
@@ -595,14 +778,19 @@ def _init_run(cfg: DecoderConfig, kind: LayerKind, n: int, key, norm_init):
     Attention: the fused ``wqkv`` (queries, then keys, then values, as
     ``attention_projection_layout: fused_qkv`` lays them), ``wo`` over the
     value heads, a sink logit per query head where the kind has one
-    (normal x 0.5: not nought, or a test would not see it).  FFN: the
+    (normal x 0.5: not nought, or a test would not see it), the gate's
+    ``attn_gate [H, heads]`` where the kind is gated (the sink's key
+    folded with 1).  FFN: the
     dense SwiGLU, or the router (f32, at its published width, with the
     ``noaux_tc`` correction bias, normal x 0.02) and the experts HELD
     (their draw keyed by the first expert's index, so that another share
     of the layer draws other experts; no ``wg`` where experts have no
-    gate) and the shared expert, which every share draws alike.  A layer
+    gate) and the shared expert, which every share draws alike (of the
+    experts' form: its gate, where they have one, from the router's key
+    folded with 2).  A layer
     of one part has the one norm ``ln0``; ``_init_mamba`` draws a mixer."""
-    H, NH, D, Dv = cfg.hidden, cfg.heads, cfg.head_dim, cfg.v_dim
+    H, D, Dv = cfg.hidden, cfg.head_dim, cfg.v_dim
+    NH = kind.heads or cfg.heads
     keys = jax.random.split(key, 8)
     run = {"ln0": jnp.ones((n, H), cfg.dtype)}
     if kind.part == "block":
@@ -615,6 +803,8 @@ def _init_run(cfg: DecoderConfig, kind: LayerKind, n: int, key, norm_init):
         run["wo"] = norm_init(keys[1], (n, NH * Dv, H), NH * Dv)
         if kind.sink:
             run["sink"] = 0.5 * jax.random.normal(keys[2], (n, NH), jnp.float32)
+        if kind.gated:
+            run["attn_gate"] = norm_init(jax.random.fold_in(keys[2], 1), (n, H, NH), H)
     if not kind.has_ffn:
         return run
     F = kind.intermediate
@@ -646,6 +836,8 @@ def _init_run(cfg: DecoderConfig, kind: LayerKind, n: int, key, norm_init):
     if cfg.experts_shared:
         Fs = cfg.experts_shared
         up, down = jax.random.split(jax.random.fold_in(keys[6], 1))
+        if cfg.experts_gated:
+            run["shared_gate"] = norm_init(jax.random.fold_in(keys[6], 2), (n, H, Fs), H)
         run["shared_up"] = norm_init(up, (n, H, Fs), H)
         run["shared_down"] = norm_init(down, (n, Fs, H), Fs)
     return run
@@ -825,25 +1017,52 @@ def quantize_decoder_tree(tree):
     }
 
 
-def _rope(x, positions, theta):
-    """Rotary embedding; ``x`` is ``[..., S, H, D]``, positions ``[..., S]``."""
+def rope_inv_frequencies(d: int, theta: float, yarn: YaRN) -> np.ndarray:
+    """YaRN's inverse frequencies ``[d / 2]`` of ``d`` rotated dims (float64;
+    transformers' ``_compute_yarn_parameters``): ``f_i = theta^(-2i/d)``,
+    the ramp ``r_i = clip((i - lo) / (hi - lo), 0, 1)`` between the dims
+    ``lo = floor(d ln(M / (beta_fast 2 pi)) / (2 ln theta))`` and ``hi =
+    ceil(d ln(M / (beta_slow 2 pi)) / (2 ln theta))`` (clipped to ``[0, d -
+    1]``, ``M`` the original context), ``f_i / factor * r_i + f_i * (1 -
+    r_i)``."""
+    f = theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+
+    def dim(rotations: float) -> float:
+        M = yarn.original_max_position_embeddings
+        return d * np.log(M / (rotations * 2 * np.pi)) / (2 * np.log(theta))
+
+    lo = max(np.floor(dim(yarn.beta_fast)), 0)
+    hi = min(np.ceil(dim(yarn.beta_slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2) - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+    return f / yarn.factor * ramp + f * (1.0 - ramp)
+
+
+def _rope(x, positions, theta, yarn: YaRN | None = None):
+    """Rotary embedding; ``x`` is ``[..., S, H, D]``, positions ``[..., S]``.
+    With ``yarn`` its frequencies (:func:`rope_inv_frequencies`), and cos
+    and sin times its ``attention_factor``."""
     d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    if yarn is None:
+        inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    else:
+        inv = jnp.asarray(rope_inv_frequencies(d, theta, yarn), jnp.float32)
     freqs = positions[..., None].astype(jnp.float32) * inv  # [..., S, D/2]
     cos = jnp.cos(freqs)[..., None, :]  # [..., S, 1, D/2]
     sin = jnp.sin(freqs)[..., None, :]
+    if yarn is not None:
+        cos, sin = cos * yarn.attention_factor, sin * yarn.attention_factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
 
 
-def _rope_part(x, positions, theta, rot: int | None):
+def _rope_part(x, positions, theta, rot: int | None, yarn: YaRN | None = None):
     """Rotary on the first ``rot`` dims of each head (all of them where
     ``rot`` is None or the head's width), the rest untouched."""
     if rot is None or rot == x.shape[-1]:
-        return _rope(x, positions, theta)
+        return _rope(x, positions, theta, yarn)
     return jnp.concatenate(
-        [_rope(x[..., :rot], positions, theta), x[..., rot:]], axis=-1
+        [_rope(x[..., :rot], positions, theta, yarn), x[..., rot:]], axis=-1
     )
 
 
@@ -851,7 +1070,10 @@ def _qkv(lp, x, positions, cfg: DecoderConfig, kind: LayerKind):
     """Input norm, projections, rotary, value scale of one layer: ``q
     [..., NH, D]``, ``k [..., KH, D]``, ``v [..., KH, Dv]`` from the
     residual stream ``x [..., H]`` (a run of kinds holds the fused
-    ``wqkv``, a model of one kind ``wq`` / ``wk`` / ``wv``).
+    ``wqkv``, a model of one kind ``wq`` / ``wk`` / ``wv``), NH the kind's
+    query heads; and where the kind is gated the gate ``sigmoid(h .
+    W_gate) [..., NH]`` (float32) of the normed ``h``, else None
+    (:func:`_gate_heads` applies it).
 
     The barrier keeps each product a plain ``[..., H] @ [H, N]``.  Without
     it the TPU compiler folds the split into heads into the product (a
@@ -863,24 +1085,48 @@ def _qkv(lp, x, positions, cfg: DecoderConfig, kind: LayerKind):
     the small output."""
     lead = x.shape[:-1]
     KH, D, Dv = kind.kv_heads, cfg.head_dim, cfg.v_dim
+    NH = kind.heads or cfg.heads
     h = _rms(x, lp["ln0"], cfg.norm_eps)
     if "wqkv" in lp:
-        nq, nk = cfg.heads * D, KH * D
+        nq, nk = NH * D, KH * D
         qkv = lax.optimization_barrier(_mm(h, lp["wqkv"]))
         q, k, v = qkv[..., :nq], qkv[..., nq:nq + nk], qkv[..., nq + nk:]
     else:
         q, k, v = lax.optimization_barrier(
             (_mm(h, lp["wq"]), _mm(h, lp["wk"]), _mm(h, lp["wv"]))
         )
-    q = q.reshape(*lead, cfg.heads, D)
+    q = q.reshape(*lead, NH, D)
     k = k.reshape(*lead, KH, D)
     v = v.reshape(*lead, KH, Dv)
     if kind.rope:
-        q = _rope_part(q, positions, kind.rope_theta, cfg.rotary_dim)
-        k = _rope_part(k, positions, kind.rope_theta, cfg.rotary_dim)
+        rot = kind.rotary_dim or cfg.rotary_dim
+
+        def turn(t):
+            return _rope_part(t, positions, kind.rope_theta, rot, kind.yarn)
+
+        if kind.yarn is None:
+            q, k = turn(q), turn(k)
+        else:
+            with jax.named_scope("rope.yarn"):
+                q, k = turn(q), turn(k)
     if cfg.value_scale != 1.0:
         v = v * jnp.asarray(cfg.value_scale, v.dtype)
-    return q, k, v
+    gate = None
+    if kind.gated:
+        with jax.named_scope("attn.gate"):
+            gate = jax.nn.sigmoid(_mm(h, lp["attn_gate"]).astype(jnp.float32))
+    return q, k, v, gate
+
+
+def _gate_heads(ctx, gate):
+    """The attention's output ``ctx [..., NH * Dv]`` with each head's
+    share times its gate ``[..., NH]`` (a kind without a gate: as it is)."""
+    if gate is None:
+        return ctx
+    with jax.named_scope("attn.gate"):
+        NH = gate.shape[-1]
+        heads = ctx.reshape(*ctx.shape[:-1], NH, ctx.shape[-1] // NH)
+        return (heads.astype(jnp.float32) * gate[..., None]).astype(ctx.dtype).reshape(ctx.shape)
 
 
 def _attend(q, k, v, mask, cfg: DecoderConfig, sink=None):
@@ -924,7 +1170,8 @@ def _ffn(lp, h, cfg: DecoderConfig, kind: LayerKind | None = None, *,
         # what a layer has of: a gate, a correction bias, its index in the
         # run's expert stacks, a shared expert
         optional = {"wg": "wg", "bias": "moe_bias", "layer": "moe_layer",
-                    "shared_up": "shared_up", "shared_down": "shared_down"}
+                    "shared_gate": "shared_gate", "shared_up": "shared_up",
+                    "shared_down": "shared_down"}
         params.update({name: lp[leaf] for name, leaf in optional.items() if leaf in lp})
         if serving:
             out, pairs, hit = moe_serve(params, h, mcfg, valid)
@@ -964,8 +1211,9 @@ def decoder_layer(lp, x, positions, mask, cfg: DecoderConfig,
             f"a {kind.part!r} layer is served by the scheduler's paged "
             "programs (_paged_trunk), not trained"
         )
-    q, k, v = _qkv(lp, x, positions, cfg, kind)
-    x = x + _mm(_attend(q, k, v, mask, cfg, lp.get("sink")), lp["wo"])
+    q, k, v, gate = _qkv(lp, x, positions, cfg, kind)
+    ctx = _gate_heads(_attend(q, k, v, mask, cfg, lp.get("sink")), gate)
+    x = x + _mm(ctx, lp["wo"])
     h = _rms(x, lp["ln1"], cfg.norm_eps)
     mlp, aux = _ffn(lp, h, cfg, kind, serving=serving)
     x = x + mlp
@@ -1390,7 +1638,7 @@ def _paged_trunk(tree, k_pool, v_pool, x, cfg: DecoderConfig, *, tables, rings,
                 x = x + mixed
             if kind.attends:
                 with jax.named_scope("attn.qkv"):
-                    q, k, v = _qkv(lp, x, positions, cfg, kind)
+                    q, k, v, gate = _qkv(lp, x, positions, cfg, kind)
                 if ring:
                     with jax.named_scope(scope):
                         ctx = attention_ops.ring_gqa_attention(
@@ -1413,6 +1661,7 @@ def _paged_trunk(tree, k_pool, v_pool, x, cfg: DecoderConfig, *, tables, rings,
                         ctx = attention_ops.paged_gqa_attention(
                             q, kp, vp, tables, mask, lp.get("sink"), index
                         )
+                ctx = _gate_heads(ctx, gate)
                 with jax.named_scope("attn.out"):
                     x = x + _mm(ctx, lp["wo"])
             if kind.has_ffn:

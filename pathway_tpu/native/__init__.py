@@ -63,6 +63,22 @@ def _compile() -> str | None:
     if os.path.exists(so_path):
         return so_path
     os.makedirs(_BUILD_DIR, exist_ok=True)
+    # processes that start together in a fresh checkout (a test run's
+    # workers) build one .so: the first to take the lock builds it, the
+    # others wait and find it built
+    import fcntl
+
+    with open(so_path + ".lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if os.path.exists(so_path):
+            return so_path
+        return _build(so_path)
+
+
+def _build(so_path: str) -> str | None:
+    """Run g++ into a temp file of this process's own, then move it into
+    place (a half-written .so is never at ``so_path``)."""
+    tmp = f"{so_path}.{os.getpid()}.tmp"
     include = sysconfig.get_paths()["include"]
     cmd = [
         "g++",
@@ -76,7 +92,7 @@ def _compile() -> str | None:
         f"-I{include}",
         _SRC,
         "-o",
-        so_path + ".tmp",
+        tmp,
     ]
     try:
         subprocess.run(
@@ -90,7 +106,7 @@ def _compile() -> str | None:
             "native core build failed, using Python fallbacks: %s", detail[-2000:]
         )
         return None
-    os.replace(so_path + ".tmp", so_path)
+    os.replace(tmp, so_path)
     return so_path
 
 

@@ -390,9 +390,9 @@ def moe_serve(params, x: jnp.ndarray, cfg: MoEConfig, valid=None):
     ``[H, router_width]`` f32, ``params["bias"]`` optional; the chosen
     weights times ``cfg.route_scale``).  An expert is gated (``wg``, ``wu``,
     ``wd``) or, with ``cfg.gated`` false, ``wd(relu(wu x) ** 2)``.  Where
-    ``params`` holds ``shared_up`` / ``shared_down``, that shared expert
-    (of the same ungated form; none is implemented beside gated experts)
-    is added for every token, real or padding.  Of a token's
+    ``params`` holds ``shared_up`` / ``shared_down`` (and ``shared_gate``
+    where experts are gated), that shared expert, of the experts' form, is
+    added for every token, real or padding, on either path.  Of a token's
     ``top_k`` (token, expert) pairs those that fall on the experts held
     here (``cfg.first_expert`` and the ``cfg.experts`` after it, the
     leading axis of ``wg`` / ``wu`` / ``wd``) are multiplied by their
@@ -475,10 +475,9 @@ def moe_serve(params, x: jnp.ndarray, cfg: MoEConfig, valid=None):
     if "shared_up" in params:
         # the shared expert: every token takes it, whatever its routing,
         # and every share of the layer computes it alike
-        if cfg.gated:
-            raise NotImplementedError("a shared expert beside gated experts")
         with jax.named_scope("moe.shared"):
-            hidden = _activate(None, xt @ params["shared_up"])
+            gate = xt @ params["shared_gate"] if cfg.gated else None
+            hidden = _activate(gate, xt @ params["shared_up"])
             y = y + (hidden @ params["shared_down"]).astype(jnp.float32)
     pairs = jnp.sum(here, dtype=jnp.int32)
     experts_hit = jnp.sum(group_sizes > 0, dtype=jnp.int32)

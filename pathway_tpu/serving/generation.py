@@ -452,6 +452,10 @@ class GenerationScheduler:
             "generate.prefill.padded",
             "token rows of prefill programs that held no prompt token",
         )
+        self._m_prefill_context = reg.counter(
+            "generate.prefill.context_tokens",
+            "tokens a prefill program's rows held in the cache before it ran",
+        )
         self._m_decode_steps = reg.counter(
             "generate.decode.steps", "continuous decode ticks dispatched"
         )
@@ -967,6 +971,18 @@ class GenerationScheduler:
                 most = len(s.pages)
         return _pow2_bucket(most, self.pages_per_seq)
 
+    def prefill_programs(self, prompt_len: int) -> list[tuple[int, int, int]]:
+        """``(rows, width, table width)`` of each prefill program a prompt
+        of ``prompt_len`` tokens runs through alone, in order: the shapes
+        a warm-up has to compile for such prompts."""
+        programs, done = [], 0
+        while done < prompt_len:
+            rows, width = prefill_shape(prompt_len - done, self._ladder, self.slots)
+            done = min(prompt_len, done + width)
+            pages = _pow2_bucket(self.allocator.pages_for(done), self.pages_per_seq)
+            programs.append((rows, width, pages))
+        return programs
+
     def _run_prefill(self, rows: list[int]) -> list[int]:
         """The tick's prefill programs, shaped by what waits
         (:func:`prefill_shape`): a one-row program for every slot with
@@ -1053,6 +1069,9 @@ class GenerationScheduler:
         real = int(chunk_lens.sum())
         self._m_prefill_tokens.inc(real)
         self._m_prefill_padded.inc(R * T - real)
+        # the earlier context the program's attention reads: a prompt's
+        # chunks after its first
+        self._m_prefill_context.inc(int(starts.sum()))
         enqueue_s = max(0.0, time.time() - enqueue_started)
         for slot in chunked:
             if slot.prefill_chunks == 0:
