@@ -370,6 +370,13 @@ METRICS: dict[str, tuple[str, str]] = {
         "counter", "held experts that met at least one token, summed over "
         "the routed layers of every prefill program (divisor: generate."
         "prefill.chunks x routed layers)"),
+    "generate.moe.prefill.tile_rows": (
+        "counter", "rows the prefill programs' grouped expert kernel "
+        "multiplied: its row tiles of 128 (parallel/moe.py::moe_serve, "
+        "ops/grouped_matmul.py), counted on the device from the groups "
+        "whatever the platform; a tile two experts share is multiplied by "
+        "each.  generate.moe.prefill.pairs over it is the share of the "
+        "kernel's rows that are real"),
     "generate.moe.decode.steps_in_place": (
         "counter", "decode steps enqueued whose routed layers loop over the "
         "held experts that met a token and read each where it lies (a "

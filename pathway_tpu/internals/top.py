@@ -531,11 +531,17 @@ def render_top(
             # ... and the share of steps that looped over those experts
             # in place instead of calling the grouped product
             in_place = generation.get("generate.moe.decode.steps_in_place") or 0.0
-            lines.append(
+            row = (
                 f"  experts: {moe_pairs / max(tokens, 1.0):.1f} pair(s) a token "
                 f"· {hit / max(steps, 1.0):.1f} hit a decode step "
                 f"· {100.0 * in_place / max(steps, 1.0):.0f}% of steps in place"
             )
+            # ... and the share of the grouped kernel's rows that are real
+            tile_rows = generation.get("generate.moe.prefill.tile_rows") or 0.0
+            if tile_rows:
+                prefill_pairs = generation.get("generate.moe.prefill.pairs") or 0.0
+                row += f" · {100.0 * prefill_pairs / tile_rows:.0f}% of prefill tile rows real"
+            lines.append(row)
         churn = generation.get("generate.churn.synthetic")
         if churn:
             lines.append(f"  churn: {int(churn)} synthetic burst request(s)")

@@ -1147,8 +1147,9 @@ def _ffn(lp, h, cfg: DecoderConfig, kind: LayerKind | None = None, *,
     returns ``(out, aux)`` with the load-balance auxiliary loss of the
     capacity-based dispatch (0 for dense).  ``serving`` routes without
     capacity (a drop would silently degrade a generation) and returns
-    ``(out, stats)``: the ``[pairs, experts_hit]`` of ``moe_serve`` over
-    the ``valid`` tokens, or for a dense layer the same 0."""
+    ``(out, stats)``: the ``[pairs, experts_hit, tile_rows]`` of
+    ``moe_serve`` over the ``valid`` tokens, or for a dense layer the
+    same 0."""
     kind = kind or cfg.kind
     if kind.routed:
         from pathway_tpu.parallel.moe import MoEConfig, moe_ffn, moe_serve
@@ -1174,8 +1175,8 @@ def _ffn(lp, h, cfg: DecoderConfig, kind: LayerKind | None = None, *,
                     "shared_down": "shared_down"}
         params.update({name: lp[leaf] for name, leaf in optional.items() if leaf in lp})
         if serving:
-            out, pairs, hit = moe_serve(params, h, mcfg, valid)
-            return out, jnp.stack([pairs, hit])
+            out, *counts = moe_serve(params, h, mcfg, valid)
+            return out, jnp.stack(counts)
         if mcfg.scoring != "softmax" or mcfg.router_width not in (0, mcfg.experts):
             raise NotImplementedError(
                 "training routes by softmax over experts that are all held "
@@ -1605,9 +1606,10 @@ def _paged_trunk(tree, k_pool, v_pool, x, cfg: DecoderConfig, *, tables, rings,
     pools donated (``serving/generation.py``) the caller's buffers are
     updated in place.
     Returns ``(x, k_pool, v_pool, stats)``; ``stats`` is the routed
-    layers' summed ``[pairs, experts_hit]`` (noughts without routed
-    layers), and for a model with Mamba-2 layers a third entry: the tokens
-    the scan advanced a state by (of one layer: they all meet the same).
+    layers' summed ``[pairs, experts_hit, tile_rows]`` (noughts without
+    routed layers); for a model with Mamba-2 layers ``[pairs,
+    experts_hit, tokens, tile_rows]``, ``tokens`` those the scan advanced
+    a state by (of one layer: they all meet the same).
     """
     from pathway_tpu.ops import attention as attention_ops
 
@@ -1676,7 +1678,7 @@ def _paged_trunk(tree, k_pool, v_pool, x, cfg: DecoderConfig, *, tables, rings,
         return layer
 
     k_out, v_out = [], []
-    stats, advanced = jnp.zeros((2,), jnp.int32), jnp.int32(0)
+    stats, advanced = jnp.zeros((3,), jnp.int32), jnp.int32(0)
     for kind, layers, kp, vp in run_stacks(cfg, tree["layers"], k_pool, v_pool):
         experts = {}
         if kind.routed:
@@ -1697,8 +1699,11 @@ def _paged_trunk(tree, k_pool, v_pool, x, cfg: DecoderConfig, *, tables, rings,
         elif kind.part == "mamba":
             advanced = advanced + counts.sum()
     if cfg.ssm_layers:
-        # every Mamba-2 layer meets the same tokens: one layer's count
-        stats = jnp.concatenate([stats, (advanced // cfg.ssm_layers)[None]])
+        # every Mamba-2 layer meets the same tokens: one layer's count,
+        # before the tile rows, which stay last
+        stats = jnp.concatenate(
+            [stats[:2], (advanced // cfg.ssm_layers)[None], stats[2:]]
+        )
     if cfg.runs is None:
         return x, k_out[0], v_out[0], stats
     return x, tuple(k_out), tuple(v_out), stats
@@ -1720,8 +1725,8 @@ def paged_decode_step(tree, k_pool, v_pool, block_tables, seq_lens, token,
     """One generation step over paged KV: ``token`` ``[S]`` is written at
     each slot's next position (``seq_lens`` ``[S]``), attention gathers
     the slot's pages.  Returns ``(logits [S, V], k_pool, v_pool)``, and
-    with ``with_stats`` the routed layers' ``[pairs, experts_hit]`` after
-    them.
+    with ``with_stats`` the routed layers' ``[pairs, experts_hit,
+    tile_rows]`` after them.
 
     The gathered context is a dense cache rearranged through the block
     table, and masked positions contribute exactly zero, so the step's
@@ -1777,7 +1782,8 @@ def paged_prefill_chunk(tree, k_pool, v_pool, block_tables, chunk_ids,
     prefill split along the query axis.  Returns ``(logits [S, V]`` at
     each slot's LAST chunk token``, k_pool, v_pool)``; rows with
     ``chunk_lens == 0`` produce garbage logits the scheduler ignores.
-    ``with_stats`` adds the routed layers' ``[pairs, experts_hit]``.
+    ``with_stats`` adds the routed layers' ``[pairs, experts_hit,
+    tile_rows]``.
 
     ``S`` and ``T`` are compile-time sizes and the scheduler chooses them
     from a few (``serving/generation.py::prefill_shape``): one row as

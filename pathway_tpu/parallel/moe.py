@@ -17,10 +17,11 @@ Design points, all MXU/XLA-motivated:
   Static shapes keep the whole layer one compiled program.
 * **No capacity (serving, ``moe_serve``).**  No (token, expert) pair that
   falls on the experts this chip holds is dropped.  A program of many
-  rows (prefill) sorts them by expert for one grouped product
-  (``jax.lax.ragged_dot``): no expert is multiplied by a token it was not
-  given.  A program of few rows (a decode step) loops over the experts
-  its rows met and reads each once, where it lies.
+  rows (prefill) sorts them by expert for one grouped product (a Pallas
+  kernel on the TPU, ``ops/grouped_matmul.py``; ``jax.lax.ragged_dot``
+  elsewhere): no expert is multiplied by a token it was not given.  A
+  program of few rows (a decode step) loops over the experts its rows
+  met and reads each once, where it lies.
 * **Top-k routing with renormalised gates** (k=2 default, the
   Mixtral/GShard setting): the combine weights of the selected experts
   are renormalised to sum to 1, so with identical experts the layer
@@ -45,6 +46,8 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from pathway_tpu.ops.grouped_matmul import TILE_ROWS, grouped_matmul, row_tiles
 
 
 @dataclasses.dataclass(frozen=True)
@@ -261,13 +264,24 @@ def route(router_logits: jnp.ndarray, cfg: MoEConfig, bias=None):
     return idx, picked / jnp.maximum(picked.sum(-1, keepdims=True), 1e-9)
 
 
+def _grouped_product(x, w, group_sizes):
+    """``x [M, A]`` rows sorted by group times each group's ``w [G, A,
+    B]``, in ``x``'s dtype: the Pallas kernel where the program is lowered
+    for the TPU, ``jax.lax.ragged_dot`` for any other platform."""
+    return jax.lax.platform_dependent(
+        x, w, group_sizes, tpu=grouped_matmul, default=jax.lax.ragged_dot
+    )
+
+
 def _grouped(x, w, rows_expert, group_sizes):
     """``x [M, A]`` rows, sorted by expert, each times its own expert's
     ``w [E, A, B]`` (a float weight or an int8 weight-only pair)."""
     if isinstance(w, dict) and "q" in w:
+        # an int8 pair keeps the compiler's product, over the stack cast
+        # whole to the rows' dtype as before
         out = jax.lax.ragged_dot(x, w["q"].astype(x.dtype), group_sizes)
         return out * w["s"].astype(x.dtype)[rows_expert, 0]
-    return jax.lax.ragged_dot(x, w, group_sizes)
+    return _grouped_product(x, w, group_sizes)
 
 
 def _activate(gate, up):
@@ -371,8 +385,8 @@ def _experts_grouped(sorted_pairs, wg, wu, wd, layer, here, weights, group_sizes
         ).reshape(-1)
     gate = None if wg is None else _grouped(xs, wg, rows, sizes)
     out = _grouped(_activate(gate, _grouped(xs, wu, rows, sizes)), wd, rows, sizes)
-    # rows past the groups are pairs held elsewhere: whatever the
-    # grouped product left there is not read
+    # rows past the groups are pairs held elsewhere: the kernel never
+    # writes them, and what they hold is masked out here
     out = jnp.where((rows_expert < E)[:, None], out, 0)
     back = jnp.argsort(order)  # undo the sort: row t*K + k again
     # the weighted sum in float32, as the weights are
@@ -384,7 +398,7 @@ def _experts_grouped(sorted_pairs, wg, wu, wd, layer, here, weights, group_sizes
 
 def moe_serve(params, x: jnp.ndarray, cfg: MoEConfig, valid=None):
     """MoE feed-forward for serving: ``x [..., H]`` → ``(y [..., H],
-    pairs, experts_hit)``.
+    pairs, experts_hit, tile_rows)``.
 
     Every token is routed over the whole router width (``params["router"]``
     ``[H, router_width]`` f32, ``params["bias"]`` optional; the chosen
@@ -401,9 +415,11 @@ def moe_serve(params, x: jnp.ndarray, cfg: MoEConfig, valid=None):
     elsewhere would add is left out (on one chip of an expert-parallel
     layer that partial sum is the layer's result here, and with every
     expert held it is the whole).  ``valid [...]`` marks real tokens:
-    padding takes no expert.  ``pairs`` counts the pairs computed here and
-    ``experts_hit`` the held experts that met at least one token (int32
-    scalars, for the scheduler's counters).
+    padding takes no expert.  ``pairs`` counts the pairs computed here,
+    ``experts_hit`` the held experts that met at least one token and
+    ``tile_rows`` the rows the grouped kernel multiplies (its row tiles of
+    ``TILE_ROWS``, nought on the loop's path; a fact of the groups, counted
+    on every platform): int32 scalars, for the scheduler's counters.
 
     Two paths share the router, the choice, the counts, the shared expert
     and the activation, and nothing else, because few rows and many want
@@ -416,22 +432,26 @@ def moe_serve(params, x: jnp.ndarray, cfg: MoEConfig, valid=None):
       (a dynamic trip count, ``experts_hit``), reads each where it lies in
       the stack and multiplies all ``T`` rows by it: the products are
       bound by the expert's read while ``T`` is small, so a step pays for
-      the ≈ 1–3 experts it met and for no sort and no kernel call (the
-      grouped product costs 0.1–0.3 ms a call on a v5e whatever the rows);
+      the ≈ 1–3 experts it met and for no sort and no kernel call;
     * a prefill program (``[1, 512]``, ``[1, 256]``, ``[8, 32]``: ``T`` ≥
       256, thousands of pairs over every held expert) sorts its pairs by
-      expert and sends them through one grouped product a matrix
-      (``jax.lax.ragged_dot``): no expert is multiplied by a token it was
-      not given.
+      expert and sends them through one grouped product a matrix: no
+      expert is multiplied by a token it was not given.  Lowered for the
+      TPU that product is the Pallas kernel of ``ops/grouped_matmul.py``,
+      which reads each held expert about once; for any other platform it
+      is ``jax.lax.ragged_dot`` (the chip's own lowering of which ran at
+      ≈ 10 x its experts' read at 15- and 21-lane widths: 7.5 ms a call at
+      3,072 pairs over 64 experts of 2,688 x 1,920 on a v5e).
 
     Inside a scan over stacked layers ``wg`` / ``wu`` / ``wd`` may be the
     whole stacks ``[n, E, ...]`` with ``params["layer"]`` the layer's index
     in them: the loop reads expert ``layer * E + e``, and the grouped
     product runs over all ``n * E`` experts with every other layer's
-    groups empty.  Neither reads a layer's experts through the scan's own
-    slice of the stack: the grouped product is a custom call on the chip,
-    and XLA copies all of a layer's experts for it, hit or not, every step
-    (three 537 MB copies a layer at MiMo-V2.5's widths).
+    groups empty (the kernel takes no step for an empty group).  Neither
+    reads a layer's experts through the scan's own slice of the stack: the
+    grouped product is a custom call on the chip, and XLA copies all of a
+    layer's experts for it, hit or not, every step (three 537 MB copies a
+    layer at MiMo-V2.5's widths).
     """
     orig_shape = x.shape
     H = orig_shape[-1]
@@ -481,7 +501,8 @@ def moe_serve(params, x: jnp.ndarray, cfg: MoEConfig, valid=None):
             y = y + (hidden @ params["shared_down"]).astype(jnp.float32)
     pairs = jnp.sum(here, dtype=jnp.int32)
     experts_hit = jnp.sum(group_sizes > 0, dtype=jnp.int32)
-    return y.reshape(orig_shape).astype(x.dtype), pairs, experts_hit
+    tile_rows = jnp.int32(0) if in_place else row_tiles(group_sizes) * TILE_ROWS
+    return y.reshape(orig_shape).astype(x.dtype), pairs, experts_hit, tile_rows
 
 
 def make_ep_mesh(n_devices: int, expert_parallel: int | None = None) -> Mesh:

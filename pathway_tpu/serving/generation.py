@@ -343,9 +343,10 @@ class GenerationScheduler:
         # programs since the last step carried forward ride behind the
         # tokens, in the one array the tick syncs anyway
         self._counted = counted = self._hybrid or self.cfg.routed_layers > 0
-        # a program's counts: the routing's [pairs, experts_hit], and with
-        # Mamba-2 layers the tokens the scan advanced a state by
-        self._no_stats = jnp.zeros((2 + self._ssm,), jnp.int32)
+        # a prefill program's counts: the routing's [pairs, experts_hit],
+        # with Mamba-2 layers the tokens the scan advanced a state by, and
+        # the rows the grouped kernel multiplied
+        self._no_stats = jnp.zeros((3 + self._ssm,), jnp.int32)
         self._prefill_stats = self._no_stats
 
         def _sample(lg, key, temp, top_p, min_p, top_k=None):
@@ -365,7 +366,9 @@ class GenerationScheduler:
             lg2, kp, vp, stats = dec.paged_decode_step(
                 tree, kp, vp, bt, sl, tok, cfg, active=active, with_stats=True
             )
-            return jnp.concatenate([tok, stats, carried]), lg2, kp, vp
+            # the grouped kernel's tile rows are counted for prefill
+            # programs alone (a step of the slots' rows loops in place)
+            return jnp.concatenate([tok, stats[:-1], carried]), lg2, kp, vp
 
         def _decode(tree, kp, vp, bt, sl, lg, key, temp, top_p, min_p, *counts):
             tok = _sample(lg, key, temp, top_p, min_p)
@@ -506,7 +509,14 @@ class GenerationScheduler:
                 "generate.ssm.state.resets",
                 "slots whose recurrent state a prompt's first chunk started from noughts",
             )
+        # last behind a prefill program's counts: the rows its grouped
+        # kernel multiplied (the pairs over it: the share of them that is real)
+        prefill_counts.append(reg.counter(
+            "generate.moe.prefill.tile_rows",
+            "rows the grouped expert kernel multiplied: its row tiles of 128",
+        ))
         self._m_counts = decode_counts + prefill_counts
+        self._decode_counted = len(decode_counts)
         self._m_window_pages_released = reg.counter(
             "generate.kv.window.pages_released",
             "ring pages that held a token, a window layer, when their slot was released",
@@ -1156,12 +1166,12 @@ class GenerationScheduler:
         self._next_phase("tick.deliver")
         prefill_pairs = 0
         if self._counted:
-            # behind the slots' tokens: this step's and the carried prefill
-            # programs' [pairs, experts_hit]
+            # behind the slots' tokens: this step's counts and the carried
+            # prefill programs', in the order of self._m_counts
             counts = [int(n) for n in htok[self.slots:]]
             for counter, n in zip(self._m_counts, counts):
                 counter.inc(n)
-            prefill_pairs = counts[len(counts) // 2]
+            prefill_pairs = counts[self._decode_counted]
         eos = self.lm.eos_id
         produced = 0
         wasted = 0

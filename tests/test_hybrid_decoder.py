@@ -175,7 +175,7 @@ def test_shares_add_up_to_the_uncut_routed_layer():
                 router_width=E, first_expert=4 * share,
             )
             params = {"router": router, "bias": bias, "wg": wg[held], "wu": wu[held], "wd": wd[held]}
-            y, n, _hit = moe_serve(params, x, cfg)
+            y, n, _hit, _tiles = moe_serve(params, x, cfg)
             want = ref.routed_ffn(
                 x, router, bias, wg[held], wu[held], wd[held], top_k=K, first=4 * share
             )
@@ -397,6 +397,24 @@ def test_top_shows_pairs_a_token_and_experts_hit_a_step():
     assert "experts:" not in render_top({"generation": dense})
 
 
+def test_top_shows_the_real_share_of_the_grouped_kernels_rows():
+    from pathway_tpu.internals.top import render_top
+
+    generation = {
+        "generate.slots.total": 8.0, "generate.tokens": 640.0,
+        "generate.prefill.tokens": 3900.0, "generate.decode.steps": 600.0,
+        "generate.moe.decode.pairs": 3840.0, "generate.moe.prefill.pairs": 23400.0,
+        "generate.moe.decode.experts_hit": 3720.0,
+        "generate.moe.decode.steps_in_place": 600.0,
+    }
+    assert "tile rows" not in render_top({"generation": generation})
+    generation["generate.moe.prefill.tile_rows"] = 83200.0
+    assert (
+        "100% of steps in place · 28% of prefill tile rows real"
+        in render_top({"generation": generation})
+    )
+
+
 def test_prefill_span_carries_the_routed_pairs(lm):
     from pathway_tpu.engine import tracing
 
@@ -410,3 +428,49 @@ def test_prefill_span_carries_the_routed_pairs(lm):
         sched.shutdown()
     (span,) = [s for s in trace.spans if s["name"] == "generate.prefill"]
     assert 0 < span["attributes"]["pairs"] <= 40 * CFG.routed_layers * CFG.experts_top_k
+
+
+def test_prefill_counts_the_grouped_kernels_tile_rows(lm, monkeypatch):
+    """Prompts that share one prefill program of 8 rows x 32 (256 rows:
+    the grouped path) count ``generate.moe.prefill.tile_rows``: at least
+    their pairs, and exactly the tiles of 128 that each routed layer's
+    groups touch, read from the groups the program routed; a dense
+    model's scheduler counts none."""
+    from pathway_tpu.engine.metrics import get_registry
+    from pathway_tpu.parallel import moe
+
+    groups = []
+    count = moe.row_tiles
+
+    def recorded(group_sizes):
+        jax.debug.callback(lambda g: groups.append(np.asarray(g)), group_sizes)
+        return count(group_sizes)
+
+    monkeypatch.setattr(moe, "row_tiles", recorded)
+    names = ("generate.moe.prefill.tile_rows", "generate.moe.prefill.pairs")
+
+    def counted(model_lm, prompts):
+        before = {n: get_registry().scalar_metrics().get(n, 0.0) for n in names}
+        sched = GenerationScheduler(model_lm, slots=8, page_size=PAGE, prefill_chunk=64)
+        try:
+            with sched._lock:
+                futures = [sched.submit_ids(p, max_new_tokens=3) for p in prompts]
+            for f in futures:
+                f.result(timeout=300)
+        finally:
+            sched.shutdown()
+        after = get_registry().scalar_metrics()
+        return [after.get(n, 0.0) - before[n] for n in names]
+
+    rng = np.random.default_rng(9)
+    tile_rows, pairs = counted(lm, [_prompt(rng, n) for n in (20, 27, 31)])
+    assert len(groups) >= CFG.routed_layers
+    formula = 0
+    for sizes in groups:
+        ends = np.cumsum(sizes)
+        formula += 128 * sum(
+            -(-e // 128) - (e - s) // 128 for s, e in zip(sizes, ends) if s
+        )
+    assert tile_rows == formula >= pairs > 0
+    dense = dec.DecoderLM("pw-tiny-decoder", max_cache=128)
+    assert counted(dense, [_prompt(rng, 20)]) == [0.0, 0.0]

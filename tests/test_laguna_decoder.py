@@ -258,7 +258,7 @@ def test_shares_add_up_to_the_uncut_routed_layer(tokens):
             params = {"router": router, "wg": wg[0, held], "wu": wu[0, held], "wd": wd[0, held]}
             if share == 0:
                 params.update(shared)
-            y, n, _hit = moe_serve(params, x, cfg)
+            y, n, _hit, _tiles = moe_serve(params, x, cfg)
             want = ref.routed_ffn(
                 x, router, wg[:, held], wu[:, held], wd[:, held], first=4 * share, **routed
             )

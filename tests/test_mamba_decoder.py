@@ -445,7 +445,7 @@ def test_both_halves_and_the_shared_expert_once_are_the_uncut_layer():
             )
             params = {"router": router, "bias": bias, "wu": wu[held], "wd": wd[held],
                       "shared_up": su, "shared_down": sd}
-            y, n, _hit = moe_serve(params, x, cfg)
+            y, n, _hit, _tiles = moe_serve(params, x, cfg)
             want = ref.routed_ffn(
                 x, router, bias, wu[held], wd[held], top_k=K, first=4 * share,
                 route_scale=2.5,

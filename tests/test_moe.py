@@ -7,8 +7,12 @@ Semantics pinned here:
   * capacity overflow drops tokens (zero contribution) instead of
     corrupting others,
   * gradients flow through routing: the EP train step reduces the loss,
-  * load-balance aux loss is minimal iff routing is uniform.
+  * load-balance aux loss is minimal iff routing is uniform,
+  * serving's grouped product, the TPU's Pallas kernel (interpreted here)
+    or ``jax.lax.ragged_dot``, equals the loop over the hit experts.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +21,7 @@ import optax
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from pathway_tpu.ops import grouped_matmul as gm
 from pathway_tpu.parallel.moe import (
     MoEConfig,
     ep_param_specs,
@@ -25,6 +30,7 @@ from pathway_tpu.parallel.moe import (
     make_moe_train_step,
     moe_ffn,
     moe_serve,
+    route,
 )
 
 
@@ -134,7 +140,7 @@ def test_serving_never_drops():
     for name in ("wg", "wu", "wd"):
         params[name] = jnp.broadcast_to(params[name][:1], params[name].shape)
     x = jax.random.normal(jax.random.PRNGKey(11), (24, 8), jnp.float32)
-    y, pairs, hit = moe_serve(params, x, cfg)
+    y, pairs, hit, _tiles = moe_serve(params, x, cfg)
     want = _dense_swiglu(x, params["wg"][0], params["wu"][0], params["wd"][0])
     np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=1e-5, atol=1e-5)
     assert int(pairs) == 24 * 2 and 1 <= int(hit) <= 4
@@ -164,7 +170,7 @@ def test_serving_matches_training_form_with_room():
     params = init_moe_params(cfg, seed=12)
     x = jax.random.normal(jax.random.PRNGKey(13), (32, 8), jnp.float32)
     y_train, _ = moe_ffn(params, x, cfg)
-    y_serve, pairs, _hit = moe_serve(params, x, cfg)
+    y_serve, pairs, _hit, _tiles = moe_serve(params, x, cfg)
     np.testing.assert_allclose(
         np.asarray(y_serve), np.asarray(y_train), rtol=1e-5, atol=1e-5
     )
@@ -177,10 +183,10 @@ def test_serving_padding_takes_no_expert():
     params = init_moe_params(cfg, seed=14)
     x = jax.random.normal(jax.random.PRNGKey(15), (2, 6, 8), jnp.float32)
     valid = jnp.arange(6)[None, :] < jnp.asarray([6, 2])[:, None]
-    y, pairs, _hit = moe_serve(params, x, cfg, valid)
+    y, pairs, _hit, _tiles = moe_serve(params, x, cfg, valid)
     assert int(pairs) == (6 + 2) * 2
     assert np.all(np.asarray(y)[1, 2:] == 0.0)
-    y_all, _, _ = moe_serve(params, x, cfg)
+    y_all, *_ = moe_serve(params, x, cfg)
     np.testing.assert_allclose(np.asarray(y)[0], np.asarray(y_all)[0], atol=1e-6)
 
 
@@ -223,17 +229,24 @@ def _serve_params(cfg, *, seed, dtype=jnp.float32, shared=0, bias=False, layers=
     return params
 
 
-def _served(monkeypatch, rows_in_place, params, x, cfg, valid=None):
-    """``moe_serve`` jitted with the rule's constant set for the test, and
-    the path its program took, read from the program."""
+def _served(monkeypatch, rows_in_place, params, x, cfg, valid=None, kernel=False):
+    """``moe_serve`` jitted with the rule's constant set for the test (and
+    with ``kernel`` the TPU's grouped kernel, interpreted, as the grouped
+    product), and the path its program took, read from the program:
+    ``(y, pairs, experts_hit, in place, tile_rows, the program's text)``."""
     from pathway_tpu.parallel import moe
 
     monkeypatch.setattr(moe, "IN_PLACE_ROWS", rows_in_place)
+    if kernel:
+        monkeypatch.setattr(
+            moe, "_grouped_product", functools.partial(gm.grouped_matmul, interpret=True)
+        )
     fn = jax.jit(lambda p, x: moe_serve(p, x, cfg, valid))
     text = str(jax.make_jaxpr(fn)(params, x))
-    assert ("ragged_dot" in text) != ("while[" in text)
-    y, pairs, hit = fn(params, x)
-    return np.asarray(y, np.float32), int(pairs), int(hit), "ragged_dot" not in text
+    grouped = "ragged_dot" in text or "pallas_call" in text
+    assert grouped != ("while[" in text)
+    y, pairs, hit, tile_rows = fn(params, x)
+    return np.asarray(y, np.float32), int(pairs), int(hit), not grouped, int(tile_rows), text
 
 
 _WIDE = dict(hidden=16, experts=4, intermediate=24, router_width=16, first_expert=8)
@@ -290,12 +303,32 @@ SERVE_CASES = {
 }
 
 
+def _tile_rows(params, x, cfg, valid=None) -> int:
+    """The rows the grouped kernel multiplies for ``x``'s routing, by the
+    formula: each held expert's run of sorted pairs touches the tiles of
+    128 from the one its first pair falls in to the one its last does."""
+    logits = jnp.matmul(
+        x.reshape(-1, cfg.hidden).astype(jnp.float32), params["router"],
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    local = np.asarray(route(logits, cfg, params.get("bias"))[0]) - cfg.first_expert
+    here = (local >= 0) & (local < cfg.experts)
+    if valid is not None:
+        here &= np.asarray(valid).reshape(-1)[:, None]
+    ends = np.cumsum(np.bincount(local[here], minlength=cfg.experts))
+    starts = np.concatenate([[0], ends[:-1]])
+    return 128 * sum(-(-e // 128) - s // 128 for s, e in zip(starts, ends) if e > s)
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("case", sorted(SERVE_CASES))
 def test_serving_loop_over_hit_experts_equals_grouped_product(monkeypatch, case, dtype):
-    """The same inputs through both paths: equal results (float32 to 1e-5;
-    bfloat16 within the rounding of the hidden activation and the output),
-    ``pairs`` and ``experts_hit`` equal exactly."""
+    """The same inputs through both paths, the grouped one with
+    ``jax.lax.ragged_dot`` and with the TPU's kernel (interpreted) as its
+    product: equal results (float32 to 1e-5; bfloat16 within the rounding
+    of the hidden activation and the output), ``pairs`` and
+    ``experts_hit`` equal exactly, ``tile_rows`` the formula's (nought on
+    the loop's path).  An int8 weight-only pair keeps ``ragged_dot``."""
     cfg, keywords, rows, valid, change, counts = SERVE_CASES[case]
     dtype = jnp.dtype(dtype)
     params = _serve_params(cfg, seed=len(case), dtype=dtype, **keywords)
@@ -305,10 +338,17 @@ def test_serving_loop_over_hit_experts_equals_grouped_product(monkeypatch, case,
     valid = None if valid is None else jnp.asarray(valid)
     loop = _served(monkeypatch, 10**9, params, x, cfg, valid)
     grouped = _served(monkeypatch, 0, params, x, cfg, valid)
-    assert loop[3] and not grouped[3]  # each took its path
-    assert loop[1:3] == grouped[1:3]
+    kernel = _served(monkeypatch, 0, params, x, cfg, valid, kernel=True)
+    assert loop[3] and not grouped[3] and not kernel[3]  # each took its path
+    int8 = "int8" in case
+    assert ("pallas_call" in kernel[5]) != int8 and ("ragged_dot" in kernel[5]) == int8
+    assert loop[1:3] == grouped[1:3] == kernel[1:3]
+    assert loop[4] == 0 and grouped[4] == kernel[4] == _tile_rows(params, x, cfg, valid)
+    assert kernel[4] >= kernel[1]
     tol = 1e-5 if dtype == jnp.float32 else 0.05
     np.testing.assert_allclose(loop[0], grouped[0], rtol=tol, atol=tol)
+    np.testing.assert_allclose(kernel[0], grouped[0], rtol=tol, atol=tol)
+    np.testing.assert_allclose(kernel[0], loop[0], rtol=tol, atol=tol)
     if counts is not None:
         pairs, hit = counts
         assert loop[1] == pairs and (hit is None or loop[2] == hit)
@@ -323,6 +363,82 @@ def test_serving_loop_over_hit_experts_equals_grouped_product(monkeypatch, case,
             assert np.all(loop[0] == 0.0)
     elif valid is not None:
         assert np.abs(loop[0][np.asarray(valid)]).max() > 0.01
+
+
+# the grouped kernel alone against ``jax.lax.ragged_dot``: rows ``M``, ``K``
+# and ``N`` wide, each group's rows in order (the last rows past them held
+# elsewhere), a budget for the weight block that makes ``tk`` < ``K``
+GROUPED_CASES = {
+    "empty-groups-ragged-sizes-rows-past": (300, 64, 96, [70, 0, 130, 0, 45, 0], None),
+    "one-group-of-every-row": (256, 64, 128, [256], None),
+    "no-rows-in-any-group": (128, 64, 128, [0, 0, 0], None),
+    # a stack of three layers' four experts, layer 2's at 2 * 4 (the
+    # scan's layout: a layer reads the stack where it lies)
+    "stacked-layer-groups-at-layer-times-experts": (
+        256, 64, 128, [0] * 8 + [40, 90, 0, 100], None,
+    ),
+    "fifteen-lanes": (384, 128, 1920, [100, 3, 0, 150, 60, 20], None),
+    "twenty-one-lanes": (256, 128, 2688, [20, 200, 0, 30], None),
+    "twenty-one-lanes-deep": (256, 2688, 128, [129, 0, 100], None),
+    "deep-in-tiles-of-k": (256, 384, 256, [60, 60, 0, 130], 2 * 256 * 2 * 150),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(GROUPED_CASES))
+def test_grouped_kernel_equals_ragged_dot(monkeypatch, case, dtype):
+    """Every row of a group is the product ``jax.lax.ragged_dot`` gives
+    (float32 to 1e-5; bfloat16, accumulated in float32 by both, within
+    one rounding of the output), in the rows' dtype, and the steps the
+    kernel takes are the row tiles its groups touch."""
+    M, K, N, sizes, budget = GROUPED_CASES[case]
+    dtype = jnp.dtype(dtype)
+    if budget is not None:
+        monkeypatch.setattr(gm, "_WEIGHT_VMEM", budget)
+        gm.grouped_matmul.clear_cache()
+    tm, tk, tn = gm.tiling(K, N, dtype.itemsize)
+    assert tm == 128 and K % tk == 0 and N % tn == 0
+    assert (tk < K) == (budget is not None)
+    kx, kw = jax.random.split(jax.random.PRNGKey(len(case)))
+    x = jax.random.normal(kx, (M, K), jnp.float32).astype(dtype)
+    w = (jax.random.normal(kw, (len(sizes), K, N), jnp.float32) / np.sqrt(K)).astype(dtype)
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    got = gm.grouped_matmul(x, w, group_sizes, interpret=True)
+    if budget is not None:
+        gm.grouped_matmul.clear_cache()
+    want = jax.lax.ragged_dot(x, w, group_sizes)
+    assert got.shape == (M, N) and got.dtype == dtype
+    n = sum(sizes)
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(
+        np.asarray(got[:n], np.float32), np.asarray(want[:n], np.float32), rtol=tol, atol=tol
+    )
+    ends = np.cumsum(sizes)
+    touched = sum(-(-e // tm) - (e - s) // tm for s, e in zip(sizes, ends) if s)
+    assert int(gm.row_tiles(group_sizes)) == touched
+    tiles = -(-M // tm)
+    offsets, group_of, tile_of, steps = gm.tile_plan(group_sizes, tiles)
+    assert int(steps) == touched
+    walk = list(zip(np.asarray(group_of)[:touched], np.asarray(tile_of)[:touched]))
+    # a tile is visited by its groups one after another, in row order
+    assert walk == sorted(walk) and all(0 <= t < tiles for _g, t in walk)
+    assert list(np.asarray(offsets)) == [0, *ends]
+
+
+def test_grouped_kernel_tiling_is_a_rule_of_the_widths():
+    """``tm`` 128; ``tn`` the widest lane multiple dividing ``N`` up to
+    1,024; ``tk`` all of ``K`` where its double-buffered weight block fits
+    16 MiB: the widths of the benchmark's three routed models' products,
+    a deeper one, and widths that are no lane multiple."""
+    bf16 = 2
+    assert gm.tiling(2688, 1920, bf16) == (128, 2688, 640)  # Nemotron's up
+    assert gm.tiling(1920, 2688, bf16) == (128, 1920, 896)  # ... and down
+    assert gm.tiling(3072, 1024, bf16) == (128, 3072, 1024)  # Laguna's up
+    assert gm.tiling(1024, 3072, bf16) == (128, 1024, 1024)
+    assert gm.tiling(4096, 2048, bf16) == (128, 4096, 1024)  # MiMo's up
+    assert gm.tiling(2048, 4096, bf16) == (128, 2048, 1024)
+    assert gm.tiling(16384, 1024, bf16) == (128, 4096, 1024)
+    assert gm.tiling(48, 40, 4) == (128, 48, 40)
 
 
 def test_serving_path_is_chosen_by_the_rows_alone(monkeypatch):

@@ -157,9 +157,15 @@ def nemotron_programs(one_chip):
 
 
 def _grouped_products(text: str) -> int:
-    """The grouped products (``jax.lax.ragged_dot``: a custom call of the
-    chip's compiler, ``%ragged-dot-none.N``) a compiled program holds."""
-    return len(re.findall(r"^\s*%ragged-dot-none[\w.\-]* = ", text, flags=re.M))
+    """The grouped products a compiled program holds: calls of the Pallas
+    kernel ``ops/grouped_matmul.py`` (a ``tpu_custom_call`` named
+    ``%grouped_matmul.N``), the only grouped product a program lowered
+    for the chip has (no ``%ragged-dot-none.N`` is left)."""
+    assert not re.search(r"^\s*(?:ROOT )?%ragged-dot", text, flags=re.M)
+    return len(re.findall(
+        r"^\s*(?:ROOT )?%grouped_matmul[\w.\-]* = .*custom_call_target=\"tpu_custom_call\"",
+        text, flags=re.M,
+    ))
 
 
 def _loops(text: str) -> int:
@@ -273,7 +279,8 @@ def test_mimo_step_on_the_chip_loops_over_hit_experts_and_prefill_groups(
     holds no grouped product, one loop a routed body (the scanned run's,
     inside the scan's own loop, and the two runs of one layer) and no
     copy the size of one expert; the prefill program keeps its three
-    grouped products a routed body and no loop but the layer scan."""
+    grouped products a routed body and no loop but the layer scan, and
+    copies no layer's expert stack."""
     text, cfg = mimo_programs[which], mimo_programs["cfg"]
     assert text.startswith(f"HloModule jit__{which}")
     scheduled = _scheduled(text)
@@ -289,3 +296,4 @@ def test_mimo_step_on_the_chip_loops_over_hit_experts_and_prefill_groups(
     else:
         assert _grouped_products(text) == 3 * routed_bodies
         assert _loops(text) == scans
+        assert not _moved_whole(scheduled, cfg.experts * expert)
