@@ -377,12 +377,12 @@ METRICS: dict[str, tuple[str, str]] = {
         "whatever the platform; a tile two experts share is multiplied by "
         "each.  generate.moe.prefill.pairs over it is the share of the "
         "kernel's rows that are real"),
-    "generate.moe.decode.steps_in_place": (
-        "counter", "decode steps enqueued whose routed layers loop over the "
+    "generate.moe.decode.in_place": (
+        "gauge", "1 where the decode step's routed layers loop over the "
         "held experts that met a token and read each where it lies (a "
         "program of few rows, parallel/moe.py::serves_in_place; a static "
-        "fact of the step program): equal to generate.decode.steps on a "
-        "model with routed layers, 0 on one without"),
+        "fact of the step program), 0 where they sort the pairs for the "
+        "grouped product or the model has no routed layer"),
     "generate.ssm.state.resets": (
         "counter", "slots whose recurrent state (a model with Mamba-2 "
         "layers) a prompt's first chunk started from noughts: over "

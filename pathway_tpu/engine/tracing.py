@@ -93,9 +93,10 @@ STORM_TREE_DEPTH = 12
 STORM_DEFAULT_TRACES = 64
 
 
-# the host timeline's ring: closed intervals, oldest dropped.  One decode
-# tick writes ≈ 8, so this holds the last ≈ 4,000 ticks (≈ 90 s of
-# uninterrupted decoding on the chip) in ≈ 5 MB
+# the host timeline's ring: closed intervals, oldest dropped.  A decode
+# tick writes six phases (≈ 6.3 records a tick with the epochs, waits and
+# calls around them in a served window on a v5e), so this holds the last
+# ≈ 5,000 ticks (≈ 20 s of uninterrupted 4 ms ticks) in ≈ 5 MB
 TIMELINE_MAX = 32768
 
 # how long the timeline trusts its cached reading of the switch: the

@@ -528,13 +528,13 @@ def render_top(
                 generation.get("generate.tokens") or 0.0
             )
             hit = generation.get("generate.moe.decode.experts_hit") or 0.0
-            # ... and the share of steps that looped over those experts
-            # in place instead of calling the grouped product
-            in_place = generation.get("generate.moe.decode.steps_in_place") or 0.0
+            # ... and whether a step loops over those experts in place or
+            # calls the grouped product (a fact of the step program)
+            in_place = generation.get("generate.moe.decode.in_place")
             row = (
                 f"  experts: {moe_pairs / max(tokens, 1.0):.1f} pair(s) a token "
                 f"· {hit / max(steps, 1.0):.1f} hit a decode step "
-                f"· {100.0 * in_place / max(steps, 1.0):.0f}% of steps in place"
+                f"· steps {'in place' if in_place else 'grouped'}"
             )
             # ... and the share of the grouped kernel's rows that are real
             tile_rows = generation.get("generate.moe.prefill.tile_rows") or 0.0

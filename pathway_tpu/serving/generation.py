@@ -489,11 +489,8 @@ class GenerationScheduler:
             reg.counter("generate.moe.prefill.pairs", pairs_help),
             reg.counter("generate.moe.prefill.experts_hit", hit_help),
         ]
-        self._m_steps_in_place = reg.counter(
-            "generate.moe.decode.steps_in_place",
-            "decode steps whose routed layers loop over the experts they met",
-        )
-        # a static fact of the step program: its rows are the slots
+        # a static fact of the step program (its rows are the slots), on
+        # /status as the gauge generate.moe.decode.in_place
         self._moe_in_place = False
         if self.cfg.routed_layers > 0:
             from pathway_tpu.parallel.moe import serves_in_place
@@ -740,9 +737,10 @@ class GenerationScheduler:
         no row left to decode reads the step in flight at once, so an
         answer's last token is never held back.  Its phases
         (``tick.admit``, ``tick.prefill.prepare``, ``tick.prefill.enqueue``,
-        ``tick.decode.prepare``, ``tick.decode.enqueue``,
-        ``tick.decode.sync``, ``tick.deliver``) tile it on the timeline's
-        ``sched`` track: each ends where the next starts."""
+        ``tick.decode.prepare``, ``tick.decode.upload``,
+        ``tick.decode.enqueue``, ``tick.decode.sync``, ``tick.deliver``)
+        tile it on the timeline's ``sched`` track: each ends where the next
+        starts."""
         t0 = time.monotonic()
         self._ticks += 1
         self._phase = tracing.begin("sched", "tick.admit", tick=self._ticks)
@@ -1115,6 +1113,11 @@ class GenerationScheduler:
             for i, slot in taken:
                 slot.seq_len += 1
                 self._seq_lens[i] = slot.seq_len
+        # every array the step takes from the host is made here, before
+        # the call: its own phase, so the upload and the dispatch are
+        # timed apart
+        self._next_phase("tick.decode.upload")
+        self._enqueued()
         counts = ()
         if self._counted or history:
             active = np.zeros(self.slots, bool)
@@ -1124,19 +1127,17 @@ class GenerationScheduler:
             counts += (self._prefill_stats,)
             self._prefill_stats = self._no_stats
         self._key, sub = jax.random.split(self._key)
-        self._next_phase("tick.decode.enqueue")
-        self._enqueued()
         args = (
             self.lm.params, self._k_pool, self._v_pool, self._tables(bt),
             jnp.asarray(sl), self._logits, sub, jnp.asarray(temps),
             jnp.asarray(top_ps), jnp.asarray(min_ps),
         )
         if history:
+            sampling = (jnp.asarray(top_ks), jnp.asarray(penalties))
+        self._next_phase("tick.decode.enqueue")
+        if history:
             tok, self._logits, self._k_pool, self._v_pool, self._seen = (
-                self._decode_history_fn(
-                    *args, jnp.asarray(top_ks), jnp.asarray(penalties),
-                    self._seen, *counts,
-                )
+                self._decode_history_fn(*args, *sampling, self._seen, *counts)
             )
         else:
             tok, self._logits, self._k_pool, self._v_pool = self._decode_fn(
@@ -1144,8 +1145,6 @@ class GenerationScheduler:
             )
         tok.copy_to_host_async()  # on its way before the host asks for it
         self._m_decode_steps.inc()
-        if self._moe_in_place:
-            self._m_steps_in_place.inc()
         return _Step(tok, [(i, slot.req) for i, slot in taken], self._programs)
 
     def _deliver(self, step: _Step) -> None:
@@ -1275,6 +1274,9 @@ class GenerationScheduler:
                 # recurrent state: a taken slot's is whole, whatever its length
                 "generate.ssm.state.slots": float(active if self._ssm else 0),
                 "generate.ssm.state.bytes": float(active * self.ssm_bytes_per_slot),
+                # 1 where the decode step's routed layers loop over the
+                # experts they met (parallel/moe.py::serves_in_place)
+                "generate.moe.decode.in_place": float(self._moe_in_place),
                 # sustained decode throughput over the last 5 s
                 "generate.tokens_per_s": (
                     sum(n for _, n in window) / span if span > 0 else 0.0

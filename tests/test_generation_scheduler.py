@@ -496,7 +496,7 @@ def test_run_ahead_gives_the_parents_tokens(rows, sampling):
         steps = _grew(before, "generate.decode.steps")
         assert steps == max(news) and _grew(before, "generate.decode.wasted") == 0
         # a model with no routed layer has no step that loops over experts
-        assert _grew(before, "generate.moe.decode.steps_in_place") == 0
+        assert _scalars()["generate.moe.decode.in_place"] == 0.0
         # every step but the first was enqueued with the one before unread
         overlapped = steps - 1 if drive is _drive else 0
         assert _grew(before, "generate.decode.overlapped") == overlapped

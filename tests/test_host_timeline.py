@@ -166,7 +166,7 @@ def test_admission_controller_opens_serve_idle_between_requests():
 
 PHASES = (
     "tick.admit", "tick.prefill.prepare", "tick.prefill.enqueue", "tick.decode.prepare",
-    "tick.decode.enqueue", "tick.decode.sync", "tick.deliver",
+    "tick.decode.upload", "tick.decode.enqueue", "tick.decode.sync", "tick.deliver",
 )
 
 
@@ -221,6 +221,12 @@ def test_tick_phases_tile_the_tick_and_inflight_never_overlaps():
         names = [r["name"] for r in mine]
         if "tick.decode.enqueue" in names and "tick.decode.sync" in names:
             assert names.index("tick.decode.enqueue") < names.index("tick.decode.sync")
+        # the step's arrays are made between its bookkeeping and its call
+        if "tick.decode.enqueue" in names:
+            at = names.index("tick.decode.upload")
+            assert names[at - 1:at + 2] == [
+                "tick.decode.prepare", "tick.decode.upload", "tick.decode.enqueue"
+            ]
         shares.append(sum(r["end"] - r["start"] for r in mine) / (ended - started))
     # each tick's phases tile its wall time (a tick the machine took the
     # thread away from, between the test's clock and the tick's, is let off)
@@ -237,7 +243,8 @@ def test_tick_phases_tile_the_tick_and_inflight_never_overlaps():
     assert inflight[0]["start"] < ticks[0][1] and inflight[0]["end"] > ticks[7][0]
     syncs = [r for r in phases if r["name"] == "tick.decode.sync"]
     enqueues = [r for r in phases if r["name"] == "tick.decode.enqueue"]
-    assert len(syncs) == len(enqueues) == 10  # every step enqueued is read, once
+    uploads = [r for r in phases if r["name"] == "tick.decode.upload"]
+    assert len(syncs) == len(enqueues) == len(uploads) == 10  # every step enqueued is read, once
     assert sum(r["attributes"]["programs"] for r in inflight) == len(syncs) + sum(
         1 for r in phases if r["name"] == "tick.prefill.enqueue"
     )

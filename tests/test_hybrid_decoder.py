@@ -267,8 +267,8 @@ def test_routing_counters_count_every_pair_once(lm):
     assert abs(pairs / (tokens * CFG.routed_layers) - 1.0) < 0.5
     steps = grew("generate.decode.steps")
     assert steps == new
-    # every step's routed layers looped over the experts they met (8 rows)
-    assert grew("generate.moe.decode.steps_in_place") == steps
+    # every step's routed layers loop over the experts they met (8 rows)
+    assert after["generate.moe.decode.in_place"] == 1.0
     assert grew("generate.moe.decode.pairs") <= steps * CFG.routed_layers * CFG.experts_top_k
     assert 0 < grew("generate.moe.decode.experts_hit") <= steps * CFG.routed_layers * CFG.experts
     assert 0 < grew("generate.moe.prefill.experts_hit") <= (
@@ -385,14 +385,14 @@ def test_top_shows_pairs_a_token_and_experts_hit_a_step():
         "generate.prefill.tokens": 3900.0, "generate.decode.steps": 600.0,
         "generate.moe.decode.pairs": 3840.0, "generate.moe.prefill.pairs": 23400.0,
         "generate.moe.decode.experts_hit": 3720.0,
-        "generate.moe.decode.steps_in_place": 600.0,
+        "generate.moe.decode.in_place": 1.0,
     }
     assert (
-        "experts: 6.0 pair(s) a token · 6.2 hit a decode step · 100% of steps in place"
+        "experts: 6.0 pair(s) a token · 6.2 hit a decode step · steps in place"
         in render_top({"generation": generation})
     )
-    generation["generate.moe.decode.steps_in_place"] = 0.0  # more than 128 slots
-    assert "hit a decode step · 0% of steps in place" in render_top({"generation": generation})
+    generation["generate.moe.decode.in_place"] = 0.0  # more than 128 slots
+    assert "hit a decode step · steps grouped" in render_top({"generation": generation})
     dense = {k: v for k, v in generation.items() if ".moe." not in k}
     assert "experts:" not in render_top({"generation": dense})
 
@@ -405,12 +405,12 @@ def test_top_shows_the_real_share_of_the_grouped_kernels_rows():
         "generate.prefill.tokens": 3900.0, "generate.decode.steps": 600.0,
         "generate.moe.decode.pairs": 3840.0, "generate.moe.prefill.pairs": 23400.0,
         "generate.moe.decode.experts_hit": 3720.0,
-        "generate.moe.decode.steps_in_place": 600.0,
+        "generate.moe.decode.in_place": 1.0,
     }
     assert "tile rows" not in render_top({"generation": generation})
     generation["generate.moe.prefill.tile_rows"] = 83200.0
     assert (
-        "100% of steps in place · 28% of prefill tile rows real"
+        "steps in place · 28% of prefill tile rows real"
         in render_top({"generation": generation})
     )
 

@@ -359,12 +359,9 @@ def test_run_ahead_with_a_row_that_ends_mid_flight(lm, ref_weights):
     after = _scalars()
     assert outs == [free_run[0], free_run[1][:4]] and again == free_run[1][:4]
     assert after["generate.decode.wasted"] - before.get("generate.decode.wasted", 0.0) >= 1
-    # every decode step's routed layers looped over the experts they met
-    steps, in_place = (
-        after[name] - before.get(name, 0.0)
-        for name in ("generate.decode.steps", "generate.moe.decode.steps_in_place")
-    )
-    assert steps == in_place > 0
+    # every decode step's routed layers loop over the experts they met
+    assert after["generate.decode.steps"] - before.get("generate.decode.steps", 0.0) > 0
+    assert after["generate.moe.decode.in_place"] == 1.0
     want = _reference_logits(ref_weights, [long_], [outs[0]], new)
     assert list(want[0].argmax(-1)) == outs[0]
 
